@@ -107,8 +107,29 @@ def smoke(out_path: str = "BENCH_smoke.json") -> int:
     return 0
 
 
-def main() -> None:
-    """CLI entry: run the selected benches, print/write the CSV rows."""
+def run_benches(benches: dict, only=None) -> list[str]:
+    """Run the selected benches, printing their CSV rows.
+
+    Returns the names of the benches that raised or wrote an ``ERROR``
+    row; the others still run.
+    """
+    failed = []
+    for name, fn in benches.items():
+        if only and name not in only:
+            continue
+        try:
+            for line in fn():
+                print(line, flush=True)
+                if line.split(",")[1:2] == ["ERROR"]:
+                    failed.append(name)
+        except Exception as e:  # report, keep going, fail at the end
+            print(f"{name},ERROR,{type(e).__name__}:{e}", file=sys.stderr)
+            failed.append(name)
+    return sorted(set(failed))
+
+
+def main(argv=None) -> None:
+    """CLI entry: run the selected benches; exit 1 if any of them failed."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small graph suite only")
@@ -118,8 +139,10 @@ def main() -> None:
     ap.add_argument("--smoke-out", default="BENCH_smoke.json")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of benches")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         raise SystemExit(smoke(args.smoke_out))
 
@@ -148,14 +171,10 @@ def main() -> None:
         "hier": lambda: hier_bench.rows(quick=args.quick),
     }
     print("name,us_per_call,derived")
-    for name, fn in benches.items():
-        if only and name not in only:
-            continue
-        try:
-            for line in fn():
-                print(line, flush=True)
-        except Exception as e:  # report, keep going
-            print(f"{name},ERROR,{type(e).__name__}:{e}", file=sys.stderr)
+    failed = run_benches(benches, only)
+    if failed:
+        print(f"benches failed: {', '.join(failed)}", file=sys.stderr)
+        raise SystemExit(1)
 
 
 if __name__ == '__main__':

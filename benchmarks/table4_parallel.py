@@ -4,6 +4,9 @@ XLA host devices are the stand-in for cores: each device count runs in a
 subprocess (device count locks at jax init). The measured quantity is the
 full decomposition wall time of `pkt_dist` (table-sharded, psum-combined),
 mirroring the paper's 1→24-core relative-speedup figure.
+
+CPU only: on an accelerator the parent process already holds the device,
+so a child that needs it would fail or hang; ``run`` refuses there.
 """
 
 from __future__ import annotations
@@ -36,7 +39,18 @@ print(f"RESULT {dt:.4f} {g.wedge_count()}")
 
 def run(suite=("rmat-small", "ba-small", "er-small"),
         device_counts=(1, 2, 4, 8)) -> list[str]:
-    """CSV rows: serial-vs-vmapped scaling proxy (paper Table 4)."""
+    """CSV rows: serial-vs-vmapped scaling proxy (paper Table 4).
+
+    Raises:
+        RuntimeError: the parent's JAX backend is not the CPU.
+    """
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"table4 starts one JAX child process per device count, and "
+            f"this process already holds the {jax.default_backend()} "
+            f"device; it runs on the CPU backend only")
     out = []
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(

@@ -52,7 +52,9 @@ from repro.core import support as support_mod
 from repro.kernels import wedge_common
 from repro.testing.chaos import fault_point
 
-_SENTINEL_S = jnp.int32(1 << 30)
+#: support of processed / padding edge slots; a numpy scalar, so importing
+#: this module initialises no JAX backend
+_SENTINEL_S = np.int32(1 << 30)
 
 PEEL_MODES = ("chunked", "dense", "pallas")
 
@@ -216,7 +218,8 @@ def _active_chunk_mask(inCurr, tabs: PeelTables, m: int, n_chunks: int):
 
 def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
                chunk: int, n_chunks: int, iters: int, mode: str,
-               interpret: bool = True, pinned=None, stop_live=None):
+               interpret: bool = True, pinned=None, stop_live=None,
+               reduce=None):
     """Full level/sub-level peel over extended (m+1,) edge state.
 
     ``S_ext0``/``processed0`` define which slots are live: slot m must be the
@@ -235,6 +238,11 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
     unprocessed edges drops to or below it — always at a level boundary, so
     the caller can gather survivors into a compacted edge space and re-enter
     with bitwise-identical continuation.
+
+    ``reduce`` (optional) combines each sub-level's decrement vector before
+    it is applied — the distributed path (core/pkt_dist.py) passes a
+    ``psum`` over the mesh, each device having folded only its own table
+    shard.
     """
     def chunk_contrib(c, dec, S_ext, processed, inCurr, l):
         """Decrement contributions from one chunk of the wedge table."""
@@ -298,6 +306,8 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
                 return i + 1, body(i, dec)
 
             _, dec = jax.lax.while_loop(cond, wbody, (jnp.int32(0), dec0))
+        if reduce is not None:
+            dec = reduce(dec)
 
         S_ext = jnp.where(
             (~processed) & (~inCurr) & (dec > 0),
@@ -553,8 +563,7 @@ def peel_live_subset(El: np.ndarray, live_ids: np.ndarray,
         # ascending ids are what make the compacted relabeling
         # order-preserving — the tie-break replay is silently wrong otherwise
         raise ValueError("live_ids must be strictly increasing edge ids")
-    if interpret is None:
-        interpret = wedge_common.interpret_default()
+    interpret = wedge_common.resolve_interpret(interpret, peel_mode=mode)
     out = np.zeros(k, np.int32)
     problem = _make_subproblem(
         np.asarray(El)[live_ids], np.arange(k, dtype=np.int64),
@@ -619,6 +628,8 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
 
     Raises:
         ValueError: unknown ``mode`` / ``support_mode`` / ``table_mode``.
+        NotImplementedError: a Pallas executor or ``interpret=True`` on a
+            TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
     import time as _time
 
@@ -634,12 +645,12 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
     if table_mode not in support_mod.TABLE_MODES:
         raise ValueError(f"table_mode must be one of "
                          f"{support_mod.TABLE_MODES}, got {table_mode!r}")
+    interpret = wedge_common.resolve_interpret(
+        interpret, peel_mode=mode, support_mode=support_mode)
     timings: dict | None = {} if phase_timings else None
     if g.m == 0:
         return PKTResult(np.zeros(0, np.int32), np.zeros(0, np.int32), 0, 0,
                          phases=timings)
-    if interpret is None:
-        interpret = wedge_common.interpret_default()
 
     # ---- support phase -----------------------------------------------------
     fault_point("support", rung=f"{support_mode}/{table_mode}")

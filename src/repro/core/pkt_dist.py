@@ -5,20 +5,21 @@ settings appears to be non-trivial." This module is that port, in the BSP
 idiom natural to an SPMD mesh:
 
   * the flat peel-wedge table (the unit of peel work) is sharded across a mesh
-    axis; each device computes decrement contributions for its slice;
-  * edge state (S, processed, frontier) is replicated; one `psum` of the
-    decrement vector per sub-level is the only communication — the distributed
-    analogue of the paper's per-sub-level barrier;
-  * support computation fans out the same way (shard the oriented wedge
-    table, psum the partial supports once); per shard it runs either as the
-    flat jnp program or — ``support_mode="pallas"`` — as the chunked VMEM
-    kernel from ``kernels/support.py``, each device lowering the kernel over
-    its own table slice.  Both modes are bitwise identical.
+    axis: each device builds its own contiguous slice of rows from the
+    replicated CSR arrays, so no device ever holds the whole table;
+  * edge state (S, processed, frontier) is replicated; each device runs the
+    single-device chunk-skipping peel loop (``core.pkt._peel_loop``) over its
+    slice, and one `psum` of the decrement vector per sub-level is the only
+    communication — the distributed analogue of the paper's per-sub-level
+    barrier;
+  * support computation fans out the same way (each device builds and probes
+    its slice of the oriented wedge table, then one psum of the partial
+    supports); per shard it runs either as the flat jnp program or —
+    ``support_mode="pallas"`` — as the chunked kernel from
+    ``kernels/support.py``.  Both modes are bitwise identical.
 
-Work per sub-level per device: O(local_table) dense (each device scans its
-slice with frontier masking). Communication per sub-level: one all-reduce of
-an m-vector. This is exactly the cost model a 1000-node deployment needs to
-reason about, and what launch/dryrun.py lowers for the production mesh.
+Work per sub-level per device: the chunks of its slice that overlap the
+frontier. Communication per sub-level: one all-reduce of an m-vector.
 """
 
 from __future__ import annotations
@@ -30,176 +31,171 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.graphs.csr import CSRGraph
 from repro.core import support as support_mod
+from repro.core.pkt import PeelTables, _SENTINEL_S, _peel_loop
 from repro.kernels import wedge_common
 
-_SENT = jnp.int32(1 << 30)
 
-
-def _dist_peel_body(N, Eid, S0, e1, cand, lo, hi, *, m: int, iters: int,
-                    chunk: int, axes: Sequence[str]):
-    """Runs inside shard_map: local table slices, replicated edge state."""
-    local = e1.shape[0]
-    n_chunks = max(1, local // chunk)
-
-    def chunk_contrib(c, dec, S_ext, processed, inCurr, l):
-        base = c * chunk
-        ee1 = jax.lax.dynamic_slice(e1, (base,), (chunk,))
-        cc = jax.lax.dynamic_slice(cand, (base,), (chunk,))
-        ll = jax.lax.dynamic_slice(lo, (base,), (chunk,))
-        hh = jax.lax.dynamic_slice(hi, (base,), (chunk,))
-        in1 = inCurr[ee1]
-        hit, safe = wedge_common.probe(N, cc, ll, hh, iters=iters)
-        e2 = Eid[cc]
-        e3 = Eid[safe]
-        valid = in1 & hit & ~processed[e2] & ~processed[e3]
-        dec2 = valid & (S_ext[e2] > l) & ((~inCurr[e3]) | (ee1 < e3))
-        dec3 = valid & (S_ext[e3] > l) & ((~inCurr[e2]) | (ee1 < e2))
-        dec = dec.at[jnp.where(dec2, e2, m)].add(dec2.astype(jnp.int32))
-        dec = dec.at[jnp.where(dec3, e3, m)].add(dec3.astype(jnp.int32))
-        return dec
-
-    def sublevel(S_ext, processed, inCurr, l):
-        def body(c, dec):
-            return chunk_contrib(c, dec, S_ext, processed, inCurr, l)
-        dec = jax.lax.fori_loop(0, n_chunks, body,
-                                jnp.zeros((m + 1,), jnp.int32))
-        for ax in axes:
-            dec = jax.lax.psum(dec, ax)
-        S_ext = jnp.where((~processed) & (~inCurr) & (dec > 0),
-                          jnp.maximum(S_ext - dec, l), S_ext)
-        processed = processed | inCurr
-        inCurr = (~processed) & (S_ext == l)
-        return S_ext, processed, inCurr
-
-    S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENT)])
-    processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
-
-    def level_body(state):
-        S_ext, processed, todo, levels, subs = state
-        l = jnp.min(jnp.where(processed, _SENT, S_ext))
-        inCurr = (~processed) & (S_ext == l)
-
-        def sub_cond(st):
-            return jnp.any(st[2])
-
-        def sub_body(st):
-            S_ext, processed, inC, subs_ = st
-            S_ext, processed, inC = sublevel(S_ext, processed, inC, l)
-            return S_ext, processed, inC, subs_ + 1
-
-        S_ext, processed, _, subs = jax.lax.while_loop(
-            sub_cond, sub_body, (S_ext, processed, inCurr, subs))
-        todo = (m + 1) - jnp.sum(processed.astype(jnp.int32))
-        return S_ext, processed, todo, levels + 1, subs
-
-    state = (S_ext0, processed0, jnp.int32(m), jnp.int32(0), jnp.int32(0))
-    S_ext, _, _, levels, subs = jax.lax.while_loop(
-        lambda st: st[2] > 0, level_body, state)
-    return S_ext[:m], levels, subs
+def _psum(x, axes: Sequence[str]):
+    for ax in axes:
+        x = jax.lax.psum(x, ax)
+    return x
 
 
 def _dist_support_body(N, Eid, e1, cand, lo, hi, *, m: int, iters: int,
                        axes: Sequence[str], mode: str = "jnp",
                        chunk: int = 0, interpret: bool = True):
-    """Sharded AM4 support computation (inside shard_map).
+    """Sharded AM4 support over this device's table slice (inside shard_map).
 
-    ``mode="pallas"`` evaluates the local table slice with the chunked
-    support kernel (the caller guarantees the slice length is a multiple of
-    ``chunk``); the folded scatter and one psum make the two modes bitwise
+    ``mode="pallas"`` evaluates the slice with the chunked support kernel
+    (the caller guarantees the slice length is a multiple of ``chunk``);
+    integer-exact addition and one psum make the two modes bitwise
     identical.
     """
-    if mode == "pallas":
-        from repro.kernels.support import support_accumulate
-
-        local = e1.shape[0]
-        assert chunk >= 1 and local % chunk == 0, (local, chunk)
-        S, _ = support_accumulate(
-            e1, cand, lo, hi, N, Eid, chunk=chunk,
-            n_chunks=local // chunk, iters=iters, m=m, interpret=interpret)
-    else:
-        hit, safe = wedge_common.probe(N, cand, lo, hi, iters=iters)
-        # sentinel entries carry e1 == m
-        inc = hit.astype(jnp.int32)
-        S = jnp.zeros((m + 1,), jnp.int32)
-        S = S.at[e1].add(inc)
-        S = S.at[jnp.where(hit, Eid[cand], m)].add(inc)
-        S = S.at[jnp.where(hit, Eid[safe], m)].add(inc)
-    for ax in axes:
-        S = jax.lax.psum(S, ax)
-    return S[:m]
+    n_chunks = e1.shape[0] // chunk if chunk else 1   # jnp ignores both
+    S = support_mod.support_from_table_arrays(
+        e1, cand, lo, hi, N, Eid, m=m, mode=mode, chunk=chunk,
+        n_chunks=n_chunks, iters=iters, interpret=interpret)
+    return _psum(S, axes)
 
 
-def make_pkt_dist(mesh: jax.sharding.Mesh, axes: Sequence[str], *, m: int,
-                  two_m: int, table_size: int, iters: int,
-                  chunk: int = 1 << 14):
-    """Builds the jittable distributed PKT callable for dry-run or execution.
+def _dist_support_dev_body(u, v, Es, Eo, N, Eid, *, m: int, per_shard: int,
+                           iters: int, axes: Sequence[str], mode: str,
+                           chunk: int, interpret: bool):
+    """Build this device's support-table slice, then probe it."""
+    start = jax.lax.axis_index(tuple(axes)) * per_shard
+    e1, cand, lo, hi, _ = support_mod.support_rows(
+        u, v, Es, Eo, jnp.int32(m), m=m, size=per_shard, start=start)
+    return _dist_support_body(N, Eid, e1, cand, lo, hi, m=m, iters=iters,
+                              axes=axes, mode=mode, chunk=chunk,
+                              interpret=interpret)
 
-    Args are logical sizes; the returned fn takes
-    (N, Eid, S0, e1, cand, lo, hi) full (global) arrays where the four table
-    arrays are sharded over ``axes`` and the rest replicated.
+
+def _dist_peel_rows_body(u, v, Es, *, m: int, per_shard: int, chunk: int,
+                         axes: Sequence[str]):
+    """This device's peel-table slice + whole-table chunk metadata."""
+    start = jax.lax.axis_index(tuple(axes)) * per_shard
+    e1, cand, lo, hi, _off, c_start, c_end, has = support_mod.peel_rows(
+        u, v, Es, jnp.int32(m), m=m, size=per_shard, chunk=chunk, start=start)
+    return e1, cand, lo, hi, c_start, c_end, has
+
+
+def _dist_peel_body(N, Eid, S0, e1, cand, lo, hi, c_start, c_end, has, *,
+                    m: int, iters: int, chunk: int, axes: Sequence[str]):
+    """Chunk-skipping peel over this device's table slice (inside shard_map).
+
+    ``c_start``/``c_end``/``has`` index the whole table's chunks; they are
+    clipped to this device's chunk window, so each device visits only its
+    own chunks that overlap the frontier.
     """
-    spec_rep = P()
-    spec_sh = P(tuple(axes))
+    n_chunks = e1.shape[0] // chunk
+    base = jax.lax.axis_index(tuple(axes)) * n_chunks
+    mine = has & (c_end >= base) & (c_start < base + n_chunks)
+    tabs = PeelTables(
+        e1, cand, lo, hi,
+        jnp.clip(c_start - base, 0, n_chunks - 1),
+        jnp.clip(c_end - base, 0, n_chunks - 1), mine)
+    S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
+    processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
+    S_ext, _, levels, subs = _peel_loop(
+        N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
+        n_chunks=n_chunks, iters=iters, mode="chunked",
+        reduce=functools.partial(_psum, axes=axes))
+    return S_ext[:m], levels, subs
 
-    fn = shard_map(
+
+@functools.lru_cache(maxsize=None)
+def make_pkt_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *, m: int,
+                  iters: int, chunk: int = 1 << 14):
+    """Builds the jitted distributed peel for dry-run or execution.
+
+    The returned fn takes (N, Eid, S0, e1, cand, lo, hi, c_start, c_end,
+    has): the four table arrays sharded over ``axes`` (each shard a whole
+    number of ``chunk``-row chunks), the rest replicated.  Returns
+    replicated (S_final, levels, sublevels).  Cached per argument set, so
+    a repeated decomposition compiles nothing.
+    """
+    rep, sh = P(), P(tuple(axes))
+    fn = jax.shard_map(
         functools.partial(_dist_peel_body, m=m, iters=iters, chunk=chunk,
                           axes=axes),
-        mesh=mesh,
-        in_specs=(spec_rep, spec_rep, spec_rep, spec_sh, spec_sh, spec_sh,
-                  spec_sh),
-        out_specs=(spec_rep, spec_rep, spec_rep),
-        check_vma=False,
-    )
+        mesh=mesh, in_specs=(rep, rep, rep, sh, sh, sh, sh, rep, rep, rep),
+        out_specs=(rep, rep, rep), check_vma=False)
     return jax.jit(fn)
 
 
-def make_support_dist(mesh: jax.sharding.Mesh, axes: Sequence[str], *, m: int,
-                      iters: int, mode: str = "jnp", chunk: int = 0,
+@functools.lru_cache(maxsize=None)
+def make_support_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
+                      m: int, iters: int, mode: str = "jnp", chunk: int = 0,
                       interpret: bool = True):
-    """Jitted shard_map support computation over ``mesh`` (DESIGN.md §6).
+    """Jitted shard_map support over prebuilt sharded tables (DESIGN.md §6).
 
     Wedge-table shards live per-device along ``axes``; each device counts
     triangles for its shard against the replicated CSR arrays and the
     results are psum-reduced to a replicated (m,) support vector.
     """
-    spec_rep = P()
-    spec_sh = P(tuple(axes))
-    fn = shard_map(
+    rep, sh = P(), P(tuple(axes))
+    fn = jax.shard_map(
         functools.partial(_dist_support_body, m=m, iters=iters, axes=axes,
                           mode=mode, chunk=chunk, interpret=interpret),
-        mesh=mesh,
-        in_specs=(spec_rep, spec_rep, spec_sh, spec_sh, spec_sh, spec_sh),
-        out_specs=spec_rep,
-        check_vma=False,
-    )
+        mesh=mesh, in_specs=(rep, rep, sh, sh, sh, sh), out_specs=rep,
+        check_vma=False)
     return jax.jit(fn)
 
 
+@functools.lru_cache(maxsize=None)
+def _support_dev_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
+                      m: int, per_shard: int, iters: int, mode: str,
+                      chunk: int, interpret: bool):
+    """Jitted shard_map: each device builds and probes its support slice."""
+    return jax.jit(jax.shard_map(
+        functools.partial(_dist_support_dev_body, m=m, per_shard=per_shard,
+                          iters=iters, axes=axes, mode=mode, chunk=chunk,
+                          interpret=interpret),
+        mesh=mesh, in_specs=(P(),) * 6, out_specs=P(), check_vma=False))
+
+
+@functools.lru_cache(maxsize=None)
+def _peel_rows_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
+                    m: int, per_shard: int, chunk: int):
+    """Jitted shard_map: each device builds its peel-table slice."""
+    return jax.jit(jax.shard_map(
+        functools.partial(_dist_peel_rows_body, m=m, per_shard=per_shard,
+                          chunk=chunk, axes=axes),
+        mesh=mesh, in_specs=(P(),) * 3,
+        out_specs=(P(axes),) * 4 + (P(),) * 3, check_vma=False))
+
+
 def _pad_to(x: np.ndarray, size: int, fill) -> np.ndarray:
-    out = np.full(size, fill, x.dtype)
+    out = np.full(size, fill, np.int32)
     out[: x.shape[0]] = x
     return out
 
 
 def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
-             axes: Sequence[str] = ("data",), chunk: int = 1 << 12,
+             axes: Sequence[str] = ("data",), chunk: int | None = None,
              support_mode: str = "jnp", table_mode: str = "device",
-             interpret: bool | None = None):
-    """Run distributed PKT on the available devices. Returns trussness (m,).
+             interpret: bool | None = None) -> np.ndarray:
+    """Run distributed PKT over ``mesh``. Returns trussness (m,).
 
+    ``mesh`` defaults to a 1-D mesh over every device.  The CSR arrays are
+    replicated onto the mesh; with ``table_mode="device"`` (the default)
+    each device builds only its own slice of both wedge tables, so the
+    tables are split across the devices from the start and never pass
+    through the host or one device.  "numpy" keeps the host builders as the
+    parity oracle and uploads each slice straight to its device.
     ``support_mode`` selects the per-shard support executor ("jnp" or
-    "pallas", see ``core.support.SUPPORT_MODES``); the peel phase is the
-    sharded BSP loop in either case.  ``table_mode="device"`` (the default)
-    builds both wedge tables with the jitted XLA builders directly at the
-    shard-rounded padded sizes — the shard_map then redistributes
-    device-resident slices instead of uploading host tables several× the
-    graph size; "numpy" keeps the host builders as the parity oracle.
+    "pallas"); the peel is the sharded chunk-skipping loop either way.
+    ``chunk`` (``None``: ``kernels.wedge_common.auto_chunk`` of the
+    per-device slice) sets the peel chunk.
+
+    Raises:
+        ValueError: unknown ``support_mode`` / ``table_mode``.
+        NotImplementedError: ``support_mode="pallas"`` or
+            ``interpret=True`` on a TPU backend.
     """
     if support_mode not in support_mod.SUPPORT_MODES:
         raise ValueError(f"support_mode must be one of "
@@ -207,59 +203,69 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
     if table_mode not in support_mod.TABLE_MODES:
         raise ValueError(f"table_mode must be one of "
                          f"{support_mod.TABLE_MODES}, got {table_mode!r}")
+    interpret = wedge_common.resolve_interpret(interpret,
+                                               support_mode=support_mode)
     if mesh is None:
-        dev = np.array(jax.devices())
-        mesh = jax.sharding.Mesh(dev, ("data",))
+        mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
         axes = ("data",)
-    if interpret is None:
-        interpret = wedge_common.interpret_default()
+    if g.m == 0:
+        return np.zeros(0, np.int64)
+    axes = tuple(axes)
     n_shards = int(np.prod([mesh.shape[a] for a in axes]))
-    iters = support_mod._search_iters(g)
-    gdev = g.device_arrays()
+    rep = NamedSharding(mesh, P())
+    sh = NamedSharding(mesh, P(axes))
+    csr = {k: jax.device_put(x, rep) for k, x in (
+        ("N", g.N), ("Eid", g.Eid), ("Es", g.Es), ("Eo", g.Eo),
+        ("u", np.ascontiguousarray(g.El[:, 0])),
+        ("v", np.ascontiguousarray(g.El[:, 1])))}
 
-    s_size = support_mod.support_table_size(g)
-    per_shard = max(1, -(-max(s_size, 1) // n_shards))
+    # ---- support: each shard probes its slice, one psum ---------------------
+    s_iters = support_mod._search_iters(g, oriented=True)
+    per_shard = max(1, -(-support_mod.support_table_size(g) // n_shards))
     sup_chunk = 0
     if support_mode == "pallas":
         # each shard lowers the kernel over its slice: the slice must be a
         # whole number of chunks, so round the per-shard length up to one
         sup_chunk = wedge_common.pow2_chunk(1 << 13, chunk)
         per_shard = -(-per_shard // sup_chunk) * sup_chunk
-    ssize = per_shard * n_shards
+    support_mod._check_table_size(per_shard * n_shards)
     if table_mode == "device":
-        support_mod._check_table_size(ssize)
-        s_e1, s_cand, s_lo, s_hi, _ = support_mod._build_support_table_dev(
-            gdev["El"][:, 0], gdev["El"][:, 1], gdev["Es"], gdev["Eo"],
-            jnp.int32(g.m), m=g.m, size=ssize)
+        sup_fn = _support_dev_dist(mesh, axes, m=g.m, per_shard=per_shard,
+                                   iters=s_iters, mode=support_mode,
+                                   chunk=sup_chunk, interpret=interpret)
+        S0 = sup_fn(csr["u"], csr["v"], csr["Es"], csr["Eo"], csr["N"],
+                    csr["Eid"])
     else:
         stab = support_mod.build_support_table(g)
-        s_e1 = jnp.asarray(_pad_to(stab.e1, ssize, g.m))
-        s_cand = jnp.asarray(_pad_to(stab.cand_slot, ssize, 0))
-        s_lo = jnp.asarray(_pad_to(stab.lo, ssize, 0))
-        s_hi = jnp.asarray(_pad_to(stab.hi, ssize, 0))
-    sup_fn = make_support_dist(mesh, axes, m=g.m, iters=iters,
-                               mode=support_mode, chunk=sup_chunk,
-                               interpret=interpret)
-    S0 = sup_fn(gdev["N"], gdev["Eid"], s_e1, s_cand, s_lo, s_hi)
+        ssize = per_shard * n_shards
+        s_tab = [jax.device_put(_pad_to(a, ssize, fill), sh) for a, fill in (
+            (stab.e1, g.m), (stab.cand_slot, 0), (stab.lo, 0), (stab.hi, 0))]
+        sup_fn = make_support_dist(mesh, axes, m=g.m, iters=s_iters,
+                                   mode=support_mode, chunk=sup_chunk,
+                                   interpret=interpret)
+        S0 = sup_fn(csr["N"], csr["Eid"], *s_tab)
 
+    # ---- peel tables: each shard holds a whole number of chunks -------------
     p_size = support_mod.peel_table_size(g)
-    per = max(chunk, -(-max(p_size, 1) // n_shards))
-    per = -(-per // chunk) * chunk           # round to chunk multiple
+    per = max(1, -(-p_size // n_shards))
+    chunk = wedge_common.pow2_chunk(
+        wedge_common.next_pow2(per), chunk, size=per)
+    per = -(-per // chunk) * chunk
     psize = per * n_shards
+    support_mod._check_table_size(psize)
     if table_mode == "device":
-        support_mod._check_table_size(psize)
-        p_e1, p_cand, p_lo, p_hi, _off, _cs, _ce, _has = \
-            support_mod._build_peel_table_dev(
-                gdev["El"][:, 0], gdev["El"][:, 1], gdev["Es"],
-                jnp.int32(g.m), m=g.m, size=psize, chunk=chunk)
+        rows_fn = _peel_rows_dist(mesh, axes, m=g.m, per_shard=per,
+                                  chunk=chunk)
+        p_tab = rows_fn(csr["u"], csr["v"], csr["Es"])
     else:
+        from repro.core.pkt import chunk_ranges
+
         ptab = support_mod.build_peel_table(g)
-        p_e1 = jnp.asarray(_pad_to(ptab.e1, psize, g.m))
-        p_cand = jnp.asarray(_pad_to(ptab.cand_slot, psize, 0))
-        p_lo = jnp.asarray(_pad_to(ptab.lo, psize, 0))
-        p_hi = jnp.asarray(_pad_to(ptab.hi, psize, 0))
-    peel_fn = make_pkt_dist(mesh, axes, m=g.m, two_m=2 * g.m,
-                            table_size=psize, iters=iters, chunk=chunk)
-    S, levels, subs = peel_fn(gdev["N"], gdev["Eid"], S0,
-                              p_e1, p_cand, p_lo, p_hi)
+        has, c_start, c_end = chunk_ranges(ptab.off, chunk)
+        p_tab = [jax.device_put(_pad_to(a, psize, fill), sh) for a, fill in (
+            (ptab.e1, g.m), (ptab.cand_slot, 0), (ptab.lo, 0), (ptab.hi, 0))]
+        p_tab += [jax.device_put(x, rep) for x in (c_start, c_end, has)]
+    peel_fn = make_pkt_dist(mesh, axes, m=g.m,
+                            iters=support_mod._search_iters(g), chunk=chunk)
+    S, _levels, _subs = peel_fn(csr["N"], csr["Eid"], S0, *p_tab)
     return np.asarray(S).astype(np.int64) + 2

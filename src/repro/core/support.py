@@ -28,7 +28,8 @@ Two execution modes (``compute_support(mode=...)``), bitwise identical:
       VMEM, per-chunk triangle partials accumulated on-chip.  The kernel
       emits increment-target streams; the support scatter-add happens once
       outside, so integer-exact addition makes the two modes agree bitwise.
-      Off-TPU the kernel runs in interpret mode (CI lowers it on every PR).
+      Off-TPU the kernel runs in interpret mode; it does not lower for the
+      TPU yet, so a TPU backend refuses it (ROADMAP Speed 2).
 
 The peel phase has the same split (``core.pkt.pkt(mode=...)``); the two
 kernels share layout and search machinery via ``kernels/wedge_common.py``.
@@ -45,9 +46,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.graphs.csr import CSRGraph
-from repro.kernels.wedge_common import (chunk_layout, interpret_default,
-                                        next_pow2, pad_chunked, pow2_chunk,
-                                        probe)
+from repro.kernels.wedge_common import (chunk_layout, next_pow2, pad_chunked,
+                                        pow2_chunk, probe, resolve_interpret)
 # re-export: the triangle-list engine binary-searches through this module's
 # namespace (kernels.wedge_common is the canonical home)
 from repro.kernels.wedge_common import ranged_searchsorted  # noqa: F401
@@ -184,14 +184,16 @@ def _check_table_size(size: int) -> None:
             f"offsets)")
 
 
-def _expand_segments(off, size: int, m: int):
+def _expand_segments(off, size: int, m: int, start=0):
     """Row → segment assignment for a cumsum offset array ``off`` (m+1,).
 
-    Returns ``(e1, e1c, intra, valid)``: the owning segment of each of the
-    ``size`` rows (``m`` for rows beyond ``off[m]``), a clamped variant safe
-    as a gather index, the offset within the segment, and the validity mask.
+    Covers rows ``[start, start + size)`` (``start`` may be traced: each
+    shard of the distributed path builds its own slice).  Returns
+    ``(e1, e1c, intra, valid)``: the owning segment of each row (``m`` for
+    rows beyond ``off[m]``), a clamped variant safe as a gather index, the
+    offset within the segment, and the validity mask.
     """
-    idx = jnp.arange(size, dtype=jnp.int32)
+    idx = start + jnp.arange(size, dtype=jnp.int32)
     e1 = jnp.searchsorted(off[1:], idx, side="right").astype(jnp.int32)
     e1c = jnp.minimum(e1, m - 1)
     valid = idx < off[m]
@@ -199,9 +201,8 @@ def _expand_segments(off, size: int, m: int):
     return jnp.where(valid, e1, m), e1c, intra, valid
 
 
-@functools.partial(jax.jit, static_argnames=("m", "size"))
-def _build_support_table_dev(u, v, Es, Eo, m_real, *, m: int, size: int):
-    """Device mirror of ``build_support_table`` at static padded ``size``.
+def support_rows(u, v, Es, Eo, m_real, *, m: int, size: int, start=0):
+    """Trace-level ``build_support_table`` rows ``[start, start + size)``.
 
     ``u``/``v``: (m,) edge endpoints (rows >= ``m_real`` are inert padding);
     ``Es``: (n_pad+1,) CSR offsets; ``Eo``: (n_pad,).  Returns
@@ -210,21 +211,21 @@ def _build_support_table_dev(u, v, Es, Eo, m_real, *, m: int, size: int):
     ar = jnp.arange(m, dtype=jnp.int32)
     cnt = jnp.where(ar < m_real, Es[v + 1] - Eo[v], 0)
     off = jnp.zeros((m + 1,), jnp.int32).at[1:].set(jnp.cumsum(cnt))
-    e1, e1c, intra, valid = _expand_segments(off, size, m)
+    e1, e1c, intra, valid = _expand_segments(off, size, m, start)
     cand = jnp.where(valid, Eo[v[e1c]] + intra, 0)
     lo = jnp.where(valid, Eo[u[e1c]], 0)
     hi = jnp.where(valid, Es[u[e1c] + 1], 0)
     return e1, cand, lo, hi, off
 
 
-@functools.partial(jax.jit, static_argnames=("m", "size", "chunk"))
-def _build_peel_table_dev(u, v, Es, m_real, *, m: int, size: int, chunk: int):
-    """Device mirror of ``build_peel_table`` + per-edge chunk-range metadata.
+def peel_rows(u, v, Es, m_real, *, m: int, size: int, chunk: int, start=0):
+    """Trace-level ``build_peel_table`` rows + per-edge chunk metadata.
 
     Same row semantics as the host builder (candidates from the
-    min-degree endpoint's full adjacency, probes against the other); also
-    emits the ``chunk_ranges`` bookkeeping for the given static ``chunk`` so
-    the peel loop's chunk-skipping needs no host pass.  Returns
+    min-degree endpoint's full adjacency, probes against the other), for
+    rows ``[start, start + size)``; also emits the ``chunk_ranges``
+    bookkeeping of the *whole* table for the given static ``chunk`` so the
+    peel loop's chunk-skipping needs no host pass.  Returns
     ``(e1, cand_slot, lo, hi, off, c_start, c_end, has_entries)``.
     """
     deg = Es[1:] - Es[:-1]
@@ -234,7 +235,7 @@ def _build_peel_table_dev(u, v, Es, m_real, *, m: int, size: int, chunk: int):
     ar = jnp.arange(m, dtype=jnp.int32)
     cnt = jnp.where(ar < m_real, deg[cand_v], 0)
     off = jnp.zeros((m + 1,), jnp.int32).at[1:].set(jnp.cumsum(cnt))
-    e1, e1c, intra, valid = _expand_segments(off, size, m)
+    e1, e1c, intra, valid = _expand_segments(off, size, m, start)
     cand = jnp.where(valid, Es[cand_v[e1c]] + intra, 0)
     lo = jnp.where(valid, Es[prob_v[e1c]], 0)
     hi = jnp.where(valid, Es[prob_v[e1c] + 1], 0)
@@ -242,6 +243,18 @@ def _build_peel_table_dev(u, v, Es, m_real, *, m: int, size: int, chunk: int):
     c_start = off[:-1] // chunk
     c_end = jnp.maximum(off[1:] - 1, 0) // chunk
     return e1, cand, lo, hi, off, c_start, c_end, has
+
+
+@functools.partial(jax.jit, static_argnames=("m", "size"))
+def _build_support_table_dev(u, v, Es, Eo, m_real, *, m: int, size: int):
+    """Device mirror of ``build_support_table`` at static padded ``size``."""
+    return support_rows(u, v, Es, Eo, m_real, m=m, size=size)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "size", "chunk"))
+def _build_peel_table_dev(u, v, Es, m_real, *, m: int, size: int, chunk: int):
+    """Device mirror of ``build_peel_table`` + per-edge chunk-range metadata."""
+    return peel_rows(u, v, Es, m_real, m=m, size=size, chunk=chunk)
 
 
 def support_from_table_arrays(e1, cand, lo, hi, N, Eid, *, m: int, mode: str,
@@ -354,7 +367,9 @@ def compute_support(g: CSRGraph, table: WedgeTable | None = None, *,
     forces/forbids interpret mode, default off-TPU).  ``table_mode`` selects
     where the wedge table is constructed (``TABLE_MODES``): "device" (the
     default when no prebuilt ``table`` is passed) runs the jitted XLA
-    builder, "numpy" the original host builder.
+    builder, "numpy" the original host builder.  On a TPU backend
+    ``mode="pallas"`` and ``interpret=True`` raise ``NotImplementedError``
+    (``kernels.wedge_common.resolve_interpret``).
     """
     if mode not in SUPPORT_MODES:
         raise ValueError(f"mode must be one of {SUPPORT_MODES}, got {mode!r}")
@@ -363,11 +378,10 @@ def compute_support(g: CSRGraph, table: WedgeTable | None = None, *,
     if table_mode not in TABLE_MODES:
         raise ValueError(
             f"table_mode must be one of {TABLE_MODES}, got {table_mode!r}")
+    interpret = resolve_interpret(interpret, support_mode=mode)
     if g.m == 0:
         return np.zeros(0, np.int32)
     if table_mode == "device" and table is None:
-        if interpret is None:
-            interpret = interpret_default()
         return np.asarray(
             _support_device(g, mode=mode, chunk=chunk, interpret=interpret))
     if table is None:
@@ -378,8 +392,6 @@ def compute_support(g: CSRGraph, table: WedgeTable | None = None, *,
     if mode == "pallas":
         from repro.kernels.support import support_counts
 
-        if interpret is None:
-            interpret = interpret_default()
         chunk_eff, n_chunks = chunk_layout(table.size, chunk)
         e1, cand, lo, hi = pad_chunked(
             table.e1, table.cand_slot, table.lo, table.hi,

@@ -428,6 +428,8 @@ class IncrementalTruss:
     Raises:
         ValueError: unknown mode axis, invalid edge array, or
             out-of-range ``local_frac``.
+        NotImplementedError: a Pallas executor or ``interpret=True`` on a
+            TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
 
     def __init__(self, edges, *, n: int | None = None, mode: str = "chunked",
@@ -471,8 +473,8 @@ class IncrementalTruss:
                       else wedge_common.next_pow2(chunk))
         self.local_frac = float(local_frac)
         self.host_peel_max = int(host_peel_max)
-        self.interpret = (wedge_common.interpret_default()
-                          if interpret is None else interpret)
+        self.interpret = wedge_common.resolve_interpret(
+            interpret, peel_mode=mode, support_mode=support_mode)
         self.stats = {"updates": 0, "local": 0, "full": 0, "noop": 0,
                       "update_seconds": 0.0, "last": None}
         E, _, _, n_seen = canonical_edges_with_rows(edges)

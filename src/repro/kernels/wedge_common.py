@@ -49,6 +49,32 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def resolve_interpret(interpret: bool | None, *, peel_mode: str = "chunked",
+                      support_mode: str = "jnp") -> bool:
+    """The interpret flag for an entry point; refuses Pallas on a TPU.
+
+    Neither Pallas kernel lowers for the TPU yet: Mosaic refuses their
+    rank-1 blocks, 1-D gathers and in-kernel scatter-adds (ROADMAP Speed
+    2).  On a TPU backend a Pallas executor, or interpret mode, is refused
+    here with ``NotImplementedError`` — before any work starts, instead of
+    failing inside Mosaic mid-run or running the interpreter on the chip.
+    Off the TPU ``interpret`` defaults to True, as before.
+    """
+    if interpret_default():
+        return True if interpret is None else bool(interpret)
+    refused = [f"{axis}='pallas'" for axis, mode in
+               (("mode", peel_mode), ("support_mode", support_mode))
+               if mode == "pallas"]
+    if interpret:
+        refused.append("interpret=True")
+    if refused:
+        raise NotImplementedError(
+            f"{', '.join(refused)} is not supported on a TPU backend: the "
+            f"Pallas kernels do not lower for TPU yet (ROADMAP Speed 2); use "
+            f"the default XLA executors (mode='chunked', support_mode='jnp')")
+    return False
+
+
 def next_pow2(x: int) -> int:
     """Smallest power of two >= x (>= 1)."""
     return 1 << max(0, int(x - 1).bit_length())

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.configs import (ARCHS, SHAPES, get_config, input_specs,
                            cell_is_valid)
@@ -222,7 +221,7 @@ def lower_cell(cfg, shape, mesh, *, microbatches=1, want_hlo=False,
         compiled = lowered.compile()
     dt = time.time() - t0
     ma = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     rec = {
@@ -318,11 +317,13 @@ def truss_cell(mesh, *, log_m: int = 27, chunk: int = 1 << 14) -> dict:
     i32 = jnp.int32
     N = sds((two_m,), i32)
     Eid = sds((two_m,), i32)
-    S0 = sds((m,), i32)
     e1 = sds((tab,), i32)
     cs = sds((tab,), i32)
     lo = sds((tab,), i32)
     hi = sds((tab,), i32)
+
+    S0 = sds((m,), i32)
+    meta = (sds((m,), i32), sds((m,), i32), sds((m,), jnp.bool_))
 
     rec = {}
     sup = make_support_dist(mesh, axes, m=m, iters=20)
@@ -330,7 +331,7 @@ def truss_cell(mesh, *, log_m: int = 27, chunk: int = 1 << 14) -> dict:
         t0 = time.time()
         c = sup.lower(N, Eid, e1, cs, lo, hi).compile()
         ma = c.memory_analysis()
-        ca = cost_analysis(c)
+        ca = c.cost_analysis() or {}
         rec["support"] = {
             "compile_s": round(time.time() - t0, 2),
             "temp_bytes": int(ma.temp_size_in_bytes),
@@ -339,10 +340,9 @@ def truss_cell(mesh, *, log_m: int = 27, chunk: int = 1 << 14) -> dict:
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
             "collectives": parse_collectives(c.as_text()),
         }
-        peel = make_pkt_dist(mesh, axes, m=m, two_m=two_m, table_size=tab,
-                             iters=20, chunk=chunk)
+        peel = make_pkt_dist(mesh, axes, m=m, iters=20, chunk=chunk)
         t0 = time.time()
-        c2 = peel.lower(N, Eid, S0, e1, cs, lo, hi).compile()
+        c2 = peel.lower(N, Eid, S0, e1, cs, lo, hi, *meta).compile()
         ma2 = c2.memory_analysis()
         rec["peel_loop"] = {
             "compile_s": round(time.time() - t0, 2),

@@ -9,8 +9,11 @@ with gradient all-reduce over the (slow) cross-pod links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

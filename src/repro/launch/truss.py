@@ -52,9 +52,10 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.graphs.datasets import named_graph
 from repro.graphs.csr import build_csr, relabel, degeneracy_order
-from repro.kernels.wedge_common import pow2_chunk
+from repro.kernels.wedge_common import resolve_interpret
 from repro.core import (pkt, truss_wc, truss_ros, truss_trilist, truss_numpy,
                         pkt_dist)
 
@@ -229,32 +230,18 @@ def run_update_stream(args) -> None:
             raise SystemExit(1)
 
 
-def run_serve(args) -> None:
-    """Replay paced mixed traffic through the async scheduler (``--serve``).
+def serve_schedule(E: np.ndarray, n: int, requests: int, seed: int) -> list:
+    """A deterministic 90/9/1 query/update/open request schedule.
 
-    Opens the named graph as a persistent handle, then replays ``--serve``
-    requests at ``--qps`` in the 90/9/1 query/update/open serving mix
-    (DESIGN.md §12): trussness queries on base rows, churn updates toggling
-    a reserved extra-edge pool (so queried rows always exist), and opens of
-    small fresh graphs.  Prints per-kind latency and the scheduler's stage
-    breakdown; ``--verify`` replays the same schedule through a synchronous
-    engine and checks every result bitwise.
-
-    With ``--fault-rate`` a seeded ``FaultPlan`` injects dispatch faults
-    during the replay (DESIGN.md §15): completed requests stay bitwise
-    parity-checked, failed ones are masked from the sync replay (their
-    updates never committed — commit is batch-scoped).
+    Queries read 8 base rows; updates toggle edges of a reserved pool of
+    32 absent edges, disjoint from the base rows the queries sample, so
+    both an async and a sync replay of the schedule stay valid; opens carry
+    small fresh Erdős–Rényi graphs.  Generation tracks pool presence so
+    removals always hit present edges.
     """
-    import contextlib
-
     from repro.graphs.gen import erdos_renyi_edges
-    from repro.serve.scheduler import TrussScheduler
 
-    E = named_graph(args.graph)
-    n = int(E.max()) + 1
-    rng = np.random.default_rng(args.update_seed)
-    # reserved churn pool: absent edges the updates toggle, disjoint from
-    # the base rows the queries sample (keeps both replays valid)
+    rng = np.random.default_rng(seed)
     present = {(int(u), int(v)) for u, v in E}
     pool = []
     while len(pool) < 32:
@@ -262,32 +249,8 @@ def run_serve(args) -> None:
         if u != v and (min(u, v), max(u, v)) not in present:
             pool.append((min(u, v), max(u, v)))
             present.add(pool[-1])
-
-    # a replay measures latency, not shedding: admit the whole schedule
-    sched = TrussScheduler(
-        max_batch=args.max_batch, max_delay_ms=args.max_delay_ms,
-        max_queue=max(256, 4 * args.serve),
-        max_inflight=max(64, 4 * args.serve),
-        deadline_ms=args.deadline_ms,
-        mode=args.mode, support_mode=args.support_mode,
-        table_mode=args.table_mode, hier_mode=args.hier_mode,
-        insert_mode=args.insert_mode,
-        chunk=args.chunk)
-    t0 = time.perf_counter()
-    h = sched.open_async(E, local_frac=args.local_frac).result()
-    print(f"graph={args.graph} n={n} m={h.m} open "
-          f"{time.perf_counter() - t0:.3f}s qps={args.qps} "
-          f"mix=90/9/1 query/update/open fault_rate={args.fault_rate}")
-
-    plan = None
-    if args.fault_rate > 0.0:
-        from repro.testing.chaos import FaultPlan
-        plan = FaultPlan.uniform(args.fault_rate, seed=args.update_seed)
-
-    # deterministic schedule (generation tracks pool presence so removals
-    # always hit present edges)
     ops, in_pool, n_open = [], set(), 0
-    for _ in range(args.serve):
+    for _ in range(requests):
         r = rng.random()
         if r < 0.90:
             ops.append(("query", E[rng.integers(0, E.shape[0], size=8)]))
@@ -302,44 +265,132 @@ def run_serve(args) -> None:
                         np.array(rem or np.zeros((0, 2)), np.int64)))
         else:
             ops.append(("open", erdos_renyi_edges(
-                64, 8.0, seed=args.update_seed + 5000 + n_open)))
+                64, 8.0, seed=seed + 5000 + n_open)))
             n_open += 1
+    return ops
 
+
+def replay_schedule(sched, handle, ops: list, qps: float):
+    """Replay ``ops`` through ``sched`` at ``qps`` against ``handle``.
+
+    Returns ``(outcomes, latencies, seconds)``: per op ``("ok", result)``
+    or ``("failed", exception)`` in schedule order, ``(kind, seconds)``
+    enqueue-to-completion latencies, and the replay's wall time.
+    """
     lat, futs = [], []
-    with plan if plan is not None else contextlib.nullcontext():
-        t_start = time.perf_counter()
-        for i, op in enumerate(ops):
-            target = t_start + i / args.qps
-            if target > time.perf_counter():
-                time.sleep(target - time.perf_counter())
-            t_enq = time.perf_counter()
-            if op[0] == "query":
-                f = sched.query_async(h, op[1])
-            elif op[0] == "update":
-                f = sched.update_async(h, add_edges=op[1],
-                                       remove_edges=op[2])
-            else:
-                f = sched.open_async(op[1])
-            f.add_done_callback(lambda f, k=op[0], t=t_enq:
-                                lat.append((k, time.perf_counter() - t)))
-            futs.append(f)
-        outcomes = []
-        for f in futs:
-            try:
-                outcomes.append(("ok", f.result()))
-            except Exception as e:  # noqa: BLE001 — typed, classified below
-                outcomes.append(("failed", e))
-        duration = time.perf_counter() - t_start
-    st = sched.stats()
-    sched.close()
+    t_start = time.perf_counter()
+    for i, op in enumerate(ops):
+        target = t_start + i / qps
+        if target > time.perf_counter():
+            time.sleep(target - time.perf_counter())
+        t_enq = time.perf_counter()
+        if op[0] == "query":
+            f = sched.query_async(handle, op[1])
+        elif op[0] == "update":
+            f = sched.update_async(handle, add_edges=op[1], remove_edges=op[2])
+        else:
+            f = sched.open_async(op[1])
+        f.add_done_callback(lambda f, k=op[0], t=t_enq:
+                            lat.append((k, time.perf_counter() - t)))
+        futs.append(f)
+    outcomes = []
+    for f in futs:
+        try:
+            outcomes.append(("ok", f.result()))
+        except Exception as e:  # noqa: BLE001 — typed, classified by callers
+            outcomes.append(("failed", e))
+    return outcomes, lat, time.perf_counter() - t_start
 
+
+def latency_percentiles(lat: list) -> dict:
+    """Per request kind: ``{"n", "p50_ms", "p99_ms", "max_ms"}``."""
+    out = {}
     for kind in ("query", "update", "open"):
         ms = sorted(1e3 * s for k, s in lat if k == kind)
         if ms:
-            print(f"{kind:6s} n={len(ms):4d} "
-                  f"p50={ms[len(ms) // 2]:.2f}ms "
-                  f"p99={ms[min(len(ms) - 1, int(0.99 * len(ms)))]:.2f}ms "
-                  f"max={ms[-1]:.2f}ms")
+            out[kind] = {"n": len(ms), "p50_ms": ms[len(ms) // 2],
+                         "p99_ms": ms[min(len(ms) - 1, int(0.99 * len(ms)))],
+                         "max_ms": ms[-1]}
+    return out
+
+
+def verify_replay(E: np.ndarray, ops: list, outcomes: list, handle, *,
+                  local_frac: float = 0.25, **engine_kwargs):
+    """Replay ``ops`` synchronously through a fresh ``TrussEngine``.
+
+    Every completed async result is compared bitwise with the synchronous
+    one, and the final handle trussness with the sync handle's; failed ops
+    are masked (their updates never committed — commit is batch-scoped).
+    Returns ``(ok, sync_handle)``.
+    """
+    from repro.serve.truss_engine import TrussEngine
+
+    eng = TrussEngine(**engine_kwargs)
+    hs = eng.open(E, local_frac=local_frac)
+    ok = True
+    for op, (status, got) in zip(ops, outcomes):
+        if status != "ok":
+            continue
+        if op[0] == "query":
+            ok = ok and np.array_equal(got, hs.query(op[1]))
+        elif op[0] == "update":
+            eng.update(hs, add_edges=op[1], remove_edges=op[2])
+        else:
+            ok = ok and np.array_equal(got.trussness,
+                                       eng.open(op[1]).trussness)
+    ok = ok and np.array_equal(handle.trussness, hs.trussness)
+    return bool(ok), hs
+
+
+def run_serve(args) -> None:
+    """Replay paced mixed traffic through the async scheduler (``--serve``).
+
+    Opens the named graph as a persistent handle, then replays ``--serve``
+    requests at ``--qps`` in the 90/9/1 query/update/open serving mix
+    (DESIGN.md §12, ``serve_schedule``).  Prints per-kind latency and the
+    scheduler's stage breakdown; ``--verify`` replays the same schedule
+    through a synchronous engine and checks every result bitwise.
+
+    With ``--fault-rate`` a seeded ``FaultPlan`` injects dispatch faults
+    during the replay (DESIGN.md §15): completed requests stay bitwise
+    parity-checked, failed ones are masked from the sync replay.
+    """
+    import contextlib
+
+    from repro.serve.scheduler import TrussScheduler
+
+    E = named_graph(args.graph)
+    n = int(E.max()) + 1
+    engine_kwargs = dict(mode=args.mode, support_mode=args.support_mode,
+                         table_mode=args.table_mode, hier_mode=args.hier_mode,
+                         chunk=args.chunk)
+    # a replay measures latency, not shedding: admit the whole schedule
+    sched = TrussScheduler(
+        max_batch=args.max_batch, max_delay_ms=args.max_delay_ms,
+        max_queue=max(256, 4 * args.serve),
+        max_inflight=max(64, 4 * args.serve),
+        deadline_ms=args.deadline_ms, insert_mode=args.insert_mode,
+        **engine_kwargs)
+    t0 = time.perf_counter()
+    h = sched.open_async(E, local_frac=args.local_frac).result()
+    print(f"graph={args.graph} n={n} m={h.m} open "
+          f"{time.perf_counter() - t0:.3f}s qps={args.qps} "
+          f"mix=90/9/1 query/update/open fault_rate={args.fault_rate}")
+
+    plan = None
+    if args.fault_rate > 0.0:
+        from repro.testing.chaos import FaultPlan
+        plan = FaultPlan.uniform(args.fault_rate, seed=args.update_seed)
+
+    ops = serve_schedule(E, n, args.serve, args.update_seed)
+    with plan if plan is not None else contextlib.nullcontext():
+        outcomes, lat, duration = replay_schedule(sched, h, ops, args.qps)
+    st = sched.stats()
+    sched.close()
+
+    for kind, p in latency_percentiles(lat).items():
+        print(f"{kind:6s} n={p['n']:4d} p50={p['p50_ms']:.2f}ms "
+              f"p99={p['p99_ms']:.2f}ms max={p['max_ms']:.2f}ms")
     print(f"achieved {len(ops) / duration:.0f} qps "
           f"(offered {args.qps:.0f}); dispatches="
           f"{st['counters']['dispatches']} "
@@ -371,25 +422,8 @@ def run_serve(args) -> None:
                         for site, r in st["resilience"].items()))
 
     if args.verify:
-        from repro.serve.truss_engine import TrussEngine
-
-        eng = TrussEngine(mode=args.mode, support_mode=args.support_mode,
-                          table_mode=args.table_mode,
-                          hier_mode=args.hier_mode,
-                          chunk=args.chunk)
-        hs = eng.open(E, local_frac=args.local_frac)
-        ok = True
-        for op, (status, got) in zip(ops, outcomes):
-            if status != "ok":
-                continue            # failed ops never committed: masked
-            if op[0] == "query":
-                ok = ok and np.array_equal(got, hs.query(op[1]))
-            elif op[0] == "update":
-                eng.update(hs, add_edges=op[1], remove_edges=op[2])
-            else:
-                ok = ok and np.array_equal(got.trussness,
-                                           eng.open(op[1]).trussness)
-        ok = ok and np.array_equal(h.trussness, hs.trussness)
+        ok, _ = verify_replay(E, ops, outcomes, h, local_frac=args.local_frac,
+                              **engine_kwargs)
         print("verify async vs sync engine (failed ops masked):",
               "OK" if ok else "MISMATCH")
         if not ok:
@@ -413,8 +447,9 @@ def run_query_communities(args) -> None:
 
 
 def main(argv=None):
-    # env tuning must act before any heavy jax work; re-exec only on a real
-    # CLI invocation (tests pass argv explicitly and must not exec away)
+    # env tuning must act before any JAX backend initialises (importing
+    # this module initialises none); re-exec only on a real CLI invocation
+    # (tests pass argv explicitly and must not exec away)
     raw = sys.argv[1:] if argv is None else argv
     if "--tune-env" in raw:
         apply_env_tuning(reexec=argv is None)
@@ -487,6 +522,10 @@ def main(argv=None):
                     help="per-request deadline for --serve; expired "
                          "requests fail with a typed DeadlineExceeded")
     args = ap.parse_args(argv)
+    # refuse Pallas executors on a TPU before any graph is built
+    resolve_interpret(None, peel_mode=args.mode,
+                      support_mode=args.support_mode)
+    enable_compile_cache()
 
     if args.serve:
         return run_serve(args)
@@ -515,8 +554,7 @@ def main(argv=None):
         extra = (f"levels={res.levels} sublevels={res.sublevels} "
                  f"compactions={res.compactions}")
     elif args.engine == "dist":
-        truss = pkt_dist(g, chunk=pow2_chunk(1 << 12,
-                                             args.chunk or (1 << 12)),
+        truss = pkt_dist(g, chunk=args.chunk,
                          support_mode=args.support_mode,
                          table_mode=args.table_mode)
         extra = ""

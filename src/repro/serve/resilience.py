@@ -15,7 +15,9 @@ top of the engine's existing exception safety:
   rung (pallas → jnp → host-numpy); after enough consecutive successes
   at a demoted rung the ladder *probes* the faster rung on live
   traffic — probe failures fall back silently without charging the
-  request — and re-promotes after consecutive probe successes;
+  request — and re-promotes after consecutive probe successes.  Every
+  demotion logs a WARNING naming the site, the new rung and the
+  exception that caused it, so a fault never hides behind a slower rung;
 - **deadline enforcement**: an absolute deadline aborts the retry loop
   (and any pending backoff sleep) with a typed :class:`DeadlineExceeded`.
 
@@ -26,11 +28,14 @@ executor axes, so degradation never changes results — only latency.
 from __future__ import annotations
 
 import contextlib
+import logging
 import time
 import zlib
 from dataclasses import dataclass
 
 from repro.core.truss_inc import IntegrityError
+
+log = logging.getLogger(__name__)
 
 #: exception types never retried: caller bugs, integrity violations
 #: (healed at a higher layer), and deadline aborts
@@ -249,7 +254,13 @@ def run_with_resilience(
             if not is_transient(e):
                 raise
             site = getattr(e, "site", None)
-            ladders.get(site, ladders[primary]).record_failure()
+            name = site if site in ladders else primary
+            ladder = ladders[name]
+            demotions = ladder.demotions
+            ladder.record_failure()
+            if ladder.demotions != demotions:
+                log.warning("%s ladder demoted to rung %r after %s: %s",
+                            name, ladder.current(), type(e).__name__, e)
             attempt += 1
             if attempt > policy.max_retries:
                 raise

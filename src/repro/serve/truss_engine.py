@@ -359,6 +359,8 @@ class TrussEngine:
     Raises:
         ValueError: unknown mode axis, or non-positive ``chunk`` /
             ``max_edges``.
+        NotImplementedError: a Pallas executor or ``interpret=True`` on a
+            TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
 
     def __init__(self, *, mode: str = "chunked", support_mode: str = "jnp",
@@ -394,8 +396,8 @@ class TrussEngine:
         self.chunk = None if chunk is None else _next_pow2(chunk)
         self.reorder = reorder
         self.max_pending = max_pending
-        self.interpret = (wedge_common.interpret_default()
-                          if interpret is None else interpret)
+        self.interpret = wedge_common.resolve_interpret(
+            interpret, peel_mode=mode, support_mode=support_mode)
         self._pending: list[_Pending] = []
         self._results: dict[int, np.ndarray] = {}
         self._next_ticket = 0

@@ -18,9 +18,10 @@ def run_py(code: str, devices: int = DEVICES, timeout: int = 560) -> str:
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     # Pin the subprocess to the CPU platform: the device-count flag only
-    # multiplies *host* devices, and letting jax probe for accelerators makes
-    # images that bundle libtpu burn ~8 minutes per subprocess retrying GCP
-    # metadata fetches before falling back to CPU.
+    # multiplies *host* devices, and a child that probed for the TPU would
+    # contend with the parent for it (one process per chip).  On a TPU
+    # machine the distributed path runs in one process instead:
+    # ``python chip_smoke.py --four-chips``.
     env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
@@ -125,7 +126,7 @@ def test_dryrun_cells_on_tiny_mesh():
     arch on an 8-device (2x4) mesh — the same code path as the 512-chip run."""
     out = run_py("""
 import numpy as np, jax, dataclasses
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import reduced_config
 import repro.configs as C
 import repro.launch.dryrun as DR
