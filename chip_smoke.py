@@ -5,26 +5,30 @@
     python chip_smoke.py --four-chips   # pkt_dist over four chips
     JAX_PLATFORMS=cpu python chip_smoke.py --rehearse [--four-chips]
 
-One chip (the default) runs two phases in this one process:
+One chip (the default) runs two phases in this one process, both on
+``rmat-medium`` (Graph500-style R-MAT, scale 15, edge factor 8;
+m = 234,101, both wedge tables padded to 2^25 rows):
 
-* one-shot decomposition: ``rmat-medium`` (Graph500-style R-MAT, scale 15,
-  edge factor 8; m = 234,101, both wedge tables padded to 2^25 rows) is
-  degeneracy-ordered and decomposed by ``pkt`` on the default executors,
-  once cold and once warm; its trussness must equal the host Wang–Cheng
-  reference (``core/wc.py``) bitwise and the warm run must compile nothing;
-* serving: ``rmat-small`` (scale 12, m = 26,726) is opened through a
-  ``TrussScheduler``, a paced 90/9/1 query/update/open mix is replayed
-  through it, then one ``communities_async(h, 4)`` is answered; every
-  result must equal a synchronous ``TrussEngine`` replay bitwise, with no
-  failed request, no retry and no degradation-ladder demotion.
+* one-shot decomposition: the graph is degeneracy-ordered and decomposed
+  by ``pkt`` on the default executors, once cold and once warm; its
+  trussness must equal the host Wang–Cheng reference (``core/wc.py``)
+  bitwise and the warm run must compile nothing;
+* serving: the graph is opened through a ``TrussScheduler``, 200 requests
+  of the 90/9/1 query/update/open mix are replayed through it at 5 offered
+  qps, then one ``communities_async(h, 4)`` is answered; every result must
+  equal a synchronous ``TrussEngine`` replay bitwise, with no failed
+  request, no retry and no degradation-ladder demotion.
 
-The sizes are what fits the run's 1200 s limit on one v5e: ``rmat-large``
-(scale 17) takes about 1000 s per warm ``pkt`` there, so it runs only on
-request (``--graph rmat-large``).
+``rmat-medium`` is the largest R-MAT graph whose phases fit the run's
+1200 s limit on one v5e: a warm ``pkt`` of ``rmat-large`` (scale 17)
+takes about 1000 s there.  The offered rate stays under what one
+``rmat-medium`` handle sustains (about 7 qps), so requests do not queue
+without bound.
 
-``--four-chips`` runs only the distributed path: ``pkt_dist`` over a
-4-device mesh, checked bitwise against Wang–Cheng, with the per-device
-peak memory showing the wedge tables split across devices.
+``--four-chips`` runs only the distributed path: ``pkt_dist`` on
+``rmat-medium`` over a 4-device mesh, checked bitwise against Wang–Cheng,
+with the per-device peak memory showing the wedge tables split across
+devices.
 
 Without a TPU the script exits non-zero and prints no result.
 ``--rehearse`` shrinks the graphs and accepts any platform, naming the
@@ -45,9 +49,9 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
-#: (one-shot / four-chip graph, serving graph, serving requests, qps)
-FULL = ("rmat-medium", "rmat-small", 200, 20.0)
-REHEARSE = ("rmat-small", "rmat-tiny", 60, 100.0)
+#: (graph of every phase, serving requests, offered qps)
+FULL = ("rmat-medium", 200, 5.0)
+REHEARSE = ("rmat-tiny", 60, 100.0)
 
 
 class CompileCounter:
@@ -256,9 +260,6 @@ def main(argv=None) -> int:
                     help="run only pkt_dist over a 4-device mesh")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny graphs on whatever platform JAX finds")
-    ap.add_argument("--graph", default=None,
-                    help="named graph for the one-shot / four-chip phase "
-                         "(default: rmat-medium; rmat-small with --rehearse)")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.WARNING, stream=sys.stdout,
                         format="%(levelname)s %(name)s: %(message)s")
@@ -275,14 +276,13 @@ def main(argv=None) -> int:
     log(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
         f"compile cache: {enable_compile_cache()}")
     counter = CompileCounter()
-    graph, served, requests, qps = REHEARSE if args.rehearse else FULL
-    graph = args.graph or graph
+    graph, requests, qps = REHEARSE if args.rehearse else FULL
 
     if args.four_chips:
         fails = four_chips(graph)
     else:
         fails = one_shot(graph, counter, dev)
-        fails += serving(served, requests, qps)
+        fails += serving(graph, requests, qps)
     if fails:
         for f in fails:
             print(f"FAIL: {f}", file=sys.stderr)

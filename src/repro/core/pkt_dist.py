@@ -169,12 +169,6 @@ def _peel_rows_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
         out_specs=(P(axes),) * 4 + (P(),) * 3, check_vma=False))
 
 
-def _pad_to(x: np.ndarray, size: int, fill) -> np.ndarray:
-    out = np.full(size, fill, np.int32)
-    out[: x.shape[0]] = x
-    return out
-
-
 def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
              axes: Sequence[str] = ("data",), chunk: int | None = None,
              support_mode: str = "jnp", table_mode: str = "device",
@@ -238,7 +232,7 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
     else:
         stab = support_mod.build_support_table(g)
         ssize = per_shard * n_shards
-        s_tab = [jax.device_put(_pad_to(a, ssize, fill), sh) for a, fill in (
+        s_tab = [jax.device_put(wedge_common.pad1(a, ssize, fill), sh) for a, fill in (
             (stab.e1, g.m), (stab.cand_slot, 0), (stab.lo, 0), (stab.hi, 0))]
         sup_fn = make_support_dist(mesh, axes, m=g.m, iters=s_iters,
                                    mode=support_mode, chunk=sup_chunk,
@@ -262,7 +256,7 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
 
         ptab = support_mod.build_peel_table(g)
         has, c_start, c_end = chunk_ranges(ptab.off, chunk)
-        p_tab = [jax.device_put(_pad_to(a, psize, fill), sh) for a, fill in (
+        p_tab = [jax.device_put(wedge_common.pad1(a, psize, fill), sh) for a, fill in (
             (ptab.e1, g.m), (ptab.cand_slot, 0), (ptab.lo, 0), (ptab.hi, 0))]
         p_tab += [jax.device_put(x, rep) for x in (c_start, c_end, has)]
     peel_fn = make_pkt_dist(mesh, axes, m=g.m,
