@@ -9,7 +9,6 @@
   engine  — batched multi-graph throughput (graphs/sec)
   inc     — incremental update vs recompute speedup     (DESIGN.md §9)
   hier    — community-index build/query + label parity  (DESIGN.md §11)
-  roofline— measured phase GB/s vs the host copy ceiling (§16)
   hillclimb— chunk-policy autotune sweep (feeds auto_chunk, §16)
 
 ``--smoke`` is the CI gate: a tiny RMAT graph decomposed by every
@@ -152,7 +151,7 @@ def main(argv=None) -> None:
 
     from benchmarks import (table2_support, table3_decomp, table4_parallel,
                             fig4_phases, fig6_levels, engine_bench, inc_bench,
-                            hier_bench, roofline, hillclimb)
+                            hier_bench, hillclimb)
     benches = {
         "table2": lambda: table2_support.run(suite),
         "table3": lambda: table3_decomp.run(suite),
@@ -164,8 +163,6 @@ def main(argv=None) -> None:
         "fig6": lambda: fig6_levels.run(),
         "engine": lambda: engine_bench.run(
             n_graphs=12 if args.quick else 24),
-        "roofline": lambda: roofline.run(
-            ("ba-small",) if args.quick else None),
         "hillclimb": lambda: hillclimb.rows(quick=args.quick),
         "inc": lambda: inc_bench.rows(quick=args.quick),
         "hier": lambda: hier_bench.rows(quick=args.quick),

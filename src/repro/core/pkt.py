@@ -47,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.graphs.csr import CSRGraph, edge_keys
 from repro.core import support as support_mod
 from repro.kernels import wedge_common
@@ -80,10 +81,17 @@ class PKTResult:
     levels: int             # number of peel levels executed
     sublevels: int          # total sub-level iterations (paper's S)
     compactions: int = 0    # live-edge compactions performed (DESIGN.md §10)
-    #: phase wall-times {tables, support, peel, compact} — populated only
-    #: when ``pkt(..., phase_timings=True)`` (each phase is synced before
-    #: the clock is read, so attribution is honest but adds barriers)
+    chunk_visits: int = 0   # peel chunk bodies run, over every sub-level
+    #: phase wall-times {tables, support, peel, compact}: the host durations
+    #: of the phase spans, populated only when ``pkt(..., phase_timings=True)``
+    #: (each phase span then waits for its device work before it closes, so
+    #: attribution is honest but adds barriers)
     phases: dict | None = None
+
+
+#: ``pkt``'s phase spans, by the ``PKTResult.phases`` key each feeds
+_PHASE_SPANS = {"pkt.support": "support", "pkt.tables": "tables",
+                "pkt.peel_segment": "peel", "pkt.compact": "compact"}
 
 
 def chunk_ranges(off: np.ndarray, chunk: int,
@@ -225,7 +233,9 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
     ``S_ext0``/``processed0`` define which slots are live: slot m must be the
     processed sentinel, and callers may pre-mark extra padding slots as
     processed (batched engine).  Returns (S_ext, processed, levels,
-    sublevels) — the full extended state, so segmented callers can resume.
+    sublevels, chunk_visits) — the full extended state, so segmented
+    callers can resume, and the chunk bodies the peel ran: every chunk per
+    sub-level in dense mode, the frontier's active chunks otherwise.
 
     ``pinned`` (optional (m+1,) bool) marks *schedule* edges: they enter the
     frontier and process their triangles at exactly their initial support
@@ -270,15 +280,19 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
         return dec
 
     def sublevel(S_ext, processed, inCurr, l):
-        """One ProcessSubLevel: aggregate decrements, apply, mark processed."""
+        """One ProcessSubLevel: aggregate decrements, apply, mark processed.
+
+        Also returns the number of chunk bodies it ran."""
         dec0 = jnp.zeros((m + 1,), jnp.int32)
         if mode == "dense":
             def body(c, dec):
                 return chunk_contrib(c, dec, S_ext, processed, inCurr, l)
             dec = jax.lax.fori_loop(0, n_chunks, body, dec0)
+            visits = jnp.int32(n_chunks)
         elif mode == "pallas":
             from repro.kernels.peel import peel_decrement_fold
             active = _active_chunk_mask(inCurr, tabs, m, n_chunks)
+            visits = jnp.sum(active.astype(jnp.int32))
             pin = (jnp.zeros((m + 1,), jnp.int32) if pinned is None
                    else pinned.astype(jnp.int32))
             dec = peel_decrement_fold(
@@ -306,6 +320,7 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
                 return i + 1, body(i, dec)
 
             _, dec = jax.lax.while_loop(cond, wbody, (jnp.int32(0), dec0))
+            visits = n_active
         if reduce is not None:
             dec = reduce(dec)
 
@@ -315,28 +330,27 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
         processed = processed | inCurr
         inCurr = (~processed) & (S_ext == l)
         inCurr = inCurr.at[m].set(False)
-        return S_ext, processed, inCurr
+        return S_ext, processed, inCurr, visits
 
     def level_body(state):
-        S_ext, processed, l_done, todo, levels, subs = state
+        S_ext, processed, l_done, todo, levels, subs, visits = state
         alive_S = jnp.where(processed, _SENTINEL_S, S_ext)
         l = jnp.min(alive_S)  # skip-ahead to next populated level
         inCurr = (~processed) & (S_ext == l)
         inCurr = inCurr.at[m].set(False)
 
         def sub_cond(st):
-            _, _, inC, subs_ = st
-            return jnp.any(inC)
+            return jnp.any(st[2])
 
         def sub_body(st):
-            S_ext, processed, inC, subs_ = st
-            S_ext, processed, inC = sublevel(S_ext, processed, inC, l)
-            return S_ext, processed, inC, subs_ + 1
+            S_ext, processed, inC, subs_, visits_ = st
+            S_ext, processed, inC, v = sublevel(S_ext, processed, inC, l)
+            return S_ext, processed, inC, subs_ + 1, visits_ + v
 
-        S_ext, processed, _, subs = jax.lax.while_loop(
-            sub_cond, sub_body, (S_ext, processed, inCurr, subs))
+        S_ext, processed, _, subs, visits = jax.lax.while_loop(
+            sub_cond, sub_body, (S_ext, processed, inCurr, subs, visits))
         todo = (m + 1) - jnp.sum(processed.astype(jnp.int32))
-        return S_ext, processed, l, todo, levels + 1, subs
+        return S_ext, processed, l, todo, levels + 1, subs, visits
 
     stop = jnp.int32(0) if stop_live is None else stop_live
 
@@ -345,10 +359,10 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
 
     todo0 = (m + 1) - jnp.sum(processed0.astype(jnp.int32))
     state = (S_ext0, processed0, jnp.int32(0), todo0, jnp.int32(0),
-             jnp.int32(0))
-    S_ext, processed, _, _, levels, subs = jax.lax.while_loop(
+             jnp.int32(0), jnp.int32(0))
+    S_ext, processed, _, _, levels, subs, visits = jax.lax.while_loop(
         level_cond, level_body, state)
-    return S_ext, processed, levels, subs
+    return S_ext, processed, levels, subs, visits
 
 
 @functools.partial(
@@ -363,7 +377,7 @@ def _pkt_peel_jit(N, Eid, S0, tabs: PeelTables, *, m: int, chunk: int,
     # extended edge state: slot m is a sentinel (processed, never in frontier)
     S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
     processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
-    S_ext, _, levels, subs = _peel_loop(
+    S_ext, _, levels, subs, _ = _peel_loop(
         N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
         n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
     return S_ext[:m], levels, subs
@@ -379,13 +393,17 @@ def _peel_segment_jit(N, Eid, S_ext0, processed0, stop_live, pinned,
                       iters: int, mode: str, interpret: bool):
     """One compaction segment: peel until done or ≤ ``stop_live`` edges live.
 
-    The peel-state buffers are donated — each segment consumes its inputs,
-    so the driver's peak device memory is one state generation, not two.
+    Returns (S_ext, processed, counts): ``counts`` stacks levels,
+    sub-levels and chunk visits into one int32 vector, so
+    ``_segmented_peel`` reads them back in one transfer.  The peel-state
+    buffers are donated — each segment consumes its inputs, so peak device
+    memory holds one state generation, not two.
     """
-    return _peel_loop(N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-                      n_chunks=n_chunks, iters=iters, mode=mode,
-                      interpret=interpret, pinned=pinned,
-                      stop_live=stop_live)
+    S_ext, processed, levels, subs, visits = _peel_loop(
+        N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
+        n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret,
+        pinned=pinned, stop_live=stop_live)
+    return S_ext, processed, jnp.stack([levels, subs, visits])
 
 
 # --- live-edge compaction (DESIGN.md §10) -----------------------------------
@@ -480,19 +498,20 @@ def _make_subproblem(El_rows: np.ndarray, ids: np.ndarray,
 def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
                     interpret: bool, table_mode: str,
                     compact_frac: float | None, compact_min: int,
-                    chunk_req: int | None,
-                    timings: dict | None = None) -> tuple[int, int, int]:
+                    chunk_req: int | None) -> tuple[int, int, int, int]:
     """Run ``problem`` to the fixed point, compacting between segments.
 
     Each segment peels until ≤ ``compact_frac · m`` edges remain live (or to
     completion when compaction is off / the problem is below
     ``compact_min``); finished edges scatter their final S into ``out`` (at
     ``problem['ids']`` slots) and survivors are re-bucketed via
-    ``_make_subproblem``.  Returns (levels, sublevels, compactions).
+    ``_make_subproblem``.  Each segment, its readback included, is a
+    ``repro.pkt.peel_segment`` span carrying its levels, sub-levels and
+    chunk visits; each compaction is a ``repro.pkt.compact`` span.
+    Returns (levels, sublevels, chunk_visits, compactions).
     """
-    import time as _time
-
-    levels = subs = compactions = 0
+    counts = np.zeros(3, np.int64)
+    compactions = 0
     while True:
         m = problem["m"]
         n_live = problem["live"]
@@ -501,39 +520,37 @@ def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
             # clamp below the live count so every segment must retire at
             # least one level before the driver considers compacting again
             live_target = min(int(compact_frac * m), n_live - 1)
-        t0 = _time.perf_counter()
-        S_ext, processed, lv, sb = _peel_segment_jit(
-            problem["N"], problem["Eid"], problem["S_ext0"],
-            problem["processed0"], jnp.int32(live_target), problem["pinned"],
-            problem["tabs"], m=m, chunk=problem["chunk"],
-            n_chunks=problem["n_chunks"], iters=problem["iters"], mode=mode,
-            interpret=interpret)
-        S_np = np.asarray(S_ext)[:m]
-        proc_np = np.asarray(processed)[:m]
-        levels += int(lv)
-        subs += int(sb)
-        if timings is not None:
-            timings["peel"] = timings.get("peel", 0.0) + \
-                (_time.perf_counter() - t0)
+        with spans.span("pkt.peel_segment", segment=compactions, m_pad=m,
+                        live=n_live, chunk=problem["chunk"],
+                        n_chunks=problem["n_chunks"]) as sp:
+            S_ext, processed, seg = _peel_segment_jit(
+                problem["N"], problem["Eid"], problem["S_ext0"],
+                problem["processed0"], jnp.int32(live_target),
+                problem["pinned"], problem["tabs"], m=m,
+                chunk=problem["chunk"], n_chunks=problem["n_chunks"],
+                iters=problem["iters"], mode=mode, interpret=interpret)
+            S_np, proc_np, seg = jax.device_get((S_ext, processed, seg))
+            sp.set(levels=int(seg[0]), sublevels=int(seg[1]),
+                   chunk_visits=int(seg[2]))
+        counts += seg
+        S_np, proc_np = S_np[:m], proc_np[:m]
         ids = problem["ids"]
         live = ~proc_np
         dead = proc_np & (ids >= 0)
         out[ids[dead]] = S_np[dead]
         if not live.any():
-            return levels, subs, compactions
+            return (*(int(c) for c in counts), compactions)
         # ≤ live_target survivors: gather them into a compacted edge space
-        t0 = _time.perf_counter()
         compactions += 1
         live_idx = np.nonzero(live)[0]
         pin_np = problem["pinned_np"]
-        problem = _make_subproblem(
-            problem["El"][live_idx], ids[live_idx], S_np[live_idx],
-            None if pin_np is None else pin_np[:m][live_idx],
-            chunk_req=chunk_req, table_mode=table_mode)
+        with spans.span("pkt.compact", live=live_idx.shape[0]) as sp:
+            problem = _make_subproblem(
+                problem["El"][live_idx], ids[live_idx], S_np[live_idx],
+                None if pin_np is None else pin_np[:m][live_idx],
+                chunk_req=chunk_req, table_mode=table_mode)
+            sp.set(m_pad=problem["m"])
         assert problem["live"] < n_live  # compaction must strictly shrink
-        if timings is not None:
-            timings["compact"] = timings.get("compact", 0.0) + \
-                (_time.perf_counter() - t0)
 
 
 def peel_live_subset(El: np.ndarray, live_ids: np.ndarray,
@@ -619,20 +636,20 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             identical either way.
         compact_min: minimum live-edge count for compaction to trigger.
         phase_timings: populate ``PKTResult.phases`` with a
-            {tables, support, peel, compact} wall-time split (adds sync
-            barriers between phases).
+            {tables, support, peel, compact} wall-time split, read from
+            the phase spans (``repro.spans``); adds a sync barrier at the
+            end of each phase.
 
     Returns:
         :class:`PKTResult` — per-edge trussness (support + 2, aligned to
-        ``g.El`` rows), initial support, and loop/compaction counters.
+        ``g.El`` rows), initial support, and the level, sub-level,
+        chunk-visit and compaction counters.
 
     Raises:
         ValueError: unknown ``mode`` / ``support_mode`` / ``table_mode``.
         NotImplementedError: a Pallas executor or ``interpret=True`` on a
             TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
-    import time as _time
-
     mode = mode if peel_mode is None else peel_mode
     if mode not in PEEL_MODES:
         raise ValueError(f"mode must be one of {PEEL_MODES}, got {mode!r}")
@@ -647,71 +664,71 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
                          f"{support_mod.TABLE_MODES}, got {table_mode!r}")
     interpret = wedge_common.resolve_interpret(
         interpret, peel_mode=mode, support_mode=support_mode)
-    timings: dict | None = {} if phase_timings else None
     if g.m == 0:
         return PKTResult(np.zeros(0, np.int32), np.zeros(0, np.int32), 0, 0,
-                         phases=timings)
+                         phases=(dict.fromkeys(_PHASE_SPANS.values(), 0.0)
+                                 if phase_timings else None))
 
-    # ---- support phase -----------------------------------------------------
-    fault_point("support", rung=f"{support_mode}/{table_mode}")
-    if table_mode == "device" and support_table is None:
-        S0_dev = support_mod._support_device(
-            g, mode=support_mode, chunk=chunk, interpret=interpret,
-            timings=timings)
-        S0 = np.asarray(S0_dev)
-    else:
-        t0 = _time.perf_counter()
-        stab = (support_table if support_table is not None
-                else support_mod.build_support_table(g))
-        if timings is not None:
-            timings["tables"] = timings.get("tables", 0.0) + \
-                (_time.perf_counter() - t0)
-        t0 = _time.perf_counter()
-        S0 = support_mod.compute_support(
-            g, stab, mode=support_mode, chunk=chunk, interpret=interpret)
-        S0_dev = jnp.asarray(S0)
-        if timings is not None:
-            timings["support"] = timings.get("support", 0.0) + \
-                (_time.perf_counter() - t0)
+    with spans.span("pkt", m=g.m) as root:
+        # ---- support phase -------------------------------------------------
+        fault_point("support", rung=f"{support_mode}/{table_mode}")
+        if table_mode == "device" and support_table is None:
+            with spans.span("pkt.support") as sp:
+                S0_dev, rows = support_mod._support_device(
+                    g, mode=support_mode, chunk=chunk, interpret=interpret)
+                sp.set(padded_rows=rows)
+                S0 = np.asarray(S0_dev)   # the readback waits for the device
+        else:
+            with spans.span("pkt.tables", table="support") as sp:
+                stab = (support_table if support_table is not None
+                        else support_mod.build_support_table(g))
+                sp.set(rows=stab.size)
+            with spans.span("pkt.support", rows=stab.size):
+                S0 = support_mod.compute_support(
+                    g, stab, mode=support_mode, chunk=chunk,
+                    interpret=interpret)
+            S0_dev = jnp.asarray(S0)
 
-    # ---- peel tables -------------------------------------------------------
-    t0 = _time.perf_counter()
-    if table_mode == "device" and peel_table is None:
-        tabs, chunk_eff, n_chunks = prepare_peel_device(g, chunk)
-        if timings is not None:
-            tabs.e1.block_until_ready()
-    else:
-        ptab = (peel_table if peel_table is not None
-                else support_mod.build_peel_table(g))
-        tabs, chunk_eff, n_chunks = prepare_peel(ptab, g.m, chunk)
-    if timings is not None:
-        timings["tables"] = timings.get("tables", 0.0) + \
-            (_time.perf_counter() - t0)
+        # ---- peel tables ---------------------------------------------------
+        with spans.span("pkt.tables", table="peel") as sp:
+            if table_mode == "device" and peel_table is None:
+                tabs, chunk_eff, n_chunks = prepare_peel_device(g, chunk)
+            else:
+                ptab = (peel_table if peel_table is not None
+                        else support_mod.build_peel_table(g))
+                tabs, chunk_eff, n_chunks = prepare_peel(ptab, g.m, chunk)
+                sp.set(rows=ptab.size)
+            sp.set(padded_rows=chunk_eff * n_chunks, chunk=chunk_eff,
+                   n_chunks=n_chunks)
+            if phase_timings:
+                tabs.e1.block_until_ready()
 
-    # ---- segmented peel with live-edge compaction --------------------------
-    dev = g.device_arrays()
-    m = g.m
-    S_ext0 = jnp.concatenate(
-        [S0_dev.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
-    processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
-    problem = dict(
-        N=dev["N"], Eid=dev["Eid"], tabs=tabs, chunk=chunk_eff,
-        n_chunks=n_chunks, iters=support_mod._search_iters(g), m=m, live=m,
-        S_ext0=S_ext0, processed0=processed0, pinned=None, pinned_np=None,
-        El=g.El, ids=np.arange(m, dtype=np.int64))
-    S_out = np.zeros(m, np.int32)
-    levels, subs, compactions = _segmented_peel(
-        problem, S_out, mode=mode, interpret=interpret,
-        table_mode=table_mode, compact_frac=compact_frac,
-        compact_min=compact_min, chunk_req=chunk, timings=timings)
-    return PKTResult(
-        trussness=S_out.astype(np.int32) + 2,
-        support=S0,
-        levels=levels,
-        sublevels=subs,
-        compactions=compactions,
-        phases=timings,
-    )
+        # ---- segmented peel with live-edge compaction ----------------------
+        dev = g.device_arrays()
+        m = g.m
+        S_ext0 = jnp.concatenate(
+            [S0_dev.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
+        processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
+        problem = dict(
+            N=dev["N"], Eid=dev["Eid"], tabs=tabs, chunk=chunk_eff,
+            n_chunks=n_chunks, iters=support_mod._search_iters(g), m=m,
+            live=m, S_ext0=S_ext0, processed0=processed0, pinned=None,
+            pinned_np=None, El=g.El, ids=np.arange(m, dtype=np.int64))
+        S_out = np.zeros(m, np.int32)
+        levels, subs, visits, compactions = _segmented_peel(
+            problem, S_out, mode=mode, interpret=interpret,
+            table_mode=table_mode, compact_frac=compact_frac,
+            compact_min=compact_min, chunk_req=chunk)
+        return PKTResult(
+            trussness=S_out.astype(np.int32) + 2,
+            support=S0,
+            levels=levels,
+            sublevels=subs,
+            compactions=compactions,
+            chunk_visits=visits,
+            phases=(spans.seconds_by_name(root, _PHASE_SPANS)
+                    if phase_timings else None),
+        )
 
 
 def align_to_input(trussness: np.ndarray, g: CSRGraph,
@@ -772,19 +789,23 @@ def truss_pkt(edges: np.ndarray, *, reorder: bool = True,
     from repro.graphs.csr import (build_csr, canonical_edges_with_rows,
                                   degeneracy_order, edge_keys, relabel)
 
-    E, lo, hi, n = canonical_edges_with_rows(edges)
-    if E.size == 0:
-        return np.zeros(0, np.int64)
-    if reorder:
-        perm = degeneracy_order(E, n)
-        r_edges = relabel(E, perm)
-        rl, rh = perm[lo], perm[hi]
-        row_keys = edge_keys(np.minimum(rl, rh), np.maximum(rl, rh), n)
-    else:
-        r_edges = E
-        row_keys = edge_keys(lo, hi, n)
-    g = build_csr(r_edges, n)
-    res = pkt(g, chunk=chunk, mode=mode, support_mode=support_mode,
-              table_mode=table_mode, compact_frac=compact_frac,
-              compact_min=compact_min)
-    return align_to_input(res.trussness, g, None, n, keys=row_keys)
+    with spans.span("truss_pkt"):
+        with spans.span("truss_pkt.prep") as sp:
+            E, lo, hi, n = canonical_edges_with_rows(edges)
+            if E.size == 0:
+                return np.zeros(0, np.int64)
+            if reorder:
+                perm = degeneracy_order(E, n)
+                r_edges = relabel(E, perm)
+                rl, rh = perm[lo], perm[hi]
+                row_keys = edge_keys(np.minimum(rl, rh), np.maximum(rl, rh), n)
+            else:
+                r_edges = E
+                row_keys = edge_keys(lo, hi, n)
+            g = build_csr(r_edges, n)
+            sp.set(n=n, m=g.m)
+        res = pkt(g, chunk=chunk, mode=mode, support_mode=support_mode,
+                  table_mode=table_mode, compact_frac=compact_frac,
+                  compact_min=compact_min)
+        with spans.span("truss_pkt.align"):
+            return align_to_input(res.trussness, g, None, n, keys=row_keys)

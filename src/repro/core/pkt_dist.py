@@ -100,7 +100,7 @@ def _dist_peel_body(N, Eid, S0, e1, cand, lo, hi, c_start, c_end, has, *,
         jnp.clip(c_end - base, 0, n_chunks - 1), mine)
     S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
     processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
-    S_ext, _, levels, subs = _peel_loop(
+    S_ext, _, levels, subs, _ = _peel_loop(
         N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
         n_chunks=n_chunks, iters=iters, mode="chunked",
         reduce=functools.partial(_psum, axes=axes))

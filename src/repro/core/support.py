@@ -296,33 +296,26 @@ def _support_device_jit(u, v, Es, Eo, N, Eid, m_real, *, m: int, size: int,
 
 
 def _support_device(g: CSRGraph, *, mode: str, chunk: int | None,
-                    interpret: bool, timings: dict | None = None):
+                    interpret: bool):
     """Support phase with the table built on device; returns a (m,) device
-    array (no host round-trip — ``pkt`` feeds it straight to the peel).
+    array (no host round-trip — ``pkt`` feeds it straight to the peel) and
+    the table's padded row count.
 
-    Table construction and the probe run as one fused jit, so with
-    ``timings`` the combined cost is attributed to "support" ("tables"
-    then covers only the peel-table build)."""
-    import time as _time
-
+    Table construction and the probe run as one fused jit, so its cost is
+    all support's (the peel-table build is the "tables" phase)."""
     size = support_table_size(g)
     if size == 0:
-        return jnp.zeros((g.m,), jnp.int32)
+        return jnp.zeros((g.m,), jnp.int32), 0
     size_pad = next_pow2(size)
     _check_table_size(size_pad)
     dev = g.device_arrays()
     chunk_eff = pow2_chunk(size_pad, chunk, size=size)
-    t0 = _time.perf_counter()
     S = _support_device_jit(
         dev["El"][:, 0], dev["El"][:, 1], dev["Es"], dev["Eo"],
         dev["N"], dev["Eid"], jnp.int32(g.m), m=g.m, size=size_pad,
         mode=mode, chunk=chunk_eff, n_chunks=size_pad // chunk_eff,
         iters=_search_iters(g, oriented=True), interpret=interpret)
-    if timings is not None:
-        S.block_until_ready()
-        timings["support"] = timings.get("support", 0.0) + \
-            (_time.perf_counter() - t0)
-    return S
+    return S, size_pad
 
 
 # ``ranged_searchsorted`` lives in kernels/wedge_common.py (shared with the
@@ -382,8 +375,9 @@ def compute_support(g: CSRGraph, table: WedgeTable | None = None, *,
     if g.m == 0:
         return np.zeros(0, np.int32)
     if table_mode == "device" and table is None:
-        return np.asarray(
-            _support_device(g, mode=mode, chunk=chunk, interpret=interpret))
+        S, _ = _support_device(g, mode=mode, chunk=chunk,
+                               interpret=interpret)
+        return np.asarray(S)
     if table is None:
         table = build_support_table(g)
     if table.size == 0:

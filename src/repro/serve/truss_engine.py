@@ -161,7 +161,7 @@ def _batched_truss(ops: BatchOperand, *, m: int, chunk: int, n_chunks: int,
         processed0 = ~edge_ok
         tabs = PeelTables(op.p_e1, op.p_cand, op.p_lo, op.p_hi,
                           op.c_start, op.c_end, op.has_entries)
-        S_ext, _, levels, subs = _peel_loop(
+        S_ext, _, levels, subs, _ = _peel_loop(
             op.N, op.Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
             n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
         return S_ext[:m], S0, levels, subs
@@ -203,7 +203,7 @@ def _batched_truss_dev(ops: CSROperand, *, m: int, chunk: int, n_chunks: int,
             _SENTINEL_S)
         processed0 = ~edge_ok
         tabs = PeelTables(p_e1, p_cand, p_lo, p_hi, c_start, c_end, has)
-        S_ext, _, levels, subs = _peel_loop(
+        S_ext, _, levels, subs, _ = _peel_loop(
             op.N, op.Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
             n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
         return S_ext[:m], S0, levels, subs

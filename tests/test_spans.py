@@ -1,0 +1,232 @@
+"""Spans and counters inside the one-shot path (``repro.spans``).
+
+``truss_pkt`` / ``pkt`` open a span at each layer boundary, all sharing
+one decomposition id; peel segments carry levels, sub-levels and chunk
+visits.  ``PKTResult.phases`` is read from the same spans.  The counts
+are checked against a plain numpy recount of the level / sub-level peel,
+and against themselves with a profiler recording.
+"""
+
+import glob
+import importlib
+import json
+import os
+import pathlib
+import pkgutil
+
+import numpy as np
+import pytest
+
+import jax
+
+import repro
+from repro import spans
+from repro.core.pkt import pkt, truss_pkt
+from repro.core.support import build_peel_table
+from repro.graphs.csr import build_csr, degeneracy_order, relabel
+from repro.graphs.gen import ring_of_cliques_edges, rmat_edges
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+METRICS = sorted((ROOT / "chipbench" / "metrics").glob("*.json"))
+
+
+def _graph(name):
+    E = {"rmat": lambda: rmat_edges(6, edge_factor=5, seed=1),
+         "cliques": lambda: ring_of_cliques_edges(5, 6)}[name]()
+    n = int(E.max()) + 1
+    return E, build_csr(relabel(E, degeneracy_order(E, n)), n)
+
+
+@pytest.fixture()
+def ring():
+    """An empty ring before the test, emptied again after it."""
+    spans.drain()
+    yield
+    spans.drain()
+
+
+def _profiled(tmp_path, fn):
+    """``fn()`` under a CPU profiler trace; returns (result, {name: stats})
+    of the ``repro.`` host events."""
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    events.setdefault(ev.name, []).append(dict(ev.stats))
+    return out, events
+
+
+def test_spans_nest_under_one_decomposition(ring):
+    E, _ = _graph("rmat")
+    truss_pkt(E, compact_frac=0.99, compact_min=0)
+    recs = spans.drain()
+    by_id = {r.id: r for r in recs}
+    root, = [r for r in recs if r.name == "truss_pkt"]
+    assert root.parent is None and root.decomp == root.id
+    assert {r.decomp for r in recs} == {root.id}
+    want_parent = {"truss_pkt.prep": "truss_pkt", "pkt": "truss_pkt",
+                   "truss_pkt.align": "truss_pkt", "pkt.support": "pkt",
+                   "pkt.tables": "pkt", "pkt.peel_segment": "pkt",
+                   "pkt.compact": "pkt"}
+    assert {r.name for r in recs} == set(want_parent) | {"truss_pkt"}
+    for r in recs:
+        if r.name in want_parent:
+            parent = by_id[r.parent]
+            assert parent.name == want_parent[r.name]
+            assert parent.start_ns <= r.start_ns <= r.end_ns <= parent.end_ns
+    segs = [r for r in recs if r.name == "pkt.peel_segment"]
+    assert [s.attrs["segment"] for s in segs] == list(range(len(segs)))
+    assert len([r for r in recs if r.name == "pkt.compact"]) == len(segs) - 1
+    # a second decomposition starts a new id
+    truss_pkt(E)
+    assert {r.decomp for r in spans.drain()}.isdisjoint({root.id})
+
+
+def test_metadata_set_at_exit_reaches_ring_and_trace(ring, tmp_path):
+    def body():
+        with spans.span("outer", n=3) as sp:
+            with spans.span("outer.inner"):
+                pass
+            sp.set(levels=7)
+    _, events = _profiled(tmp_path, body)
+    inner, outer = spans.drain()
+    assert outer.attrs == {"n": 3, "levels": 7} and outer.traced
+    assert inner.parent == outer.id and inner.traced
+    assert events["repro.outer"] == [{"n": 3, "levels": 7}]
+    assert "repro.outer.inner" in events
+    with spans.span("untraced") as sp:
+        pass
+    assert not sp.traced
+
+
+def test_ring_stays_bounded(ring):
+    for i in range(spans.RING_SIZE + 10):
+        with spans.span("tick", i=i):
+            pass
+    recs = spans.records()
+    assert len(recs) == spans.RING_SIZE
+    assert recs[0].attrs["i"] == 10          # the oldest went first
+    assert len(spans.drain()) == spans.RING_SIZE
+    assert spans.records() == []
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(table_mode="numpy"), dict(compact_frac=0.99, compact_min=0),
+    dict(empty=True)], ids=["device", "numpy", "compacting", "empty"])
+def test_phases_keep_their_four_keys(ring, kw):
+    _, g = _graph("rmat")
+    if kw.pop("empty", False):
+        g = build_csr(np.zeros((0, 2), np.int64), 1)
+    res = pkt(g, phase_timings=True, **kw)
+    assert set(res.phases) == {"tables", "support", "peel", "compact"}
+    assert all(v >= 0.0 for v in res.phases.values())
+    if g.m:
+        assert res.phases["peel"] > 0.0 and res.phases["support"] > 0.0
+        assert (res.phases["compact"] > 0.0) == (res.compactions > 0)
+    assert pkt(g, **kw).phases is None
+
+
+def _triangles(El):
+    """Every triangle as a triple of edge ids (rows of ``El``)."""
+    eid = {(int(u), int(v)): i for i, (u, v) in enumerate(El)}
+    nbrs = {}
+    for u, v in eid:
+        nbrs.setdefault(u, set()).add(v)
+        nbrs.setdefault(v, set()).add(u)
+    return [(i, eid[(u, w)], eid[(v, w)]) for (u, v), i in eid.items()
+            for w in nbrs[u] & nbrs[v] if w > v]
+
+
+def _recount(g, off, chunk, n_chunks):
+    """Plain level / sub-level peel: (sub-levels, chunks overlapping each
+    sub-level's frontier, summed)."""
+    tri = _triangles(g.El)
+    S = np.zeros(g.m, np.int64)
+    for t in tri:
+        S[list(t)] += 1
+    processed = np.zeros(g.m, bool)
+    has = off[1:] > off[:-1]
+    subs = visits = 0
+    while not processed.all():
+        l = S[~processed].min()
+        curr = ~processed & (S == l)
+        while curr.any():
+            subs += 1
+            active = np.zeros(n_chunks, bool)
+            for e in np.nonzero(curr & has)[0]:
+                active[off[e] // chunk:(off[e + 1] - 1) // chunk + 1] = True
+            visits += int(active.sum())
+            dec = np.zeros(g.m, np.int64)
+            for t in tri:
+                t = list(t)
+                if processed[t].any() or not curr[t].any():
+                    continue
+                for y in t:
+                    if not curr[y] and S[y] > l:
+                        dec[y] += 1
+            hit = ~processed & ~curr & (dec > 0)
+            S[hit] = np.maximum(S[hit] - dec[hit], l)
+            processed |= curr
+            curr = ~processed & (S == l)
+    return subs, visits
+
+
+@pytest.mark.parametrize("mode", ["chunked", "dense", "pallas"])
+@pytest.mark.parametrize("graph", ["rmat", "cliques"])
+@pytest.mark.parametrize("table_mode", ["numpy", "device"])
+def test_chunk_visits_match_a_numpy_recount(ring, graph, mode, table_mode):
+    _, g = _graph(graph)
+    res = pkt(g, mode=mode, chunk=16, table_mode=table_mode,
+              compact_frac=None)
+    seg, = [r for r in spans.drain() if r.name == "pkt.peel_segment"]
+    chunk, n_chunks = seg.attrs["chunk"], seg.attrs["n_chunks"]
+    assert seg.attrs["chunk_visits"] == res.chunk_visits
+    assert seg.attrs["sublevels"] == res.sublevels
+    if mode == "dense":
+        assert res.chunk_visits == n_chunks * res.sublevels
+    else:
+        subs, visits = _recount(g, build_peel_table(g).off, chunk, n_chunks)
+        assert (res.sublevels, res.chunk_visits) == (subs, visits)
+
+
+def test_counts_are_the_same_with_the_profiler_on(ring, tmp_path):
+    _, g = _graph("rmat")
+    kw = dict(compact_frac=0.99, compact_min=0)
+    off = pkt(g, **kw)
+    on, events = _profiled(tmp_path, lambda: pkt(g, **kw))
+    counts = [(r.levels, r.sublevels, r.chunk_visits, r.compactions)
+              for r in (off, on)]
+    assert counts[0] == counts[1] and off.compactions > 0
+    assert np.array_equal(off.trussness, on.trussness)
+    segs = events["repro.pkt.peel_segment"]
+    assert sum(s["sublevels"] for s in segs) == on.sublevels
+    assert sum(s["chunk_visits"] for s in segs) == on.chunk_visits
+
+
+def _jitted_names():
+    names = set()
+    for mod in pkgutil.walk_packages(repro.__path__, "repro."):
+        if mod.name.startswith(("repro.core", "repro.serve", "repro.kernels")):
+            module = importlib.import_module(mod.name)
+            names |= {k for k, v in vars(module).items()
+                      if callable(getattr(v, "lower", None))}
+    return names
+
+
+@pytest.mark.parametrize("path", METRICS, ids=lambda p: p.stem)
+def test_benchmark_metrics_name_what_the_program_has(path):
+    """A metric reads jits by name and spans by name: a rename reads
+    nothing, so every name a metric file gives must still exist."""
+    spec = json.loads(path.read_text())
+    if "jits" in spec:
+        assert set(spec["jits"]) <= _jitted_names()
+    if "span" in spec:
+        spans.drain()
+        E, _ = _graph("rmat")
+        truss_pkt(E, compact_frac=0.99, compact_min=0)
+        assert spec["span"] in {r.name for r in spans.drain()}
