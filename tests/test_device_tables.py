@@ -11,6 +11,7 @@ Runs under real ``hypothesis`` and under the deterministic fallback shim
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from hypothesis import HealthCheck, given, settings
@@ -187,3 +188,94 @@ def test_prebuilt_table_forces_numpy_path():
     ptab = support_mod.build_peel_table(g)
     res = pkt(g, support_table=stab, peel_table=ptab)
     assert np.array_equal(res.trussness, pkt(g).trussness)
+
+
+# --- segment expansion (support._expand_segments) ---------------------------
+
+def _expand_oracle(off, size, m, start):
+    """The row → segment map by ``jnp.searchsorted`` over the offsets."""
+    idx = start + jnp.arange(size, dtype=jnp.int32)
+    e1 = jnp.searchsorted(off[1:], idx, side="right").astype(jnp.int32)
+    e1c = jnp.minimum(e1, m - 1)
+    valid = idx < off[m]
+    return jnp.where(valid, e1, m), e1c, idx - off[e1c], valid
+
+
+def _offsets(cnt):
+    return np.concatenate([[0], np.cumsum(cnt)]).astype(np.int32)
+
+
+def _random_counts(m, seed, hi=9):
+    """Segment lengths with about 30 % empty segments."""
+    rng = np.random.default_rng(seed)
+    cnt = rng.integers(1, hi, m)
+    cnt[rng.random(m) < 0.3] = 0
+    return cnt
+
+
+#: name -> (segment lengths, size, start, how): ``how`` is ``eager``,
+#: ``jit`` (``start`` traced) or ``vmap`` (a batch of offset arrays of one
+#: padded m, as the batched engine builds them)
+_EXPAND_CASES = {
+    "leading_empty": ([0, 0, 3, 1, 2], 8, 0, "eager"),
+    "trailing_empty": ([2, 3, 1, 0, 0], 8, 0, "eager"),
+    "inner_empty_runs": ([1, 0, 0, 0, 2, 0, 3], 8, 0, "eager"),
+    "all_empty": ([0, 0, 0, 0], 4, 0, "eager"),
+    "m1": ([5], 8, 0, "eager"),
+    "m1_empty": ([0], 2, 0, "eager"),
+    "size_below_total": (_random_counts(300, 1), 256, 0, "eager"),
+    "size_above_total": (_random_counts(300, 2), 4096, 0, "eager"),
+    "m22728_size2pow21": (_random_counts(22728, 3, hi=90), 1 << 21, 0, "jit"),
+    "jit_start_0": (_random_counts(300, 4), 512, 0, "jit"),
+    "jit_start_mid": (_random_counts(300, 5), 256, 333, "jit"),
+    "jit_start_past_total": (_random_counts(300, 6), 256, 5000, "jit"),
+    "jit_start_mid_m1": ([7], 4, 5, "jit"),
+    "size_odd": (_random_counts(50, 7), 333, 0, "eager"),
+    "jit_size_384_start_mid": (_random_counts(90, 8), 384, 101, "jit"),
+    "vmap_batch": ([_random_counts(40, s) for s in range(5)] + [[0] * 40],
+                   256, 0, "vmap"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_EXPAND_CASES))
+def test_expand_segments_equals_searchsorted(name):
+    cnt, size, start, how = _EXPAND_CASES[name]
+    if how == "vmap":
+        off = jnp.asarray(np.stack([_offsets(c) for c in cnt]))
+        m = off.shape[1] - 1
+
+        def both(o):
+            return (support_mod._expand_segments(o, size, m),
+                    _expand_oracle(o, size, m, 0))
+
+        got, want = jax.vmap(both)(off)
+    else:
+        off = jnp.asarray(_offsets(cnt))
+        m = off.shape[0] - 1
+        if how == "jit":
+            got = jax.jit(support_mod._expand_segments,
+                          static_argnums=(1, 2))(off, size, m,
+                                                 jnp.int32(start))
+        else:
+            got = support_mod._expand_segments(off, size, m, start)
+        want = _expand_oracle(off, size, m, start)
+    for part, a, b in zip(("e1", "e1c", "intra", "valid"), got, want):
+        assert a.dtype == b.dtype, part
+        assert np.array_equal(np.asarray(a), np.asarray(b)), part
+
+
+@pytest.mark.parametrize("table", ["peel", "support"])
+def test_table_builders_lower_without_loops(table):
+    """The builders run no per-row loop: segment expansion is a scatter and
+    a prefix sum, so a ``while`` in their StableHLO means a search (or any
+    other per-row loop) came back."""
+    g = _graph(rmat_edges(6, edge_factor=5, seed=3))
+    dev = g.device_arrays()
+    u, v = dev["El"][:, 0], dev["El"][:, 1]
+    if table == "peel":
+        low = support_mod._build_peel_table_dev.lower(
+            u, v, dev["Es"], jnp.int32(g.m), m=g.m, size=1 << 12, chunk=64)
+    else:
+        low = support_mod._build_support_table_dev.lower(
+            u, v, dev["Es"], dev["Eo"], jnp.int32(g.m), m=g.m, size=1 << 12)
+    assert "stablehlo.while" not in low.as_text()
