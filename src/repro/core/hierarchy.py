@@ -49,6 +49,11 @@ import functools
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
+from repro import spans
+from repro.kernels.wedge_common import next_pow2
 from repro.testing.chaos import fault_point
 
 #: where per-level labels are computed: jitted label propagation on device
@@ -58,61 +63,55 @@ HIER_MODES = ("device", "host")
 
 # ------------------------------------------------------- device label flood --
 
-def _labelprop_jit_factory():
-    """Build the jitted per-level label-propagation function lazily so the
-    module imports without jax (numpy-only contexts use mode="host")."""
-    import jax
-    import jax.numpy as jnp
+@functools.partial(jax.jit, static_argnames=("sz", "mp"))
+def _labelprop(tri_all, lvl_all, start, k, L0, *, sz: int, mp: int):
+    """Min-label flood over the *representative graph* to the fixed point.
 
-    @functools.partial(jax.jit, static_argnames=("sz", "mp"))
-    def _labelprop(tri_all, lvl_all, start, k, L0, *, sz: int, mp: int):
-        """Min-label flood over the *representative graph* to the fixed point.
+    ``tri_all``/``lvl_all`` are the full level-sorted triangle table and
+    its per-row levels (min member trussness); the flood runs on the
+    ``sz``-row window at dynamic offset ``start`` (every row that can
+    still merge components at level ``k`` — see the stratum windowing
+    in ``_build_device``; slicing in-jit saves two eager dispatches per
+    level).  ``k`` is the dynamic level, ``L0`` the (mp,) initial
+    labels (live edges: any in-component id <= their own — warm starts
+    pass a finer level's *flat* component minima; dead and padding
+    slots: themselves).
 
-        ``tri_all``/``lvl_all`` are the full level-sorted triangle table and
-        its per-row levels (min member trussness); the flood runs on the
-        ``sz``-row window at dynamic offset ``start`` (every row that can
-        still merge components at level ``k`` — see the stratum windowing
-        in ``_build_device``; slicing in-jit saves two eager dispatches per
-        level).  ``k`` is the dynamic level, ``L0`` the (mp,) initial
-        labels (live edges: any in-component id <= their own — warm starts
-        pass a finer level's *flat* component minima; dead and padding
-        slots: themselves).
+    Each round gathers every active row's current representatives
+    ``r = L[tri]``, scatter-mins the row's 3-way representative-label
+    minimum into ``L[r]`` — the union step, expressed on the component
+    graph so already-merged rows are no-ops — then pointer-jumps
+    ``L <- min(L, L[L])``.  Labels only decrease and always point at
+    in-component edge ids, so the fixed point is exactly the flat
+    component-minimum labeling: at convergence ``L[L[e]] == L[e]``
+    (labels are roots) and every active row's members share one root
+    (DESIGN.md §16 gives the argument).  Warm-started levels converge
+    in O(log merge-chain) rounds over only their fresh stratum.
+    Returns the labels and the number of rounds run.
+    """
+    tri = jax.lax.dynamic_slice(tri_all, (start, 0), (sz, 3))
+    act = jax.lax.dynamic_slice(lvl_all, (start,), (sz,)) >= k
+    sink = jnp.int32(mp - 1)
 
-        Each round gathers every active row's current representatives
-        ``r = L[tri]``, scatter-mins the row's 3-way representative-label
-        minimum into ``L[r]`` — the union step, expressed on the component
-        graph so already-merged rows are no-ops — then pointer-jumps
-        ``L <- min(L, L[L])``.  Labels only decrease and always point at
-        in-component edge ids, so the fixed point is exactly the flat
-        component-minimum labeling: at convergence ``L[L[e]] == L[e]``
-        (labels are roots) and every active row's members share one root
-        (DESIGN.md §16 gives the argument).  Warm-started levels converge
-        in O(log merge-chain) rounds over only their fresh stratum.
-        """
-        tri = jax.lax.dynamic_slice(tri_all, (start, 0), (sz, 3))
-        act = jax.lax.dynamic_slice(lvl_all, (start,), (sz,)) >= k
-        sink = jnp.int32(mp - 1)
+    def body(state):
+        L, _, rounds = state
+        r = L[tri]
+        lm = jnp.min(L[r], axis=1)
+        idx = jnp.where(act[:, None], r, sink)
+        lmw = jnp.where(act, lm, sink)
+        L2 = (L.at[idx[:, 0]].min(lmw)
+               .at[idx[:, 1]].min(lmw)
+               .at[idx[:, 2]].min(lmw))
+        L2 = jnp.minimum(L2, L2[L2])
+        return L2, L, rounds + 1
 
-        def body(state):
-            L, _ = state
-            r = L[tri]
-            lm = jnp.min(L[r], axis=1)
-            idx = jnp.where(act[:, None], r, sink)
-            lmw = jnp.where(act, lm, sink)
-            L2 = (L.at[idx[:, 0]].min(lmw)
-                   .at[idx[:, 1]].min(lmw)
-                   .at[idx[:, 2]].min(lmw))
-            L2 = jnp.minimum(L2, L2[L2])
-            return L2, L
+    def cond(state):
+        L, prev, _ = state
+        return jnp.any(L != prev)
 
-        def cond(state):
-            L, prev = state
-            return jnp.any(L != prev)
-
-        L, _ = jax.lax.while_loop(cond, body, (L0, jnp.full_like(L0, -1)))
-        return L
-
-    return _labelprop
+    L, _, rounds = jax.lax.while_loop(
+        cond, body, (L0, jnp.full_like(L0, -1), jnp.int32(0)))
+    return L, rounds
 
 
 # Host-side flood seeding: active sets up to _SEED_ROWS_MAX rows run up to
@@ -124,14 +123,11 @@ def _labelprop_jit_factory():
 _SEED_ROWS_MAX = 4096
 _SEED_ROUNDS = 2
 
-_LABELPROP = None
-
-
-def _labelprop_fns():
-    global _LABELPROP
-    if _LABELPROP is None:
-        _LABELPROP = _labelprop_jit_factory()
-    return _LABELPROP
+#: The device flood's row window is a power of two no smaller than this, so
+#: the small strata of a warm-started level share one compiled program
+#: instead of one per power of two below it (a window's extra rows are
+#: finer rows, no-ops under the warm start).
+_FLOOD_MIN_ROWS = 4096
 
 
 # ------------------------------------------------------ host union-find oracle
@@ -237,8 +233,13 @@ class TrussHierarchy:
             return np.full(self.m, -1, np.int64)
         li = k - 2
         if self._labels[li] is None:
-            self._labels[li] = (self._build_device(k) if self.mode == "device"
-                                else self._build_host(k))
+            # one span per level built: ``rows`` the triangle rows entering
+            # at k since the warm level, ``rounds`` the device flood's
+            # rounds (0 where the host closed the level)
+            with spans.span("hier.level", k=k, mode=self.mode) as sp:
+                self._labels[li] = (self._build_device(k, sp)
+                                    if self.mode == "device"
+                                    else self._build_host(k, sp))
         return self._labels[li]
 
     def build_all(self) -> "TrussHierarchy":
@@ -292,8 +293,6 @@ class TrussHierarchy:
     # ------------------------------------------------------- device builder --
 
     def _pad_dims(self) -> tuple[int, int]:
-        from repro.kernels.wedge_common import next_pow2
-
         # Labels are pure jnp (no pallas tiling), so the label array only
         # needs *size-class* padding for compile reuse, not a full pow2:
         # round m+1 up to the nearest of {0.75 * 2^b, 2^b}.  Half-step
@@ -314,8 +313,6 @@ class TrussHierarchy:
         reordering cannot change any label.
         """
         if self._dev is None:
-            import jax.numpy as jnp
-
             mp, tp = self._pad_dims()
             order = np.argsort(-self.tri_lvl, kind="stable")
             tri = np.full((tp, 3), mp - 1, np.int32)
@@ -348,10 +345,11 @@ class TrussHierarchy:
         L0[:self.m][dead] = np.nonzero(dead)[0]
         return L0
 
-    def _build_device(self, k: int) -> np.ndarray:
+    def _build_device(self, k: int, sp) -> np.ndarray:
         fault_point("hierarchy", rung="device")
         j = self._warm_level(k)
         fresh = (self.tri_lvl >= k) & (self.tri_lvl < j)
+        sp.set(rows=int(np.count_nonzero(fresh)), rounds=0)
         if not fresh.any():
             # Empty-stratum shortcut: no triangle enters between j and k,
             # so no merge is possible — level k's labels are level j's plus
@@ -420,9 +418,6 @@ class TrussHierarchy:
                 lm = rl.min(axis=1)
                 np.minimum.at(L0, rows.ravel(), np.repeat(lm, 3))
                 np.minimum(L0, L0[L0], out=L0)
-        import jax.numpy as jnp
-
-        labelprop = _labelprop_fns()
         tri_dev, lvl_dev, _ = self._device_tables()
         # Dispatch on the *fresh stratum* window only: the device rows are
         # sorted by level descending, so rows entering between the warm
@@ -432,15 +427,16 @@ class TrussHierarchy:
         # coarser than it are masked by the flood's own ``tri_lvl >= k``
         # predicate, so pow2-rounding the window backward is bitwise-safe
         # while bounding distinct compiled flood shapes to O(log T).
-        from repro.kernels.wedge_common import next_pow2
-
         lo = int(np.count_nonzero(self.tri_lvl >= j))
-        sz = min(int(tri_dev.shape[0]), max(8, next_pow2(hi - lo)))
+        sz = min(int(tri_dev.shape[0]),
+                 max(_FLOOD_MIN_ROWS, next_pow2(hi - lo)))
         start = max(0, hi - sz)
-        L = labelprop(tri_dev, lvl_dev, jnp.int32(start), jnp.int32(k),
-                      jnp.asarray(L0), sz=sz, mp=mp)
+        L, rounds = _labelprop(tri_dev, lvl_dev, jnp.int32(start),
+                               jnp.int32(k), jnp.asarray(L0), sz=sz, mp=mp)
         self.stats["device_levels"] += 1
-        return self._finish(np.asarray(L), k)
+        L, rounds = jax.device_get((L, rounds))
+        sp.set(rounds=int(rounds))
+        return self._finish(L, k)
 
     def _finish(self, L: np.ndarray, k: int) -> np.ndarray:
         labels = L[: self.m].astype(np.int64)
@@ -449,7 +445,7 @@ class TrussHierarchy:
 
     # --------------------------------------------------------- host builder --
 
-    def _build_host(self, k: int) -> np.ndarray:
+    def _build_host(self, k: int, sp) -> np.ndarray:
         """Shared top-down union-find: triangles sorted by level descending
         are unioned once in total across all levels; each level snapshot is
         a vectorized root lookup.  The shared state is only valid while
@@ -460,6 +456,7 @@ class TrussHierarchy:
         exactly once)."""
         fault_point("hierarchy", rung="host")
         self.stats["host_levels"] += 1
+        sp.set(rows=int(np.count_nonzero(self.tri_lvl >= k)))
         if self._uf is not None and k > self._uf["k_at"]:
             return host_level_labels(self.m, self.T, self.tri,
                                      self.tri_lvl, k)

@@ -48,3 +48,59 @@ def max_truss(edges: np.ndarray) -> int:
     """Largest k such that the k-truss is non-empty (numpy oracle)."""
     t = truss_numpy(edges)
     return int(t.max(initial=2))
+
+
+def community_numpy(edges: np.ndarray, trussness: np.ndarray, q: int,
+                    k: int) -> list[np.ndarray]:
+    """The k-truss communities that contain vertex ``q`` (numpy oracle).
+
+    A k-truss community (Huang et al., SIGMOD 2014) is a triangle-connected
+    set of edges of trussness >= k: two such edges belong together iff a
+    chain of triangles, each with all three edges at trussness >= k, links
+    them.  Triangles are listed here from adjacency sets and joined by a
+    plain union-find; nothing is shared with ``core/hierarchy.py``.
+
+    Args:
+        edges: canonical (m, 2) u < v edge array, unique rows.
+        trussness: (m,) trussness aligned to ``edges``.
+        q: the query vertex.
+        k: the community level.
+
+    Returns:
+        One (c, 2) array per community that holds an edge of ``q``, each
+        in the row order of ``edges``, the list ordered by its first row.
+    """
+    edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
+    T = np.asarray(trussness, dtype=np.int64)
+    ok = T >= k
+    eid = {(int(u), int(v)): i for i, (u, v) in enumerate(edges)}
+    adj: dict[int, set[int]] = {}
+    for (u, v), a in zip(edges.tolist(), ok.tolist()):
+        if a:
+            adj.setdefault(u, set()).add(v)
+            adj.setdefault(v, set()).add(u)
+    parent = list(range(edges.shape[0]))
+
+    def find(x: int) -> int:
+        while parent[x] != x:
+            parent[x] = parent[parent[x]]
+            x = parent[x]
+        return x
+
+    for (u, v), a in zip(edges.tolist(), ok.tolist()):
+        if not a:
+            continue
+        e = eid[(u, v)]
+        for w in adj[u] & adj[v]:
+            for f in (eid[(min(u, w), max(u, w))],
+                      eid[(min(v, w), max(v, w))]):
+                re, rf = find(e), find(f)
+                if re != rf:
+                    parent[rf] = re
+    mine = {find(i) for i in range(edges.shape[0])
+            if ok[i] and q in (int(edges[i, 0]), int(edges[i, 1]))}
+    out = []
+    for root in mine:
+        rows = [i for i in range(edges.shape[0]) if ok[i] and find(i) == root]
+        out.append(edges[np.array(rows, np.int64)])
+    return sorted(out, key=lambda c: tuple(c[0]))

@@ -46,8 +46,13 @@ region-local; a few O(m) vectorized mask/bound passes per step remain):
 
 The serving layer wraps this in a persistent handle
 (``TrussEngine.open / update / close`` in ``serve/truss_engine.py``);
-``launch/truss.py --update-stream`` replays synthetic churn through it, and
-``benchmarks/inc_bench.py`` measures update-vs-recompute speedup.
+``launch/truss.py --update-stream`` replays synthetic churn through it.
+The benchmark cell ``community.kron11`` (``chipbench/``) measures update
+speed on the chip: a delete-and-re-insert edge stream on a Kronecker graph
+through the scheduler, with community queries after every batch.  Each
+update is a ``repro.inc.update`` span (``repro.spans``) whose children
+name the phases: ``inc.deletions``, ``inc.insertions``,
+``inc.region_peel``, ``inc.full_rebuild`` and ``inc.triangle_list``.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ import time
 
 import numpy as np
 
+from repro import spans
 from repro.graphs.csr import (CSRGraph, build_csr, canonical_edges_with_rows,
                               check_edge_array, degeneracy_order, edge_keys,
                               relabel)
@@ -72,6 +78,9 @@ from repro.testing.chaos import fault_point
 #: applies edges one at a time (the ±1 locality bound) and serves as the
 #: bitwise parity oracle for the batched path.
 INSERT_MODES = ("sequential", "batched")
+
+#: ``mode`` counter of the ``inc.update`` span, by ``UpdateStats.mode``
+MODE_CODES = {"noop": 0, "local": 1, "full": 2}
 
 
 class IntegrityError(RuntimeError):
@@ -605,7 +614,18 @@ class IncrementalTruss:
         Raises:
             ValueError: edge arrays fail validation (self-loops, negative
                 or overflowing vertex ids), or unknown ``insert_mode``.
+
+        The call is a ``repro.inc.update`` span: attributes ``inserted``,
+        ``deleted`` and ``m`` (edges after the batch); counters ``mode``
+        (``MODE_CODES``), ``affected``, ``boundary``, ``passes`` and
+        ``insert_candidates``, the insertion candidate region's size where
+        its level scan ended (at the first level past ``local_frac`` when
+        the batch falls back to a full recompute; absent where no scan ran).
         """
+        with spans.span("inc.update") as sp:
+            return self._update(add_edges, remove_edges, insert_mode, sp)
+
+    def _update(self, add_edges, remove_edges, insert_mode, sp):
         t0 = time.perf_counter()
         imode = self.insert_mode if insert_mode is None else insert_mode
         if imode not in INSERT_MODES:
@@ -630,7 +650,10 @@ class IncrementalTruss:
         I_keys = np.setdiff1d(new_keys, old_keys, assume_unique=True)
         D_keys = np.setdiff1d(old_keys, new_keys, assume_unique=True)
 
-        totals = {"affected": 0, "boundary": 0, "passes": 0}
+        sp.set(inserted=int(I_keys.size), deleted=int(D_keys.size),
+               m=int(new_keys.shape[0]))
+        totals = {"affected": 0, "boundary": 0, "passes": 0,
+                  "insert_candidates": None}
         T_old_ref = self.T      # for the changed count (old-id space)
 
         def done(mode):
@@ -660,6 +683,10 @@ class IncrementalTruss:
                 seconds=time.perf_counter() - t0,
                 insert_mode=imode if (I_keys.size and mode != "noop")
                 else None)
+            sp.set(mode=MODE_CODES[mode], affected=st.affected,
+                   boundary=st.boundary, passes=st.rounds)
+            if totals["insert_candidates"] is not None:
+                sp.set(insert_candidates=totals["insert_candidates"])
             self.stats["updates"] += 1
             self.stats[mode] += 1
             self.stats["update_seconds"] += st.seconds
@@ -680,15 +707,18 @@ class IncrementalTruss:
 
         # ---------------- phase D: all deletions as one exact batch -------
         if D_keys.size:
-            state = self._apply_deletions(old_keys, D_keys, n, limit, totals)
+            with spans.span("inc.deletions"):
+                state = self._apply_deletions(old_keys, D_keys, n, limit,
+                                              totals)
             if state is None:
                 self._full_rebuild(E_new)
                 return done("full")
 
         # ---------------- phase I: insertions (batched or sequential) -----
         if I_keys.size:
-            state = self._apply_insertions(state, new_keys, I_keys, n, limit,
-                                           totals, imode)
+            with spans.span("inc.insertions"):
+                state = self._apply_insertions(state, new_keys, I_keys, n,
+                                               limit, totals, imode)
             if state is None:
                 self._full_rebuild(E_new)
                 return done("full")
@@ -839,10 +869,15 @@ class IncrementalTruss:
                 reach = _tri_bfs(inc_static, side_rows,
                                  np.array([e_i]), allowed)
                 cand[reach[T_cur[reach] == k]] = True
-                if int(cand.sum()) > limit:
+                size = int(cand.sum())
+                if size > limit:
+                    totals["insert_candidates"] = max(
+                        totals["insert_candidates"] or 0, size)
                     return None
             cand[e_i] = True
             A = np.nonzero(cand)[0]
+            totals["insert_candidates"] = max(
+                totals["insert_candidates"] or 0, int(A.size))
             if A.size > limit or totals["affected"] + A.size > limit:
                 return None    # cumulative local work past paying: recompute
             tau = self._region_peel(g_new, inc_static, side_rows, A, S_cur,
@@ -904,10 +939,13 @@ class IncrementalTruss:
             totals["passes"] += 1
             reach = _tri_bfs(inc_static, side_rows, ins_new, allowed)
             cand[reach[T_cur[reach] == k]] = True
-            if int(cand.sum()) > limit:
+            size = int(cand.sum())
+            if size > limit:
+                totals["insert_candidates"] = size
                 return None
         cand[ins_new] = True
         A = np.nonzero(cand)[0]
+        totals["insert_candidates"] = int(A.size)
         if A.size > limit or totals["affected"] + A.size > limit:
             return None        # merged region past paying: recompute
         tau = self._region_peel(g_new, inc_static, side_rows, A, S_cur,
@@ -957,34 +995,12 @@ class IncrementalTruss:
         totals["boundary"] += int(boundary.size)
 
         L = np.union1d(A, boundary)
-        chaos = fault_point(
-            "region",
-            rung="host" if L.shape[0] <= self.host_peel_max else self.mode)
-        if L.shape[0] <= self.host_peel_max:
-            # compact host path: local ids preserve the global id order, so
-            # the tie-break picks the same winners
-            lmap = np.full(m, -1, np.int64)
-            lmap[L] = np.arange(L.shape[0])
-            n_loc = L.shape[0]
-            S0 = np.where(in_A[L], S_vec[L], T_fix[L] - 2)
-            live = np.ones(n_loc, bool)
-            pinned = ~in_A[L]
-            S_fin = _host_peel(n_loc, lmap[rows] if rows.size else
-                               np.zeros((0, 3), np.int64),
-                               S0, live, pinned)
-            tau_L = S_fin + 2
-        else:
-            # larger regions reuse the live-edge compaction machinery
-            # (core.pkt.peel_live_subset): the region is gathered into a
-            # compacted pow2-bucketed edge space — work bounded by |L|, not
-            # m — with boundary edges pinned at their death level, and the
-            # driver keeps compacting as the region itself peels away
-            S0 = np.where(in_A[L], S_vec[L], T_fix[L] - 2)
-            S_fin = peel_live_subset(
-                g.El, L, S0, ~in_A[L], chunk=self.chunk, mode=self.mode,
-                interpret=self.interpret, table_mode=self.table_mode,
-                compact_frac=self.compact_frac, compact_min=self.compact_min)
-            tau_L = S_fin.astype(np.int64) + 2
+        path = "host" if L.shape[0] <= self.host_peel_max else "device"
+        chaos = fault_point("region",
+                            rung="host" if path == "host" else self.mode)
+        with spans.span("inc.region_peel", region=int(A.size),
+                        boundary=int(boundary.size), path=path):
+            tau_L = self._peel_region(g, L, in_A, rows, S_vec, T_fix, path)
         if chaos == "corrupt" and boundary.size:
             # injected corruption (testing/chaos.py): bump one pinned slot so
             # the replay invariant below is guaranteed to trip — exercising
@@ -1000,6 +1016,32 @@ class IncrementalTruss:
                 "incremental re-peel integrity violation: a pinned boundary "
                 "edge left its death level — please report this graph")
         return tau_L[np.searchsorted(L, A)]
+
+    def _peel_region(self, g: CSRGraph, L: np.ndarray, in_A: np.ndarray,
+                     rows: np.ndarray, S_vec: np.ndarray, T_fix: np.ndarray,
+                     path: str) -> np.ndarray:
+        """Peel values + 2 of the region-plus-boundary edges ``L`` (sorted),
+        boundary edges (``~in_A``) pinned at their death level."""
+        S0 = np.where(in_A[L], S_vec[L], T_fix[L] - 2)
+        if path == "host":
+            # compact host path: local ids preserve the global id order, so
+            # the tie-break picks the same winners
+            lmap = np.full(g.m, -1, np.int64)
+            lmap[L] = np.arange(L.shape[0])
+            S_fin = _host_peel(L.shape[0], lmap[rows] if rows.size else
+                               np.zeros((0, 3), np.int64),
+                               S0, np.ones(L.shape[0], bool), ~in_A[L])
+            return S_fin + 2
+        # larger regions reuse the live-edge compaction machinery
+        # (core.pkt.peel_live_subset): the region is gathered into a
+        # compacted pow2-bucketed edge space — work bounded by |L|, not m —
+        # with boundary edges pinned at their death level, and the driver
+        # keeps compacting as the region itself peels away
+        S_fin = peel_live_subset(
+            g.El, L, S0, ~in_A[L], chunk=self.chunk, mode=self.mode,
+            interpret=self.interpret, table_mode=self.table_mode,
+            compact_frac=self.compact_frac, compact_min=self.compact_min)
+        return S_fin.astype(np.int64) + 2
 
     # ---------------------------------------------------------- internals --
     def _hier_update(self, old_keys, I_keys, T_old, posn, ok, kn) -> None:
@@ -1048,32 +1090,37 @@ class IncrementalTruss:
         self.tri = tri_new.astype(np.int64)
 
     def _full_rebuild(self, E: np.ndarray) -> None:
-        """From-scratch decomposition through the standard (KCO) pipeline."""
+        """From-scratch decomposition through the standard (KCO) pipeline,
+        as a ``repro.inc.full_rebuild`` span (``pkt``'s spans nest in it)."""
         self._hier = None        # full rebuild: community index rebuilt lazily
-        g = build_csr(E, self.n)
-        if g.m == 0:
-            self.open_phases = {}
-            self._commit(g, np.zeros(0, np.int64), np.zeros(0, np.int32),
-                         np.zeros((0, 3), np.int64))
-            return
-        perm = degeneracy_order(E, self.n)
-        r_edges = relabel(E, perm)
-        gr = build_csr(r_edges, self.n)
-        res = pkt(gr, chunk=self.chunk, mode=self.mode,
-                  support_mode=self.support_mode, table_mode=self.table_mode,
-                  compact_frac=self.compact_frac,
-                  compact_min=self.compact_min, interpret=self.interpret,
-                  phase_timings=True)
-        #: phase breakdown of the most recent full (re)build — the open
-        #: path's table-build vs support vs peel cost (benchmarks read it)
-        self.open_phases = dict(res.phases or {})
-        u = g.El[:, 0].astype(np.int64)
-        v = g.El[:, 1].astype(np.int64)
-        rl, rh = perm[u], perm[v]
-        keys = edge_keys(np.minimum(rl, rh), np.maximum(rl, rh), self.n)
-        T = align_to_input(res.trussness, gr, None, self.n, keys=keys)
-        S = align_to_input(res.support, gr, None, self.n, keys=keys)
-        self._commit(g, T, S.astype(np.int32), triangle_list(g))
+        with spans.span("inc.full_rebuild", m=int(E.shape[0])):
+            g = build_csr(E, self.n)
+            if g.m == 0:
+                self.open_phases = {}
+                self._commit(g, np.zeros(0, np.int64), np.zeros(0, np.int32),
+                             np.zeros((0, 3), np.int64))
+                return
+            perm = degeneracy_order(E, self.n)
+            r_edges = relabel(E, perm)
+            gr = build_csr(r_edges, self.n)
+            res = pkt(gr, chunk=self.chunk, mode=self.mode,
+                      support_mode=self.support_mode,
+                      table_mode=self.table_mode,
+                      compact_frac=self.compact_frac,
+                      compact_min=self.compact_min, interpret=self.interpret,
+                      phase_timings=True)
+            #: phase breakdown of the most recent full (re)build — the open
+            #: path's table-build vs support vs peel cost (benchmarks read it)
+            self.open_phases = dict(res.phases or {})
+            u = g.El[:, 0].astype(np.int64)
+            v = g.El[:, 1].astype(np.int64)
+            rl, rh = perm[u], perm[v]
+            keys = edge_keys(np.minimum(rl, rh), np.maximum(rl, rh), self.n)
+            T = align_to_input(res.trussness, gr, None, self.n, keys=keys)
+            S = align_to_input(res.support, gr, None, self.n, keys=keys)
+            with spans.span("inc.triangle_list"):
+                tri = triangle_list(g)
+            self._commit(g, T, S.astype(np.int32), tri)
 
     def check_invariants(self, *, sample: int = 64, seed: int = 0) -> int:
         """Cheap consistency check over a sampled edge set (DESIGN.md §15).

@@ -18,10 +18,11 @@ compatible work coalesces per tick —
     repair (``compose_update_batches``: n churn batches, one
     affected-region re-peel), bitwise-identical to applying them one at a
     time;
-  * **queries** (``query_async``/``communities_async``) serve from the
-    handle's maintained trussness and cached hierarchy index, ordered FIFO
-    per handle against that handle's updates, so every query observes
-    exactly the prefix of updates admitted before it.
+  * **queries** (``query_async``/``communities_async``/
+    ``community_async``) serve from the handle's maintained trussness and
+    cached hierarchy index, ordered FIFO per handle against that handle's
+    updates, so every query observes exactly the prefix of updates admitted
+    before it.
 
 Admission control sheds load with a typed :class:`Overloaded` error (never
 by silent queueing): a global queue-depth bound (``max_queue``) plus a
@@ -69,6 +70,7 @@ Usage::
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import sys
 import threading
@@ -85,7 +87,7 @@ from repro.serve.resilience import (DeadlineExceeded, Ladder, RetryPolicy,
                                     run_with_resilience)
 from repro.serve.truss_engine import TrussEngine, TrussHandle
 
-_KINDS = ("submit", "open", "update", "query", "communities")
+_KINDS = ("submit", "open", "update", "query", "communities", "community")
 
 #: degradation-ladder attribute overrides for the region re-peel site
 #: (applied to the handle's ``IncrementalTruss`` for one dispatch)
@@ -101,6 +103,39 @@ _SUPPORT_OVERRIDES = {
     "jnp": {"support_mode": "jnp"},
     "numpy": {"support_mode": "jnp", "table_mode": "numpy"},
 }
+
+
+#: glibc ``mallopt`` parameters (malloc.h) and what a scheduler sets them to:
+#: large blocks from the heap up to glibc's 32 MiB ceiling, and freed memory
+#: kept rather than trimmed (an int's largest value is the ceiling here)
+_MALLOPT = ((-3, 32 << 20),         # M_MMAP_THRESHOLD
+            (-1, (1 << 31) - 1),     # M_TRIM_THRESHOLD
+            (-2, 256 << 20))         # M_TOP_PAD
+_host_memory_kept: bool | None = None
+
+
+def _keep_freed_host_memory() -> bool:
+    """Ask glibc to keep the host memory a repair frees for the next one.
+
+    Every repair of a handle allocates and frees host arrays of tens of MiB
+    (triangle lists, CSR arrays, scan regions).  By default glibc serves
+    such blocks with fresh ``mmap`` s and hands freed heap tops back to the
+    kernel, so each round faults its memory in again; on a TPU v5e host a
+    fresh MiB cost about a millisecond, and how much of that a round paid
+    drifted with each process's allocation history.  A scheduler is a
+    long-lived server, so it keeps the memory instead.  Process-wide, set
+    once; returns whether the allocator took the settings (``False`` off
+    glibc)."""
+    global _host_memory_kept
+    if _host_memory_kept is None:
+        try:
+            mallopt = ctypes.CDLL(None).mallopt
+        except (OSError, AttributeError):
+            _host_memory_kept = False
+        else:
+            mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+            _host_memory_kept = all(mallopt(p, v) == 1 for p, v in _MALLOPT)
+    return _host_memory_kept
 
 
 class Overloaded(RuntimeError):
@@ -151,7 +186,8 @@ class _Request:
     handle: TrussHandle | None = None      # update/query/communities target
     add: np.ndarray | None = None          # update payload
     remove: np.ndarray | None = None
-    k: int = 0                             # communities level
+    k: int = 0                             # communities/community level
+    vertex: int = 0                        # community query vertex
     local_frac: float = 0.25               # open policy
     t_deadline: float | None = None        # absolute perf_counter deadline
 
@@ -163,7 +199,8 @@ class TrussScheduler:
     the ``*_async`` methods, each returning a ``concurrent.futures.Future``
     (engine errors — validation, oversized graphs, closed handles —
     surface as that future's exception; admission errors raise
-    :class:`Overloaded` synchronously).
+    :class:`Overloaded` synchronously).  Constructing one asks glibc to
+    keep freed host memory for the process (:func:`_keep_freed_host_memory`).
 
     Args:
         engine: the engine to serve; ``None`` builds one from
@@ -183,8 +220,8 @@ class TrussScheduler:
             each ``*_async`` call may override.  Expired requests fail with
             a typed :class:`DeadlineExceeded` — before dispatch for every
             kind, and additionally at delivery for read-only kinds
-            (submit/query/communities); committed updates and opens always
-            deliver, so deadline pressure never tears state.
+            (submit/query/communities/community); committed updates and
+            opens always deliver, so deadline pressure never tears state.
         retry: :class:`RetryPolicy` for transient dispatch failures
             (``None``: the default policy — 2 retries, exponential backoff
             from 2ms with deterministic jitter).
@@ -233,6 +270,7 @@ class TrussScheduler:
             raise ValueError("watchdog_s must be positive (or None)")
         if invariant_sample < 0:
             raise ValueError("invariant_sample must be >= 0")
+        _keep_freed_host_memory()
         if engine is None:
             engine_kwargs.setdefault("max_pending", 4 * max_batch + max_queue)
             engine = TrussEngine(**engine_kwargs)
@@ -593,6 +631,41 @@ class TrussScheduler:
             handle=self._check_handle(handle), k=int(k),
             t_deadline=self._deadline_for(t, deadline_ms)))
 
+    def community_async(self, handle: TrussHandle, vertex: int, k: int, *,
+                        tenant: str = "default",
+                        deadline_ms: float | None = None) -> Future:
+        """Queue a (q, k) community search; FIFO against the handle's updates.
+
+        The query of Huang et al. (SIGMOD 2014): every k-truss community
+        that contains vertex ``vertex``, served by
+        ``TrussHandle.community(vertex, k)`` from the handle's index under
+        the same hierarchy-site ladder as :meth:`communities_async`.  It
+        observes exactly the updates admitted on this handle before it.
+
+        Args:
+            handle: an open handle.
+            vertex: the query vertex ``q``.
+            k: community level.
+            tenant: admission-control accounting key.
+            deadline_ms: per-request deadline override.
+
+        Returns:
+            ``Future[list[np.ndarray]]`` — one ``(c, 2)`` endpoint array per
+            level-``k`` community among ``vertex``'s edges (empty when none
+            of them reaches trussness ``k``).
+
+        Raises:
+            Overloaded: shed by admission control.
+            TypeError: ``handle`` is not a :class:`TrussHandle`.
+            ValueError: the handle is already closed.
+            RuntimeError: the scheduler is closed.
+        """
+        t = time.perf_counter()
+        return self._admit(_Request(
+            kind="community", tenant=tenant, future=Future(), t_enq=t,
+            handle=self._check_handle(handle), vertex=int(vertex), k=int(k),
+            t_deadline=self._deadline_for(t, deadline_ms)))
+
     # ------------------------------------------------------------- the loop --
     def _loop(self) -> None:
         while True:
@@ -875,7 +948,7 @@ class TrussScheduler:
                     self._finish(req, exc=e)
                     continue
                 self._finish(req, value=h)
-            else:                               # update / query / communities
+            else:                   # update / query / communities / community
                 with self._lock:
                     self._hqueues.setdefault(
                         req.handle.hid, deque()).append(req)
@@ -1006,15 +1079,19 @@ class TrussScheduler:
         self._finish(req, value=out)
 
     def _resilient_communities(self, req: _Request):
-        """Community listing under the hierarchy-site ladder."""
+        """Community listing or (q, k) search under the hierarchy-site
+        ladder."""
         def call(rungs):
             rung = rungs["hierarchy"]
-            return req.handle.communities(
-                req.k, hier_mode=None if rung == "default" else rung)
+            mode = None if rung == "default" else rung
+            if req.kind == "community":
+                return req.handle.community(req.vertex, req.k,
+                                            hier_mode=mode)
+            return req.handle.communities(req.k, hier_mode=mode)
         return run_with_resilience(
             call, ladders={"hierarchy": self._ladders["hierarchy"]},
             primary="hierarchy", policy=self.retry,
-            deadline=req.t_deadline, kind="communities",
+            deadline=req.t_deadline, kind=req.kind,
             on_retry=self._count_retry)
 
     # ------------------------------------------------------ bucket dispatch --

@@ -55,6 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.graphs.csr import (CSRGraph, build_csr, canonical_edges_with_rows,
                               degeneracy_order, edge_keys, relabel)
 from repro.core import support as support_mod
@@ -264,8 +265,12 @@ class TrussHandle:
         return self._inc.insert_mode
 
     def query(self, edges) -> np.ndarray:
-        """Trussness for specific edges, aligned to the given rows."""
-        return self._inc.query(edges)
+        """Trussness for specific edges, aligned to the given rows.
+
+        The call is a ``repro.engine.query`` span with attribute ``rows``.
+        """
+        with spans.span("engine.query", rows=int(np.shape(edges)[0])):
+            return self._inc.query(edges)
 
     # --------------------------------------------- community queries (§11) --
     def hierarchy(self, *, mode: str | None = None):
@@ -299,7 +304,8 @@ class TrussHandle:
         ids_per = self._inc.hierarchy(mode=hier_mode).communities(k)
         return [E[ids] for ids in ids_per]
 
-    def community(self, edge_or_vertex, k: int):
+    def community(self, edge_or_vertex, k: int, *,
+                  hier_mode: str | None = None):
         """The k-truss community around one edge — or all around one vertex.
 
         An ``(u, v)`` pair returns that edge's community as a (c, 2)
@@ -307,19 +313,29 @@ class TrussHandle:
         edge not in the graph raises the descriptive alignment ValueError).
         A scalar vertex id returns a *list* of communities, one per distinct
         level-``k`` community among the vertex's incident edges — a vertex,
-        unlike an edge, can sit on the border of several k-trusses.
+        unlike an edge, can sit on the border of several k-trusses (Huang et
+        al.'s (q, k) query).  ``hier_mode`` overrides the index builder as
+        in :meth:`communities`.  The call is a ``repro.engine.community``
+        span with attribute ``k`` and counters ``communities`` and
+        ``edges`` returned; levels the index builds for it nest inside.
         """
-        h = self._inc.hierarchy()
-        E = self._inc.edges
-        q = np.asarray(edge_or_vertex)
-        if q.ndim == 0:                       # vertex query
-            v = int(q)
-            inc_ids = np.nonzero((E[:, 0] == v) | (E[:, 1] == v))[0]
-            labels = h.level_labels(k)[inc_ids]
-            reps = np.unique(labels[labels >= 0])
-            return [E[h.community_of(int(r), k)] for r in reps]
-        eid = int(self._inc.edge_ids(q.reshape(1, 2))[0])
-        return E[h.community_of(eid, k)]
+        with spans.span("engine.community", k=int(k)) as sp:
+            h = self._inc.hierarchy(mode=hier_mode)
+            E = self._inc.edges
+            q = np.asarray(edge_or_vertex)
+            if q.ndim == 0:                       # vertex query
+                v = int(q)
+                inc_ids = np.nonzero((E[:, 0] == v) | (E[:, 1] == v))[0]
+                labels = h.level_labels(k)[inc_ids]
+                reps = np.unique(labels[labels >= 0])
+                out = [E[h.community_of(int(r), k)] for r in reps]
+                sp.set(communities=len(out),
+                       edges=sum(c.shape[0] for c in out))
+                return out
+            eid = int(self._inc.edge_ids(q.reshape(1, 2))[0])
+            out = E[h.community_of(eid, k)]
+            sp.set(communities=int(out.shape[0] > 0), edges=out.shape[0])
+            return out
 
     def __repr__(self):
         state = "closed" if self.closed else f"m={self._inc.m}"

@@ -124,10 +124,10 @@ def test_engine_batched_flush_compiles(one_chip):
 
 
 def test_hierarchy_flood_jit_compiles(one_chip):
-    from repro.core.hierarchy import _labelprop_fns
+    from repro.core.hierarchy import _labelprop
 
     s, rows, mp = one_chip, 1 << 22, 1 << 18
-    c = _labelprop_fns().lower(
+    c = _labelprop.lower(
         _sds(s, (rows, 3)), _sds(s, (rows,)), _sds(s, ()), _sds(s, ()),
         _sds(s, (mp,)), sz=rows, mp=mp).compile()
     _fits_v5e(c)

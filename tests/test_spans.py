@@ -218,15 +218,34 @@ def _jitted_names():
     return names
 
 
+@pytest.fixture(scope="module")
+def program_span_names():
+    """Every span name the one-shot path and a handle's stream open: a
+    compacting ``truss_pkt``, an open, a deletion batch, a re-insertion
+    repaired locally, and a (q, k) community query."""
+    from repro.serve.truss_engine import TrussEngine
+
+    spans.drain()
+    E, _ = _graph("rmat")
+    truss_pkt(E, compact_frac=0.99, compact_min=0)
+    eng = TrussEngine()
+    h = eng.open(E, local_frac=1.0)
+    rows = h.edges[::7]
+    eng.update(h, remove_edges=rows)
+    eng.update(h, add_edges=rows)
+    h.community(int(rows[0, 0]), 3)
+    names = {r.name for r in spans.drain()}
+    yield names
+
+
 @pytest.mark.parametrize("path", METRICS, ids=lambda p: p.stem)
-def test_benchmark_metrics_name_what_the_program_has(path):
+def test_benchmark_metrics_name_what_the_program_has(path,
+                                                     program_span_names):
     """A metric reads jits by name and spans by name: a rename reads
     nothing, so every name a metric file gives must still exist."""
     spec = json.loads(path.read_text())
     if "jits" in spec:
         assert set(spec["jits"]) <= _jitted_names()
-    if "span" in spec:
-        spans.drain()
-        E, _ = _graph("rmat")
-        truss_pkt(E, compact_frac=0.99, compact_min=0)
-        assert spec["span"] in {r.name for r in spans.drain()}
+    named = ([spec["span"]] if "span" in spec else []) \
+        + spec.get("spans", []) + spec.get("roots", [])
+    assert set(named) <= program_span_names

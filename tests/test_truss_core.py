@@ -177,6 +177,31 @@ def test_support_equals_naive(seed):
     assert np.array_equal(compute_support_kernel(g), S_naive)
 
 
+def test_oriented_search_bound_covers_out_degrees_and_holds_under_churn():
+    """The support search's bound covers every N⁺ range, and on the scale-11
+    Graph Challenge Kronecker graph it stays put while batches of 64 edges
+    leave, though the largest out-degree crosses 64 (the bound is a static
+    argument: a change compiles a new program)."""
+    from repro.core import support as support_mod
+
+    rng = np.random.default_rng(0)
+    E = rmat_edges(11, 16, seed=0)
+    n = int(E.max()) + 1
+    iters, dmax = set(), set()
+    for r in range(6):
+        keep = np.ones(E.shape[0], bool)
+        if r:
+            keep[rng.choice(E.shape[0], 64, replace=False)] = False
+        g = build_csr(relabel(E[keep], degeneracy_order(E[keep], n)), n)
+        deg = g.degrees
+        h = max(h for h in range(n + 1) if (deg >= h).sum() >= h)
+        assert support_mod._h_index(deg) == h
+        iters.add(support_mod._search_iters(g, oriented=True))
+        dmax.add(int(g.dplus.max()))
+        assert 2 ** (min(iters) - 1) > max(dmax)
+    assert min(dmax) < 64 < max(dmax) and len(iters) == 1
+
+
 def test_triangle_count_invariants():
     E = _er_edges(60, 0.2, 42)
     g = build_csr(E)
