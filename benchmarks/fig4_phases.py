@@ -43,7 +43,7 @@ def run(suite=None, modes=MODES, support_modes=SUPPORT_MODES) -> list[str]:
 
         t0 = time.perf_counter()
         stab = support_mod.build_support_table(g)
-        ptab = support_mod.build_peel_table(g)
+        rows = support_mod.resolve_peel_table(g)
         t_tables = time.perf_counter() - t0
 
         t_support = {}
@@ -55,21 +55,19 @@ def run(suite=None, modes=MODES, support_modes=SUPPORT_MODES) -> list[str]:
                 lambda: support_mod.compute_support(g, stab, mode=smode))
         S0 = support_mod.compute_support(g, stab)
 
-        tabs, chunk, n_chunks = prepare_peel(ptab, g.m, None)   # tuned/auto chunk policy
-        N, Eid = jnp.asarray(g.N), jnp.asarray(g.Eid)
-        iters = support_mod._search_iters(g)
+        tabs, chunk, n_chunks = prepare_peel(rows, g.m, None)   # tuned/auto chunk policy
 
         t_peel = {}
         for pmode in modes:
             if pmode == "pallas" and not on_tpu \
-                    and ptab.size > PALLAS_MAX_WEDGES:
+                    and rows.wedges > PALLAS_MAX_WEDGES:
                 continue
 
             def peel():
                 # fresh S0 upload per call: _pkt_peel_jit donates its S0
-                S, _, _ = _pkt_peel_jit(N, Eid, jnp.asarray(S0), tabs,
+                S, _, _ = _pkt_peel_jit(jnp.asarray(S0), tabs,
                                         m=g.m, chunk=chunk,
-                                        n_chunks=n_chunks, iters=iters,
+                                        n_chunks=n_chunks,
                                         mode=pmode, interpret=not on_tpu)
                 S.block_until_ready()
 

@@ -20,27 +20,19 @@ from repro.core.pkt import prepare_peel, _SENTINEL_S
 from benchmarks.common import prep_graph, row
 
 
-@functools.partial(jax.jit, static_argnames=("m", "chunk", "n_chunks",
-                                             "iters"))
-def _one_level(N, Eid, S_ext, processed, tabs, *, m, chunk, n_chunks, iters):
+@functools.partial(jax.jit, static_argnames=("m", "chunk", "n_chunks"))
+def _one_level(S_ext, processed, tabs, *, m, chunk, n_chunks):
     """Peel one full level (all sub-levels); returns updated state + level."""
-    two_m = N.shape[0]
     l = jnp.min(jnp.where(processed, _SENTINEL_S, S_ext))
     inCurr = (~processed) & (S_ext == l)
 
     def chunk_contrib(c, dec, S_ext, processed, inCurr):
         base = c * chunk
         e1 = jax.lax.dynamic_slice(tabs.e1, (base,), (chunk,))
-        cand = jax.lax.dynamic_slice(tabs.cand_slot, (base,), (chunk,))
-        lo = jax.lax.dynamic_slice(tabs.lo, (base,), (chunk,))
-        hi = jax.lax.dynamic_slice(tabs.hi, (base,), (chunk,))
+        e2 = jax.lax.dynamic_slice(tabs.e2, (base,), (chunk,))
+        e3 = jax.lax.dynamic_slice(tabs.e3, (base,), (chunk,))
         in1 = inCurr[e1]
-        w = N[cand]
-        idx = support_mod.ranged_searchsorted(N, w, lo, hi, iters)
-        safe = jnp.minimum(idx, two_m - 1)
-        hit = (idx < hi) & (N[safe] == w)
-        e2, e3 = Eid[cand], Eid[safe]
-        valid = in1 & hit & ~processed[e2] & ~processed[e3]
+        valid = in1 & ~processed[e2] & ~processed[e3]
         dec2 = valid & (S_ext[e2] > l) & ((~inCurr[e3]) | (e1 < e3))
         dec3 = valid & (S_ext[e3] > l) & ((~inCurr[e2]) | (e1 < e2))
         dec = dec.at[jnp.where(dec2, e2, m)].add(dec2.astype(jnp.int32))
@@ -85,11 +77,9 @@ def run(suite=("rmat-small", "cliques-small", "ba-small")) -> list[str]:
     for name in suite:
         g, stats = prep_graph(name, order="kco")
         stab = support_mod.build_support_table(g)
-        ptab = support_mod.build_peel_table(g)
+        rows = support_mod.resolve_peel_table(g)
         S0 = support_mod.compute_support(g, stab)
-        tabs, chunk, n_chunks = prepare_peel(ptab, g.m, None)   # tuned/auto chunk policy
-        N, Eid = jnp.asarray(g.N), jnp.asarray(g.Eid)
-        iters = support_mod._search_iters(g)
+        tabs, chunk, n_chunks = prepare_peel(rows, g.m, None)   # tuned/auto chunk policy
 
         S_ext = jnp.concatenate([jnp.asarray(S0),
                                  jnp.full((1,), _SENTINEL_S)])
@@ -98,8 +88,8 @@ def run(suite=("rmat-small", "cliques-small", "ba-small")) -> list[str]:
         while int(jnp.sum(processed)) < g.m + 1:
             t0 = time.perf_counter()
             S_ext, processed, l, subs = _one_level(
-                N, Eid, S_ext, processed, tabs, m=g.m, chunk=chunk,
-                n_chunks=n_chunks, iters=iters)
+                S_ext, processed, tabs, m=g.m, chunk=chunk,
+                n_chunks=n_chunks)
             S_ext.block_until_ready()
             times.append(time.perf_counter() - t0)
             levels.append(int(l))

@@ -10,6 +10,7 @@ from repro.core.support import (
     triangle_count,
     build_support_table,
     build_peel_table,
+    resolve_peel_table,
     support_table_size,
     peel_table_size,
     TABLE_MODES,
@@ -26,7 +27,7 @@ __all__ = [
     "IncrementalTruss", "UpdateStats",
     "TrussHierarchy", "HIER_MODES", "hierarchy_from_graph",
     "compute_support", "compute_support_ros", "triangle_count",
-    "build_support_table", "build_peel_table",
+    "build_support_table", "build_peel_table", "resolve_peel_table",
     "support_table_size", "peel_table_size", "TABLE_MODES",
     "truss_wc", "truss_ros", "truss_numpy",
     "truss_trilist", "enumerate_triangles",

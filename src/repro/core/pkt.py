@@ -9,11 +9,17 @@ JAX/TPU adaptation of the OpenMP original (see DESIGN.md §2 for the mapping):
                       scatter-add, then  S ← max(S − dec, l)  (identical fixed
                       point, bitwise deterministic)
   * tie-break       → the paper's "lowest frontier edge id processes the
-                      triangle" predicate evaluated vectorially per wedge hit
-  * dynamic sched.  → chunk-skipping: the flat peel-wedge table is cut into
-                      fixed chunks; a sub-level only visits chunks overlapping
+                      triangle" predicate evaluated vectorially per table row
+  * dynamic sched.  → chunk-skipping: the flat peel table is cut into fixed
+                      chunks; a sub-level only visits chunks overlapping
                       frontier edges' ranges (work-efficiency: each triangle's
-                      wedge entries are scanned O(1) times over the whole run)
+                      rows are scanned O(1) times over the whole run)
+
+The peel table holds resolved triangle rows ``(e1, e2, e3)`` (DESIGN.md
+§10): the wedge probe that decides whether a row closes a triangle, and
+which two edges close it, depends only on the graph, so the table builder
+runs it once and keeps the hits; the peel gathers edge state at three
+edge ids per row and never searches.
 
 Three peel modes (``mode`` / ``peel_mode``):
   mode="chunked" (default): work-efficient chunk-skipping while_loop.
@@ -61,12 +67,19 @@ PEEL_MODES = ("chunked", "dense", "pallas")
 
 
 class PeelTables(NamedTuple):
-    """Device-resident static tables for the peel phase (padded to chunks)."""
+    """Device-resident static tables for the peel phase (padded to chunks).
 
-    e1: jnp.ndarray         # (n_chunks*C,) int32, sentinel m
-    cand_slot: jnp.ndarray  # (n_chunks*C,) int32, sentinel 0
-    lo: jnp.ndarray         # (n_chunks*C,) int32, sentinel 0
-    hi: jnp.ndarray         # (n_chunks*C,) int32, sentinel 0  (lo==hi → miss)
+    One row per (edge ``e1``, triangle through it): ``e2``/``e3`` are the
+    triangle's two other edges (``support.PeelRows``).  Rows are grouped
+    by ``e1``; padding rows carry the sentinel ``m`` in all three columns,
+    which the peel never selects (slot ``m`` is processed, never in the
+    frontier).  The padded length is that of the wedge table the rows were
+    resolved from, which 3T never exceeds.
+    """
+
+    e1: jnp.ndarray         # (n_chunks*C,) int32 anchor edge, sentinel m
+    e2: jnp.ndarray         # (n_chunks*C,) int32 closing edge, sentinel m
+    e3: jnp.ndarray         # (n_chunks*C,) int32 closing edge, sentinel m
     c_start: jnp.ndarray    # (m,) int32   first chunk containing edge e
     c_end: jnp.ndarray      # (m,) int32   last chunk containing edge e (inclusive)
     has_entries: jnp.ndarray  # (m,) bool
@@ -119,23 +132,21 @@ def chunk_ranges(off: np.ndarray, chunk: int,
     return has, c_start, c_end
 
 
-def _pad_tables(tab: support_mod.WedgeTable, m: int, chunk: int,
-                n_chunks: int) -> PeelTables:
-    e1, cand, lo, hi = wedge_common.pad_chunked(
-        tab.e1, tab.cand_slot, tab.lo, tab.hi,
-        m=m, chunk=chunk, n_chunks=n_chunks)
-    has, c_start, c_end = chunk_ranges(tab.off, chunk)
-    return PeelTables(
-        e1=jnp.asarray(e1), cand_slot=jnp.asarray(cand),
-        lo=jnp.asarray(lo), hi=jnp.asarray(hi),
-        c_start=jnp.asarray(c_start), c_end=jnp.asarray(c_end),
-        has_entries=jnp.asarray(has),
-    )
+def _pad_tables(rows: support_mod.PeelRows, chunk: int, n_chunks: int,
+                m_out: int) -> PeelTables:
+    """Host rows padded to ``n_chunks * chunk`` with the sentinel ``m_out``
+    (the edge space, at least the rows' m) in all three columns."""
+    size = n_chunks * chunk
+    e1, e2, e3 = (jnp.asarray(wedge_common.pad1(col, size, m_out))
+                  for col in (rows.e1, rows.e2, rows.e3))
+    has, c_start, c_end = chunk_ranges(rows.off, chunk, m_out=m_out)
+    return PeelTables(e1=e1, e2=e2, e3=e3, c_start=jnp.asarray(c_start),
+                      c_end=jnp.asarray(c_end), has_entries=jnp.asarray(has))
 
 
-def prepare_peel(tab: support_mod.WedgeTable, m: int,
+def prepare_peel(rows: support_mod.PeelRows, m: int,
                  chunk: int | None) -> tuple[PeelTables, int, int]:
-    """Clamp ``chunk`` to the table, pad, and derive ``n_chunks``.
+    """Clamp ``chunk`` to the wedge table, pad, and derive ``n_chunks``.
 
     The single place where the chunk size is sanitized (the layout policy
     itself lives in ``kernels.wedge_common.chunk_layout``): a user-passed
@@ -144,15 +155,17 @@ def prepare_peel(tab: support_mod.WedgeTable, m: int,
     wedge entries) used to be able to reach ``n_chunks == 0`` through the
     old call-site-local ``min(chunk, size)`` dance.
 
-    A table with no entries at all — the empty graph (m == 0), or a support
-    table of a triangle-free orientation — takes an explicit early-exit
-    rather than relying on the clamping arithmetic: one all-padding chunk of
-    size 1, every edge marked entry-less.
+    The layout follows the wedge count ``rows.wedges``, as the device
+    builder's does, so both table modes peel the same chunks.  Rows
+    resolved from no wedges at all — the empty graph (m == 0), or edges
+    with no common-neighbour candidates — take an explicit early-exit
+    rather than relying on the clamping arithmetic: one all-padding chunk
+    of size 1, every edge marked entry-less.
     """
-    if tab.size == 0:
+    if rows.wedges == 0:
         return _empty_peel_tables(m), 1, 1
-    chunk, n_chunks = wedge_common.chunk_layout(tab.size, chunk)
-    tabs = _pad_tables(tab, m, chunk, n_chunks)
+    chunk, n_chunks = wedge_common.chunk_layout(rows.wedges, chunk)
+    tabs = _pad_tables(rows, chunk, n_chunks, m)
     assert tabs.e1.shape[0] == n_chunks * chunk
     return tabs, chunk, n_chunks
 
@@ -161,9 +174,8 @@ def _empty_peel_tables(m: int) -> PeelTables:
     """One all-padding chunk of size 1; every edge entry-less."""
     return PeelTables(
         e1=jnp.full((1,), m, jnp.int32),
-        cand_slot=jnp.zeros((1,), jnp.int32),
-        lo=jnp.zeros((1,), jnp.int32),
-        hi=jnp.zeros((1,), jnp.int32),
+        e2=jnp.full((1,), m, jnp.int32),
+        e3=jnp.full((1,), m, jnp.int32),
         c_start=jnp.zeros((m,), jnp.int32),
         c_end=jnp.zeros((m,), jnp.int32),
         has_entries=jnp.zeros((m,), jnp.bool_),
@@ -172,22 +184,29 @@ def _empty_peel_tables(m: int) -> PeelTables:
 
 def prepare_peel_device(g: CSRGraph, chunk: int | None, *,
                         m_out: int | None = None,
-                        m_real: int | None = None) -> tuple[PeelTables, int,
-                                                            int]:
+                        m_real: int | None = None
+                        ) -> tuple[PeelTables, int, int, object]:
     """Device-built peel tables for ``g``, pow2-padded (DESIGN.md §10).
 
-    The device counterpart of ``build_peel_table`` + ``prepare_peel``: the
-    table entry count is bounded on host (O(m)), rows are materialized on
-    device to the next power of two, and the chunk-range metadata is
-    computed in the same jit.  ``m_out`` (default ``g.m``) sizes the edge
-    state space (the batched/compacted callers pad it to a pow2 bucket);
-    ``m_real`` marks how many leading edge slots are real.
+    The device counterpart of ``resolve_peel_table`` + ``prepare_peel``:
+    the wedge-table entry count is bounded on host (O(m)) and sets the
+    table's pow2 padded size and chunk; one jit
+    (``support._build_peel_table_dev``) expands the wedge rows, probes each
+    once, keeps the hits and computes the chunk-range metadata.
+    ``m_out`` (default ``g.m``) sizes the edge state space (compacted
+    callers pad it to a pow2 bucket, and this pads ``u``, ``v``, ``Es``,
+    ``N`` and ``Eid`` to it); ``m_real`` marks how many leading edge
+    slots are real.  A padded bucket bounds the probe by its pow2 vertex
+    dimension, which is already a shape of the builder, so a graph whose
+    largest degree wanders under edge updates compiles no new builder.
+    Returns ``(tabs, chunk, n_chunks, hits)`` with ``hits`` the rows kept
+    (3T) as a device scalar, read back with the first peel segment.
     """
     m_out = g.m if m_out is None else m_out
     m_real = g.m if m_real is None else m_real
     size = support_mod.peel_table_size(g)
     if size == 0:
-        return _empty_peel_tables(m_out), 1, 1
+        return _empty_peel_tables(m_out), 1, 1, 0
     size_pad = wedge_common.next_pow2(size)
     support_mod._check_table_size(size_pad)
     chunk_eff = wedge_common.pow2_chunk(size_pad, chunk, size=size)
@@ -201,16 +220,19 @@ def prepare_peel_device(g: CSRGraph, chunk: int | None, *,
         v = jnp.asarray(wedge_common.pad1(g.El[:, 1], m_out, 0))
         n_es = wedge_common.next_pow2(g.n + 1)
         Es = jnp.asarray(wedge_common.pad1(g.Es, n_es, 2 * g.m))
+        N = jnp.asarray(wedge_common.pad1(g.N, 2 * m_out, wedge_common.PAD_N))
+        Eid = jnp.asarray(wedge_common.pad1(g.Eid, 2 * m_out, m_out))
+        # a degree is below n_es, so log2(n_es) + 1 >= _search_iters(g)
+        iters = n_es.bit_length()
     else:
         dev = g.device_arrays()
         u, v, Es = dev["El"][:, 0], dev["El"][:, 1], dev["Es"]
-    e1, cand, lo, hi, _off, c_start, c_end, has = \
-        support_mod._build_peel_table_dev(
-            u, v, Es, jnp.int32(m_real), m=m_out, size=size_pad,
-            chunk=chunk_eff)
-    tabs = PeelTables(e1=e1, cand_slot=cand, lo=lo, hi=hi, c_start=c_start,
-                      c_end=c_end, has_entries=has)
-    return tabs, chunk_eff, n_chunks
+        N, Eid = dev["N"], dev["Eid"]
+        iters = support_mod._search_iters(g)
+    *cols, hits = support_mod._build_peel_table_dev(
+        u, v, Es, N, Eid, jnp.int32(m_real), m=m_out, size=size_pad,
+        chunk=chunk_eff, iters=iters)
+    return PeelTables(*cols), chunk_eff, n_chunks, hits
 
 
 def _active_chunk_mask(inCurr, tabs: PeelTables, m: int, n_chunks: int):
@@ -224,10 +246,9 @@ def _active_chunk_mask(inCurr, tabs: PeelTables, m: int, n_chunks: int):
     return jnp.cumsum(delta[:n_chunks]) > 0
 
 
-def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
-               chunk: int, n_chunks: int, iters: int, mode: str,
-               interpret: bool = True, pinned=None, stop_live=None,
-               reduce=None):
+def _peel_loop(S_ext0, processed0, tabs: PeelTables, *, m: int, chunk: int,
+               n_chunks: int, mode: str, interpret: bool = True, pinned=None,
+               stop_live=None, reduce=None):
     """Full level/sub-level peel over extended (m+1,) edge state.
 
     ``S_ext0``/``processed0`` define which slots are live: slot m must be the
@@ -255,17 +276,13 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
     shard.
     """
     def chunk_contrib(c, dec, S_ext, processed, inCurr, l):
-        """Decrement contributions from one chunk of the wedge table."""
+        """Decrement contributions from one chunk of the peel table."""
         base = c * chunk
         e1 = jax.lax.dynamic_slice(tabs.e1, (base,), (chunk,))
-        cand = jax.lax.dynamic_slice(tabs.cand_slot, (base,), (chunk,))
-        lo = jax.lax.dynamic_slice(tabs.lo, (base,), (chunk,))
-        hi = jax.lax.dynamic_slice(tabs.hi, (base,), (chunk,))
-        in1 = inCurr[e1]
-        hit, safe = wedge_common.probe(N, cand, lo, hi, iters=iters)
-        e2 = Eid[cand]
-        e3 = Eid[safe]
-        valid = in1 & hit & ~processed[e2] & ~processed[e3]
+        e2 = jax.lax.dynamic_slice(tabs.e2, (base,), (chunk,))
+        e3 = jax.lax.dynamic_slice(tabs.e3, (base,), (chunk,))
+        in1 = inCurr[e1]   # sentinel rows: inCurr[m] is False
+        valid = in1 & ~processed[e2] & ~processed[e3]
         s2 = S_ext[e2]
         s3 = S_ext[e3]
         in2 = inCurr[e2]
@@ -298,11 +315,10 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
             dec = peel_decrement_fold(
                 active.astype(jnp.int32),
                 jnp.reshape(l, (1,)).astype(jnp.int32),
-                tabs.e1, tabs.cand_slot, tabs.lo, tabs.hi, N, Eid,
+                tabs.e1, tabs.e2, tabs.e3,
                 S_ext, processed.astype(jnp.int32),
                 inCurr.astype(jnp.int32), pin,
-                chunk=chunk, n_chunks=n_chunks, iters=iters, m=m,
-                interpret=interpret)
+                chunk=chunk, n_chunks=n_chunks, m=m, interpret=interpret)
         else:  # chunked: visit only chunks overlapping the frontier
             active = _active_chunk_mask(inCurr, tabs, m, n_chunks)
             n_active = jnp.sum(active.astype(jnp.int32))
@@ -367,30 +383,30 @@ def _peel_loop(N, Eid, S_ext0, processed0, tabs: PeelTables, *, m: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "iters", "mode", "interpret"),
-    donate_argnums=(2,),  # S0: consumed into the peel state, never reread
+    static_argnames=("m", "chunk", "n_chunks", "mode", "interpret"),
+    donate_argnums=(0,),  # S0: consumed into the peel state, never reread
 )
-def _pkt_peel_jit(N, Eid, S0, tabs: PeelTables, *, m: int, chunk: int,
-                  n_chunks: int, iters: int, mode: str = "chunked",
+def _pkt_peel_jit(S0, tabs: PeelTables, *, m: int, chunk: int,
+                  n_chunks: int, mode: str = "chunked",
                   interpret: bool = True):
     """Runs the full level/sub-level peel; returns (S_final, levels, sublevels)."""
     # extended edge state: slot m is a sentinel (processed, never in frontier)
     S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
     processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
     S_ext, _, levels, subs, _ = _peel_loop(
-        N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-        n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
+        S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
+        mode=mode, interpret=interpret)
     return S_ext[:m], levels, subs
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "iters", "mode", "interpret"),
-    donate_argnums=(2, 3),  # peel-state buffers: never reread by the driver
+    static_argnames=("m", "chunk", "n_chunks", "mode", "interpret"),
+    donate_argnums=(0, 1),  # peel-state buffers: never reread by the caller
 )
-def _peel_segment_jit(N, Eid, S_ext0, processed0, stop_live, pinned,
+def _peel_segment_jit(S_ext0, processed0, stop_live, pinned,
                       tabs: PeelTables, *, m: int, chunk: int, n_chunks: int,
-                      iters: int, mode: str, interpret: bool):
+                      mode: str, interpret: bool):
     """One compaction segment: peel until done or ≤ ``stop_live`` edges live.
 
     Returns (S_ext, processed, counts): ``counts`` stacks levels,
@@ -400,9 +416,8 @@ def _peel_segment_jit(N, Eid, S_ext0, processed0, stop_live, pinned,
     memory holds one state generation, not two.
     """
     S_ext, processed, levels, subs, visits = _peel_loop(
-        N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-        n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret,
-        pinned=pinned, stop_live=stop_live)
+        S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
+        mode=mode, interpret=interpret, pinned=pinned, stop_live=stop_live)
     return S_ext, processed, jnp.stack([levels, subs, visits])
 
 
@@ -449,29 +464,22 @@ def _make_subproblem(El_rows: np.ndarray, ids: np.ndarray,
     E_sub = np.searchsorted(verts, El_rows).astype(np.int64)
     g_sub = build_csr(E_sub, verts.shape[0])
     m_pad = max(_MIN_M_PAD, wedge_common.next_pow2(m_sub))
+    wedges = support_mod.peel_table_size(g_sub)
 
     if table_mode == "device":
-        tabs, chunk_eff, n_chunks = prepare_peel_device(
+        tabs, chunk_eff, n_chunks, hits = prepare_peel_device(
             g_sub, chunk_req, m_out=m_pad, m_real=m_sub)
     else:
-        tab = support_mod.build_peel_table(g_sub)
-        if tab.size == 0:
+        rows = support_mod.resolve_peel_table(g_sub)
+        hits = rows.size
+        if wedges == 0:
             tabs, chunk_eff, n_chunks = _empty_peel_tables(m_pad), 1, 1
         else:
-            size_pad = wedge_common.next_pow2(tab.size)
+            size_pad = wedge_common.next_pow2(wedges)
             chunk_eff = wedge_common.pow2_chunk(size_pad, chunk_req,
-                                                size=tab.size)
+                                                size=wedges)
             n_chunks = size_pad // chunk_eff
-            e1, cand, lo, hi = wedge_common.pad_chunked(
-                tab.e1, tab.cand_slot, tab.lo, tab.hi,
-                m=m_pad, chunk=chunk_eff, n_chunks=n_chunks)
-            has, c_start, c_end = chunk_ranges(tab.off, chunk_eff,
-                                               m_out=m_pad)
-            tabs = PeelTables(
-                e1=jnp.asarray(e1), cand_slot=jnp.asarray(cand),
-                lo=jnp.asarray(lo), hi=jnp.asarray(hi),
-                c_start=jnp.asarray(c_start), c_end=jnp.asarray(c_end),
-                has_entries=jnp.asarray(has))
+            tabs = _pad_tables(rows, chunk_eff, n_chunks, m_pad)
 
     S_ext0 = np.full(m_pad + 1, int(_SENTINEL_S), np.int32)
     S_ext0[:m_sub] = S_rows
@@ -486,11 +494,8 @@ def _make_subproblem(El_rows: np.ndarray, ids: np.ndarray,
         pinned_np[:m_sub] = pinned_rows
         pinned = jnp.asarray(pinned_np)
     return dict(
-        N=jnp.asarray(wedge_common.pad1(g_sub.N, 2 * m_pad,
-                                        wedge_common.PAD_N)),
-        Eid=jnp.asarray(wedge_common.pad1(g_sub.Eid, 2 * m_pad, m_pad)),
-        tabs=tabs, chunk=chunk_eff, n_chunks=n_chunks,
-        iters=int(np.ceil(np.log2(2 * m_pad + 1))) + 1, m=m_pad, live=m_sub,
+        tabs=tabs, chunk=chunk_eff, n_chunks=n_chunks, table_rows=wedges,
+        table_hits=hits, m=m_pad, live=m_sub,
         S_ext0=jnp.asarray(S_ext0), processed0=jnp.asarray(processed0),
         pinned=pinned, pinned_np=pinned_np, El=g_sub.El, ids=ids_pad)
 
@@ -507,7 +512,10 @@ def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
     ``problem['ids']`` slots) and survivors are re-bucketed via
     ``_make_subproblem``.  Each segment, its readback included, is a
     ``repro.pkt.peel_segment`` span carrying its levels, sub-levels and
-    chunk visits; each compaction is a ``repro.pkt.compact`` span.
+    chunk visits, and its table's wedge rows (``table_rows``) and the rows
+    the build kept (``table_hits``, 3T of the segment's graph, read back
+    with the segment's state); each compaction is a ``repro.pkt.compact``
+    span.
     Returns (levels, sublevels, chunk_visits, compactions).
     """
     counts = np.zeros(3, np.int64)
@@ -522,16 +530,17 @@ def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
             live_target = min(int(compact_frac * m), n_live - 1)
         with spans.span("pkt.peel_segment", segment=compactions, m_pad=m,
                         live=n_live, chunk=problem["chunk"],
-                        n_chunks=problem["n_chunks"]) as sp:
+                        n_chunks=problem["n_chunks"],
+                        table_rows=problem["table_rows"]) as sp:
             S_ext, processed, seg = _peel_segment_jit(
-                problem["N"], problem["Eid"], problem["S_ext0"],
-                problem["processed0"], jnp.int32(live_target),
-                problem["pinned"], problem["tabs"], m=m,
-                chunk=problem["chunk"], n_chunks=problem["n_chunks"],
-                iters=problem["iters"], mode=mode, interpret=interpret)
-            S_np, proc_np, seg = jax.device_get((S_ext, processed, seg))
+                problem["S_ext0"], problem["processed0"],
+                jnp.int32(live_target), problem["pinned"], problem["tabs"],
+                m=m, chunk=problem["chunk"], n_chunks=problem["n_chunks"],
+                mode=mode, interpret=interpret)
+            S_np, proc_np, seg, hits = jax.device_get(
+                (S_ext, processed, seg, problem["table_hits"]))
             sp.set(levels=int(seg[0]), sublevels=int(seg[1]),
-                   chunk_visits=int(seg[2]))
+                   chunk_visits=int(seg[2]), table_hits=int(hits))
         counts += seg
         S_np, proc_np = S_np[:m], proc_np[:m]
         ids = problem["ids"]
@@ -625,7 +634,8 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             kept as the parity oracle.
         support_table: optional prebuilt host support table (implies
             ``table_mode="numpy"`` unless overridden).
-        peel_table: optional prebuilt host peel table (same implication).
+        peel_table: optional prebuilt host peel wedge table (same
+            implication); it is resolved to triangle rows on the host.
         interpret: force/forbid Pallas interpret mode (default: interpret
             when not on a TPU).
         compact_frac: live-edge compaction threshold (DESIGN.md §10): once
@@ -692,28 +702,28 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
         # ---- peel tables ---------------------------------------------------
         with spans.span("pkt.tables", table="peel") as sp:
             if table_mode == "device" and peel_table is None:
-                tabs, chunk_eff, n_chunks = prepare_peel_device(g, chunk)
+                tabs, chunk_eff, n_chunks, hits = prepare_peel_device(g, chunk)
+                wedges = support_mod.peel_table_size(g)
             else:
-                ptab = (peel_table if peel_table is not None
-                        else support_mod.build_peel_table(g))
-                tabs, chunk_eff, n_chunks = prepare_peel(ptab, g.m, chunk)
-                sp.set(rows=ptab.size)
+                rows = support_mod.resolve_peel_table(g, peel_table)
+                tabs, chunk_eff, n_chunks = prepare_peel(rows, g.m, chunk)
+                hits, wedges = rows.size, rows.wedges
+                sp.set(rows=rows.size)
             sp.set(padded_rows=chunk_eff * n_chunks, chunk=chunk_eff,
                    n_chunks=n_chunks)
             if phase_timings:
                 tabs.e1.block_until_ready()
 
         # ---- segmented peel with live-edge compaction ----------------------
-        dev = g.device_arrays()
         m = g.m
         S_ext0 = jnp.concatenate(
             [S0_dev.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
         processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
         problem = dict(
-            N=dev["N"], Eid=dev["Eid"], tabs=tabs, chunk=chunk_eff,
-            n_chunks=n_chunks, iters=support_mod._search_iters(g), m=m,
-            live=m, S_ext0=S_ext0, processed0=processed0, pinned=None,
-            pinned_np=None, El=g.El, ids=np.arange(m, dtype=np.int64))
+            tabs=tabs, chunk=chunk_eff, n_chunks=n_chunks, table_rows=wedges,
+            table_hits=hits, m=m, live=m, S_ext0=S_ext0,
+            processed0=processed0, pinned=None, pinned_np=None, El=g.El,
+            ids=np.arange(m, dtype=np.int64))
         S_out = np.zeros(m, np.int32)
         levels, subs, visits, compactions = _segmented_peel(
             problem, S_out, mode=mode, interpret=interpret,

@@ -4,9 +4,11 @@ The paper closes with: "porting this algorithm to GPU and distributed-memory
 settings appears to be non-trivial." This module is that port, in the BSP
 idiom natural to an SPMD mesh:
 
-  * the flat peel-wedge table (the unit of peel work) is sharded across a mesh
-    axis: each device builds its own contiguous slice of rows from the
-    replicated CSR arrays, so no device ever holds the whole table;
+  * the flat peel table (the unit of peel work) is sharded across a mesh
+    axis: each device resolves its own contiguous slice of the wedge rows
+    from the replicated CSR arrays (one probe a row, hits kept as triangle
+    rows ``(e1, e2, e3)``, ``core.support.peel_rows``) along with its own
+    per-edge chunk ranges, so no device ever holds the whole table;
   * edge state (S, processed, frontier) is replicated; each device runs the
     single-device chunk-skipping peel loop (``core.pkt._peel_loop``) over its
     slice, and one `psum` of the decrement vector per sub-level is the only
@@ -74,55 +76,50 @@ def _dist_support_dev_body(u, v, Es, Eo, N, Eid, *, m: int, per_shard: int,
                               interpret=interpret)
 
 
-def _dist_peel_rows_body(u, v, Es, *, m: int, per_shard: int, chunk: int,
-                         axes: Sequence[str]):
-    """This device's peel-table slice + whole-table chunk metadata."""
+def _dist_peel_rows_body(u, v, Es, N, Eid, *, m: int, per_shard: int,
+                         chunk: int, iters: int, axes: Sequence[str]):
+    """This device's resolved peel-table slice and its own chunk ranges."""
     start = jax.lax.axis_index(tuple(axes)) * per_shard
-    e1, cand, lo, hi, _off, c_start, c_end, has = support_mod.peel_rows(
-        u, v, Es, jnp.int32(m), m=m, size=per_shard, chunk=chunk, start=start)
-    return e1, cand, lo, hi, c_start, c_end, has
+    e1, e2, e3, _off, c_start, c_end, has = support_mod.peel_rows(
+        u, v, Es, N, Eid, jnp.int32(m), m=m, size=per_shard, chunk=chunk,
+        iters=iters, start=start)
+    return e1, e2, e3, c_start, c_end, has
 
 
-def _dist_peel_body(N, Eid, S0, e1, cand, lo, hi, c_start, c_end, has, *,
-                    m: int, iters: int, chunk: int, axes: Sequence[str]):
+def _dist_peel_body(S0, e1, e2, e3, c_start, c_end, has, *, m: int,
+                    chunk: int, axes: Sequence[str]):
     """Chunk-skipping peel over this device's table slice (inside shard_map).
 
-    ``c_start``/``c_end``/``has`` index the whole table's chunks; they are
-    clipped to this device's chunk window, so each device visits only its
-    own chunks that overlap the frontier.
+    ``c_start``/``c_end``/``has`` are this device's own (m,) chunk ranges
+    into its slice, so each device visits only its chunks that overlap the
+    frontier.
     """
-    n_chunks = e1.shape[0] // chunk
-    base = jax.lax.axis_index(tuple(axes)) * n_chunks
-    mine = has & (c_end >= base) & (c_start < base + n_chunks)
-    tabs = PeelTables(
-        e1, cand, lo, hi,
-        jnp.clip(c_start - base, 0, n_chunks - 1),
-        jnp.clip(c_end - base, 0, n_chunks - 1), mine)
+    tabs = PeelTables(e1, e2, e3, c_start, c_end, has)
     S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
     processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
     S_ext, _, levels, subs, _ = _peel_loop(
-        N, Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-        n_chunks=n_chunks, iters=iters, mode="chunked",
+        S_ext0, processed0, tabs, m=m, chunk=chunk,
+        n_chunks=e1.shape[0] // chunk, mode="chunked",
         reduce=functools.partial(_psum, axes=axes))
     return S_ext[:m], levels, subs
 
 
 @functools.lru_cache(maxsize=None)
 def make_pkt_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *, m: int,
-                  iters: int, chunk: int = 1 << 14):
+                  chunk: int = 1 << 14):
     """Builds the jitted distributed peel for dry-run or execution.
 
-    The returned fn takes (N, Eid, S0, e1, cand, lo, hi, c_start, c_end,
-    has): the four table arrays sharded over ``axes`` (each shard a whole
-    number of ``chunk``-row chunks), the rest replicated.  Returns
-    replicated (S_final, levels, sublevels).  Cached per argument set, so
-    a repeated decomposition compiles nothing.
+    The returned fn takes (S0, e1, e2, e3, c_start, c_end, has): S0
+    replicated, the rest sharded over ``axes`` — each device holds a slice
+    of table rows (a whole number of ``chunk``-row chunks) and the (m,)
+    chunk ranges of that slice.  Returns replicated (S_final, levels,
+    sublevels).  Cached per argument set, so a repeated decomposition
+    compiles nothing.
     """
     rep, sh = P(), P(tuple(axes))
     fn = jax.shard_map(
-        functools.partial(_dist_peel_body, m=m, iters=iters, chunk=chunk,
-                          axes=axes),
-        mesh=mesh, in_specs=(rep, rep, rep, sh, sh, sh, sh, rep, rep, rep),
+        functools.partial(_dist_peel_body, m=m, chunk=chunk, axes=axes),
+        mesh=mesh, in_specs=(rep,) + (sh,) * 6,
         out_specs=(rep, rep, rep), check_vma=False)
     return jax.jit(fn)
 
@@ -160,13 +157,34 @@ def _support_dev_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
 
 @functools.lru_cache(maxsize=None)
 def _peel_rows_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
-                    m: int, per_shard: int, chunk: int):
-    """Jitted shard_map: each device builds its peel-table slice."""
+                    m: int, per_shard: int, chunk: int, iters: int):
+    """Jitted shard_map: each device resolves its peel-table slice."""
     return jax.jit(jax.shard_map(
         functools.partial(_dist_peel_rows_body, m=m, per_shard=per_shard,
-                          chunk=chunk, axes=axes),
-        mesh=mesh, in_specs=(P(),) * 3,
-        out_specs=(P(axes),) * 4 + (P(),) * 3, check_vma=False))
+                          chunk=chunk, iters=iters, axes=axes),
+        mesh=mesh, in_specs=(P(),) * 5, out_specs=(P(axes),) * 6,
+        check_vma=False))
+
+
+def _host_peel_shards(g: CSRGraph, n_shards: int, per: int,
+                      chunk: int) -> list[np.ndarray]:
+    """Host-resolved peel rows split evenly into ``n_shards`` slices of
+    ``per`` rows, with each slice's (m,) chunk ranges; every array is the
+    shards' parts concatenated, ready to shard along the mesh axes."""
+    from repro.core.pkt import chunk_ranges
+
+    rows = support_mod.resolve_peel_table(g)
+    step = -(-rows.size // n_shards)
+    parts = [[] for _ in range(6)]
+    for k in range(n_shards):
+        a, b = min(k * step, rows.size), min((k + 1) * step, rows.size)
+        for col, x in zip(parts, (rows.e1, rows.e2, rows.e3)):
+            col.append(wedge_common.pad1(x[a:b], per, g.m))
+        local = np.clip(rows.off - a, 0, b - a)
+        has, c_start, c_end = chunk_ranges(local, chunk)
+        for col, x in zip(parts[3:], (c_start, c_end, has)):
+            col.append(x)
+    return [np.concatenate(col) for col in parts]
 
 
 def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
@@ -245,21 +263,15 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
     chunk = wedge_common.pow2_chunk(
         wedge_common.next_pow2(per), chunk, size=per)
     per = -(-per // chunk) * chunk
-    psize = per * n_shards
-    support_mod._check_table_size(psize)
+    support_mod._check_table_size(per * n_shards)
     if table_mode == "device":
         rows_fn = _peel_rows_dist(mesh, axes, m=g.m, per_shard=per,
-                                  chunk=chunk)
-        p_tab = rows_fn(csr["u"], csr["v"], csr["Es"])
+                                  chunk=chunk,
+                                  iters=support_mod._search_iters(g))
+        p_tab = rows_fn(csr["u"], csr["v"], csr["Es"], csr["N"], csr["Eid"])
     else:
-        from repro.core.pkt import chunk_ranges
-
-        ptab = support_mod.build_peel_table(g)
-        has, c_start, c_end = chunk_ranges(ptab.off, chunk)
-        p_tab = [jax.device_put(wedge_common.pad1(a, psize, fill), sh) for a, fill in (
-            (ptab.e1, g.m), (ptab.cand_slot, 0), (ptab.lo, 0), (ptab.hi, 0))]
-        p_tab += [jax.device_put(x, rep) for x in (c_start, c_end, has)]
-    peel_fn = make_pkt_dist(mesh, axes, m=g.m,
-                            iters=support_mod._search_iters(g), chunk=chunk)
-    S, _levels, _subs = peel_fn(csr["N"], csr["Eid"], S0, *p_tab)
+        p_tab = [jax.device_put(x, sh)
+                 for x in _host_peel_shards(g, n_shards, per, chunk)]
+    peel_fn = make_pkt_dist(mesh, axes, m=g.m, chunk=chunk)
+    S, _levels, _subs = peel_fn(S0, *p_tab)
     return np.asarray(S).astype(np.int64) + 2

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 
 from repro.graphs.csr import CSRGraph
 from repro.kernels.wedge_common import (chunk_layout, next_pow2, pad_chunked,
-                                        pow2_chunk, probe, resolve_interpret)
+                                        pow2_chunk, probe, probe_np,
+                                        resolve_interpret)
 # re-export: the triangle-list engine binary-searches through this module's
 # namespace (kernels.wedge_common is the canonical home)
 from repro.kernels.wedge_common import ranged_searchsorted  # noqa: F401
@@ -103,12 +104,13 @@ def build_support_table(g: CSRGraph) -> WedgeTable:
 
 
 def build_peel_table(g: CSRGraph) -> WedgeTable:
-    """Full-adjacency wedge table used by the peel phase.
+    """Full-adjacency wedge table the peel-phase rows are resolved from.
 
     For edge e=(u,v): candidates w from the *smaller*-degree endpoint's full
     adjacency, probed against the other endpoint's full adjacency — the
     ProcessSubLevel loop nest of Algorithm 5 with the cheap side chosen
     (the paper marks N(u) and scans N(v); we pick min-degree for the scan).
+    ``resolve_peel_table`` keeps the rows whose probe hits.
     """
     u = g.El[:, 0].astype(np.int64)
     v = g.El[:, 1].astype(np.int64)
@@ -135,6 +137,56 @@ def build_peel_table(g: CSRGraph) -> WedgeTable:
     )
 
 
+@dataclasses.dataclass(frozen=True)
+class PeelRows:
+    """The peel table: one row ``(e1, e2, e3)`` per triangle through ``e1``.
+
+    ``e2``/``e3`` are the two edges that close the triangle with anchor
+    ``e1``.  Rows are grouped by ``e1`` in ascending order, in wedge order
+    within a group, so edge e's rows are ``[off[e], off[e+1])`` and that
+    count is e's support: every triangle appears three times (3T rows).
+    ``wedges`` is the row count of the wedge table the rows were resolved
+    from; it sets the chunk and the padded size, so a table of resolved
+    rows is laid out exactly as its wedge table would be, and 3T never
+    exceeds it.
+    """
+
+    e1: np.ndarray       # (3T,) int32 anchor edge
+    e2: np.ndarray       # (3T,) int32 edge (scanned endpoint, w)
+    e3: np.ndarray       # (3T,) int32 edge (probed endpoint, w)
+    off: np.ndarray      # (m+1,) int64 — rows of edge e at [off[e], off[e+1])
+    wedges: int          # rows of the wedge table resolved
+
+    @property
+    def size(self) -> int:
+        """Number of resolved rows (3T)."""
+        return int(self.e1.shape[0])
+
+
+def resolve_peel_table(g: CSRGraph,
+                       tab: WedgeTable | None = None) -> PeelRows:
+    """Host resolve of a peel wedge table: probe every row once (``probe_np``)
+    and keep the hits as ``(e1, e2, e3)`` rows (``PeelRows``).
+
+    ``tab`` defaults to ``build_peel_table(g)``; a table restricted to some
+    anchors (``truss_inc.wedge_subtable``) resolves the same way.
+    """
+    if tab is None:
+        tab = build_peel_table(g)
+    if tab.size == 0:
+        z = np.zeros(0, np.int32)
+        return PeelRows(z, z.copy(), z.copy(), np.zeros(g.m + 1, np.int64), 0)
+    hit, safe = probe_np(g.N, tab.cand_slot, tab.lo, tab.hi,
+                         iters=_search_iters(g))
+    off = np.zeros(g.m + 1, np.int64)
+    np.cumsum(np.bincount(tab.e1[hit], minlength=g.m), out=off[1:])
+    return PeelRows(
+        e1=tab.e1[hit],
+        e2=g.Eid[tab.cand_slot[hit]].astype(np.int32),
+        e3=g.Eid[safe[hit]].astype(np.int32),
+        off=off, wedges=tab.size)
+
+
 # --- device-side table construction (DESIGN.md §10) -------------------------
 #
 # The builders above materialize Θ(Σ d·d)-entry tables in host numpy and pay
@@ -147,11 +199,13 @@ def build_peel_table(g: CSRGraph) -> WedgeTable:
 # where a ``searchsorted`` over the offsets costs ceil(log2(m+1)) dependent
 # gathers per row.  Rows are materialized to a *static* pow2-padded ``size``
 # (the exact entry count is data-dependent; the cheap O(m) host calculators
-# below bound it before the jit runs), with the same inert-padding contract
-# as ``wedge_common.pad_chunked``: anchor sentinel ``m``, empty probe range
-# ``lo == hi == 0``.  ``m_real`` is a dynamic scalar so the batched engine
-# can reuse one compiled builder for every graph of a size class — and vmap
-# it across the class.
+# below bound it before the jit runs).  Support-table padding follows
+# ``wedge_common.pad_chunked``: anchor sentinel ``m``, empty probe range
+# ``lo == hi == 0``.  The peel builder probes its wedge rows in the same jit
+# and keeps only the hits, as triangle rows ``(e1, e2, e3)`` (``PeelRows``),
+# with the sentinel ``m`` in all three columns on the rows left over.
+# ``m_real`` is a dynamic scalar so the batched engine can reuse one compiled
+# builder for every graph of a size class — and vmap it across the class.
 
 #: device tables carry int32 offsets; reject anything larger outright
 _MAX_TABLE = np.iinfo(np.int32).max
@@ -242,15 +296,37 @@ def support_rows(u, v, Es, Eo, m_real, *, m: int, size: int, start=0):
     return e1, cand, lo, hi, off
 
 
-def peel_rows(u, v, Es, m_real, *, m: int, size: int, chunk: int, start=0):
-    """Trace-level ``build_peel_table`` rows + per-edge chunk metadata.
+#: wedge rows the peel builder expands and probes per step, at the least
+#: (``peel_rows``); a step takes at least ``m`` rows, so the per-step
+#: segment-end histogram over m edges never outweighs the rows
+_RESOLVE_BLOCK = 1 << 16
 
-    Same row semantics as the host builder (candidates from the
-    min-degree endpoint's full adjacency, probes against the other), for
-    rows ``[start, start + size)``; also emits the ``chunk_ranges``
-    bookkeeping of the *whole* table for the given static ``chunk`` so the
-    peel loop's chunk-skipping needs no host pass.  Returns
-    ``(e1, cand_slot, lo, hi, off, c_start, c_end, has_entries)``.
+
+def peel_rows(u, v, Es, N, Eid, m_real, *, m: int, size: int, chunk: int,
+              iters: int, start=0):
+    """Trace-level ``resolve_peel_table`` over wedge rows
+    ``[start, start + size)``, with per-edge chunk metadata.
+
+    Builds the ``build_peel_table`` wedge rows (candidates from the
+    min-degree endpoint's full adjacency, probes against the other), runs
+    the ``probe`` once over every row (``iters`` bounds the probed
+    adjacency: ``_search_iters`` of the graph, or log2 of a padded
+    vertex bucket plus one), and keeps the hits as
+    ``(e1, e2 = Eid[cand], e3 = Eid[safe])``, compacted stably to the
+    front: rows stay grouped by ``e1`` in ascending order.  The other
+    ``size - hits`` rows carry the sentinel ``m`` in all three columns.
+    ``off`` (m+1,) gives each edge's kept rows ``[off[e], off[e+1])``
+    within the slice (their count is e's support when the slice covers the
+    table), ``off[m]`` the rows kept, and ``c_start``/``c_end``/``has``
+    the ``chunk_ranges`` bookkeeping of those rows for the static
+    ``chunk``, so the peel loop's chunk-skipping needs no host pass.
+    Returns ``(e1, e2, e3, off, c_start, c_end, has)``.
+
+    The rows are expanded, probed and compacted a block at a time, in a
+    loop that stops at the slice's last wedge row: the pow2 padding past
+    it (up to half of ``size``) is never expanded or probed.  Each block's
+    hits land after the hits of the blocks before it (a prefix sum over
+    the block's hit mask plus a running count).
     """
     deg = Es[1:] - Es[:-1]
     swap = deg[u] > deg[v]
@@ -258,15 +334,40 @@ def peel_rows(u, v, Es, m_real, *, m: int, size: int, chunk: int, start=0):
     prob_v = jnp.where(swap, u, v)               # binary-search this side
     ar = jnp.arange(m, dtype=jnp.int32)
     cnt = jnp.where(ar < m_real, deg[cand_v], 0)
-    off = jnp.zeros((m + 1,), jnp.int32).at[1:].set(jnp.cumsum(cnt))
-    e1, e1c, intra, valid = _expand_segments(off, size, m, start)
-    cand = jnp.where(valid, Es[cand_v[e1c]] + intra, 0)
-    lo = jnp.where(valid, Es[prob_v[e1c]], 0)
-    hi = jnp.where(valid, Es[prob_v[e1c] + 1], 0)
+    off_w = jnp.zeros((m + 1,), jnp.int32).at[1:].set(jnp.cumsum(cnt))
+    end = jnp.minimum(off_w[m], start + size)    # last wedge row, exclusive
+    blk = min(size, max(_RESOLVE_BLOCK, next_pow2(m)))
+    # per edge: first candidate slot and the probed range
+    c_first, p_lo, p_hi = Es[cand_v], Es[prob_v], Es[prob_v + 1]
+
+    def block(state):
+        b0, kept, e1, e2, e3, off = state
+        e1w, e1c, intra, valid = _expand_segments(off_w, blk, m, b0)
+        valid = valid & (b0 + jnp.arange(blk, dtype=jnp.int32) < end)
+        cand = jnp.where(valid, c_first[e1c] + intra, 0)
+        lo = jnp.where(valid, p_lo[e1c], 0)
+        hi = jnp.where(valid, p_hi[e1c], 0)             # lo == hi: a miss
+        hit, safe = probe(N, cand, lo, hi, iters=iters)
+        hits = _prefix_sum(hit.astype(jnp.int32))
+        dst = jnp.where(hit, kept + hits - 1, size)     # misses are dropped
+        e1 = e1.at[dst].set(e1w, mode="drop")
+        e2 = e2.at[dst].set(Eid[cand], mode="drop")
+        e3 = e3.at[dst].set(Eid[safe], mode="drop")
+        # each edge's kept rows start after this block's hits before its
+        # first wedge row, summed over the blocks
+        before = jnp.concatenate([jnp.zeros((1,), jnp.int32), hits])
+        off = off + before[jnp.clip(off_w - b0, 0, blk)]
+        return b0 + blk, kept + hits[-1], e1, e2, e3, off
+
+    fill = jnp.full((size,), m, jnp.int32)
+    state = (jnp.asarray(start, jnp.int32), jnp.int32(0), fill, fill, fill,
+             jnp.zeros((m + 1,), jnp.int32))
+    _, _, e1, e2, e3, off = jax.lax.while_loop(
+        lambda st: st[0] < end, block, state)
     has = off[1:] > off[:-1]
     c_start = off[:-1] // chunk
     c_end = jnp.maximum(off[1:] - 1, 0) // chunk
-    return e1, cand, lo, hi, off, c_start, c_end, has
+    return e1, e2, e3, off, c_start, c_end, has
 
 
 @functools.partial(jax.jit, static_argnames=("m", "size"))
@@ -275,10 +376,17 @@ def _build_support_table_dev(u, v, Es, Eo, m_real, *, m: int, size: int):
     return support_rows(u, v, Es, Eo, m_real, m=m, size=size)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "size", "chunk"))
-def _build_peel_table_dev(u, v, Es, m_real, *, m: int, size: int, chunk: int):
-    """Device mirror of ``build_peel_table`` + per-edge chunk-range metadata."""
-    return peel_rows(u, v, Es, m_real, m=m, size=size, chunk=chunk)
+@functools.partial(jax.jit, static_argnames=("m", "size", "chunk", "iters"))
+def _build_peel_table_dev(u, v, Es, N, Eid, m_real, *, m: int, size: int,
+                          chunk: int, iters: int):
+    """Device ``resolve_peel_table`` (``peel_rows``) of a whole graph at
+    static padded ``size``: the wedge rows, their one probe and the
+    compaction of the hits run in this one jit.  Returns the
+    ``core.pkt.PeelTables`` fields ``(e1, e2, e3, c_start, c_end, has)``
+    and the rows kept (3T)."""
+    e1, e2, e3, off, c_start, c_end, has = peel_rows(
+        u, v, Es, N, Eid, m_real, m=m, size=size, chunk=chunk, iters=iters)
+    return e1, e2, e3, c_start, c_end, has, off[m]
 
 
 def support_from_table_arrays(e1, cand, lo, hi, N, Eid, *, m: int, mode: str,

@@ -162,7 +162,7 @@ def wedge_subtable(g: CSRGraph, anchors: np.ndarray) -> support_mod.WedgeTable:
     Same layout and min-degree orientation policy as
     ``support.build_peel_table``, but only the anchor edges get entries; the
     ``off`` array still spans all ``m`` edges (non-anchors carry empty
-    ranges) so ``chunk_ranges`` and the masked peel loop work unchanged.
+    ranges), so ``support.resolve_peel_table`` resolves it unchanged.
     """
     anchors = np.asarray(anchors, dtype=np.int64)
     if anchors.size == 0 or g.m == 0:
@@ -194,34 +194,23 @@ def wedge_subtable(g: CSRGraph, anchors: np.ndarray) -> support_mod.WedgeTable:
     )
 
 
-def _probe_iters(g: CSRGraph) -> int:
-    dmax = int(g.degrees.max(initial=1))
-    return max(1, int(np.ceil(np.log2(dmax + 1))) + 1)
-
-
 def triangles_through(g: CSRGraph,
                       anchors: np.ndarray) -> tuple[np.ndarray, np.ndarray,
                                                     np.ndarray]:
     """Every triangle through each anchor edge, as (anchor, e2, e3) id rows.
 
     A triangle through an anchor is reported exactly once *per anchor it
-    contains*.  Runs on the host (``probe_np``) — update batches probe tiny,
+    contains*.  Runs on the host (``support.resolve_peel_table``, which
+    probes with ``probe_np``) — update batches probe tiny,
     differently-shaped tables every call, the wrong regime for a jit trace.
     """
     anchors = np.asarray(anchors, dtype=np.int64)
     if anchors.size == 0 or g.m == 0:
         z = np.zeros(0, np.int64)
         return z, z.copy(), z.copy()
-    tab = wedge_subtable(g, anchors)
-    if tab.size == 0:
-        z = np.zeros(0, np.int64)
-        return z, z.copy(), z.copy()
-    hit, safe = wedge_common.probe_np(
-        g.N, tab.cand_slot.astype(np.int64), tab.lo, tab.hi,
-        iters=_probe_iters(g))
-    return (tab.e1[hit].astype(np.int64),
-            g.Eid[tab.cand_slot[hit]].astype(np.int64),
-            g.Eid[safe[hit]].astype(np.int64))
+    rows = support_mod.resolve_peel_table(g, wedge_subtable(g, anchors))
+    return (rows.e1.astype(np.int64), rows.e2.astype(np.int64),
+            rows.e3.astype(np.int64))
 
 
 def triangle_list(g: CSRGraph) -> np.ndarray:

@@ -1,10 +1,13 @@
 """Pallas TPU kernel for the PKT peel phase (Algorithm 5's SCAN hot loop).
 
-One grid step evaluates one wedge-table chunk: the chunk's ``e1 / cand_slot /
-lo / hi`` rows are staged in VMEM next to the (replicated) adjacency arrays
-and edge-state vectors, the ranged binary search runs branch-free on the VPU,
-and the frontier / processed / tie-break predicates of ProcessSubLevel are
-evaluated as dense masks.  The decrement fold is fused on-chip: the kernel
+One grid step evaluates one peel-table chunk: the chunk's resolved
+triangle rows ``(e1, e2, e3)`` — anchor edge and the two edges that close
+its triangle, resolved once by the table builder (``core.support.
+peel_rows``), sentinel ``m`` in all three columns on padding rows — are
+staged in VMEM next to the replicated edge-state vectors, and the
+frontier / processed / tie-break predicates of ProcessSubLevel are
+evaluated as dense masks over gathers of that state; no adjacency and no
+search enter the kernel.  The decrement fold is fused on-chip: the kernel
 owns a single ``(m + 1,)`` accumulator output block pinned to block 0 for
 every grid step, so it stays resident in VMEM across the whole (sequential)
 grid.  Grid step 0 zeroes it; every step scatter-adds its chunk's two
@@ -28,13 +31,13 @@ work-efficient ``mode="chunked"`` while_loop in ``core/pkt.py`` remains the
 right choice for very sparse frontiers; this kernel wins when frontiers are
 wide (dense sub-levels dominate total peel time, paper Fig. 6).
 
-VMEM per grid step ≈ 4·(chunk + two_m·2 + 5·(m+1)) bytes; callers pick
-``chunk`` so this stays well under the ~16 MiB budget.  On non-TPU backends
-the kernel runs in interpret mode (the CI contract: the lowering is
-exercised on every PR, the Mosaic path on TPU runners).
+VMEM per grid step ≈ 4·(3·chunk + 5·(m+1)) bytes; callers pick ``chunk``
+so this stays well under the ~16 MiB budget.  On non-TPU backends the
+kernel runs in interpret mode (the CI contract: the lowering is exercised
+on every PR, the Mosaic path on TPU runners).
 
-Chunk layout, padding, and the fused gather + ranged-binary-search probe are
-shared with the support kernel via ``kernels/wedge_common.py``.
+Chunk layout and the BlockSpec helpers are shared with the support kernel
+via ``kernels/wedge_common.py``.
 """
 
 from __future__ import annotations
@@ -50,16 +53,13 @@ from repro.kernels import wedge_common
 _interpret_default = wedge_common.interpret_default
 
 
-def _peel_chunk_kernel(act_ref, l_ref, e1_ref, cand_ref, lo_ref, hi_ref,
-                       n_ref, eid_ref, s_ref, proc_ref, curr_ref, pin_ref,
-                       dec_ref, *, iters: int, m: int):
-    """One wedge-table chunk folded into the (m+1,) decrement accumulator."""
+def _peel_chunk_kernel(act_ref, l_ref, e1_ref, e2_ref, e3_ref, s_ref,
+                       proc_ref, curr_ref, pin_ref, dec_ref, *, m: int):
+    """One peel-table chunk folded into the (m+1,) decrement accumulator."""
     @pl.when(pl.program_id(0) == 0)
     def _zero():
         dec_ref[...] = jnp.zeros_like(dec_ref)
 
-    N = n_ref[...]                 # (two_m,) int32 adjacency values
-    Eid = eid_ref[...]             # (two_m,) int32 slot → edge id
     S = s_ref[...]                 # (m+1,)  int32 extended support
     proc = proc_ref[...] != 0      # (m+1,)  processed mask
     curr = curr_ref[...] != 0      # (m+1,)  current-frontier mask
@@ -68,15 +68,11 @@ def _peel_chunk_kernel(act_ref, l_ref, e1_ref, cand_ref, lo_ref, hi_ref,
     l = l_ref[0]                   # current peel level
 
     e1 = e1_ref[...]               # (chunk,) anchor edge ids (m = padding)
-    cand = cand_ref[...]           # (chunk,) CSR slot of candidate w
-    lo = lo_ref[...]               # (chunk,) probe range start
-    hi = hi_ref[...]               # (chunk,) probe range end (lo==hi → miss)
+    e2 = e2_ref[...]               # (chunk,) closing edge ids (m = padding)
+    e3 = e3_ref[...]               # (chunk,) closing edge ids (m = padding)
 
     in1 = curr[e1]                 # padding rows carry e1 == m → curr[m] False
-    hit, safe = wedge_common.probe(N, cand, lo, hi, iters=iters)
-    e2 = Eid[cand]
-    e3 = Eid[safe]
-    valid = act & in1 & hit & (~proc[e2]) & (~proc[e3])
+    valid = act & in1 & (~proc[e2]) & (~proc[e3])
     # the paper's tie-break: of two frontier edges sharing a triangle, the
     # lower edge id processes it (each triangle decremented exactly once)
     dec2 = valid & (S[e2] > l) & ((~curr[e3]) | (e1 < e3)) & (~pin[e2])
@@ -86,22 +82,20 @@ def _peel_chunk_kernel(act_ref, l_ref, e1_ref, cand_ref, lo_ref, hi_ref,
     dec_ref[...] = dec_ref[...].at[tgt2].add(1).at[tgt3].add(1)
 
 
-def peel_decrement_fold(active, l, e1, cand, lo, hi, N, Eid,
-                        S_ext, processed, inCurr, pinned, *, chunk: int,
-                        n_chunks: int, iters: int, m: int,
+def peel_decrement_fold(active, l, e1, e2, e3, S_ext, processed, inCurr,
+                        pinned, *, chunk: int, n_chunks: int, m: int,
                         interpret: bool = True):
-    """Fused decrement fold over the wedge table at sub-level ``l``.
+    """Fused decrement fold over the peel table at sub-level ``l``.
 
-    active: (n_chunks,) int32 chunk mask; l: (1,) int32; table arrays
-    (n_chunks*chunk,) int32; N/Eid: (two_m,) int32;
+    active: (n_chunks,) int32 chunk mask; l: (1,) int32; table rows
+    e1/e2/e3: (n_chunks*chunk,) int32 (``core.pkt.PeelTables``);
     S_ext/processed/inCurr/pinned: (m+1,) int32 (pinned all-zero when the
     caller has no schedule edges).  Returns the (m+1,) int32 decrement
     vector accumulated on-chip — slot ``m`` absorbs sentinel writes; read
     the result below index m.  Trace-level: ``core/pkt.py`` calls this
     inside its jitted peel loop.
     """
-    two_m = N.shape[0]
-    kernel = functools.partial(_peel_chunk_kernel, iters=iters, m=m)
+    kernel = functools.partial(_peel_chunk_kernel, m=m)
     chunk_spec = wedge_common.chunk_spec(chunk)
     full = wedge_common.replicated_spec
     return pl.pallas_call(
@@ -110,28 +104,26 @@ def peel_decrement_fold(active, l, e1, cand, lo, hi, N, Eid,
         in_specs=[
             wedge_common.chunk_spec(1),           # active (per chunk)
             full(1),                              # l (replicated scalar)
-            chunk_spec, chunk_spec, chunk_spec, chunk_spec,
-            full(two_m), full(two_m),             # N, Eid
+            chunk_spec, chunk_spec, chunk_spec,   # e1, e2, e3
             full(m + 1), full(m + 1),             # S_ext, processed
             full(m + 1), full(m + 1),             # inCurr, pinned
         ],
         out_specs=[full(m + 1)],
         out_shape=[jax.ShapeDtypeStruct((m + 1,), jnp.int32)],
         interpret=interpret,
-    )(active, l, e1, cand, lo, hi, N, Eid, S_ext, processed, inCurr,
-      pinned)[0]
+    )(active, l, e1, e2, e3, S_ext, processed, inCurr, pinned)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks", "iters",
-                                             "m", "interpret"))
-def peel_decrements(active, l, e1, cand, lo, hi, N, Eid, S_ext, processed,
-                    inCurr, *, chunk: int, n_chunks: int, iters: int, m: int,
+@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks", "m",
+                                             "interpret"))
+def peel_decrements(active, l, e1, e2, e3, S_ext, processed, inCurr, *,
+                    chunk: int, n_chunks: int, m: int,
                     interpret: bool = True):
     """Jitted convenience wrapper: fused fold with no pinned edges → (m+1,)
     decrement vector (slot m absorbs sentinel writes). Used directly by tests
     and the CI interpret-compile gate; ``core/pkt.py`` traces
     ``peel_decrement_fold`` inside its peel loop."""
     return peel_decrement_fold(
-        active, l, e1, cand, lo, hi, N, Eid, S_ext, processed, inCurr,
+        active, l, e1, e2, e3, S_ext, processed, inCurr,
         jnp.zeros((m + 1,), jnp.int32),
-        chunk=chunk, n_chunks=n_chunks, iters=iters, m=m, interpret=interpret)
+        chunk=chunk, n_chunks=n_chunks, m=m, interpret=interpret)

@@ -1,25 +1,29 @@
-"""Shared wedge-table machinery for the Pallas truss kernels.
+"""Shared wedge-table machinery for the truss kernels and table builders.
 
-Both hot-phase kernels walk the same flat data structure: a *wedge table* —
-one row per (anchor edge, candidate adjacency slot) pair, with a probe range
-``[lo, hi)`` into the CSR adjacency array ``N``.  The support kernel
-(``kernels/support.py``) walks the oriented AM4 table, the peel kernel
-(``kernels/peel.py``) the full-adjacency ProcessSubLevel table; the table
-*math* is identical and used to be duplicated across the two kernels and
-``core/pkt.py``.  This module is its single home:
+A *wedge table* has one row per (anchor edge, candidate adjacency slot)
+pair, with a probe range ``[lo, hi)`` into the CSR adjacency array ``N``.
+The support kernel (``kernels/support.py``) walks the oriented AM4 table
+and probes every row.  The peel's full-adjacency ProcessSubLevel table is
+probed once, by its builder (``core.support.peel_rows`` and the host
+``resolve_peel_table``), which keeps the hits as resolved triangle rows
+``(e1, e2, e3)`` with the sentinel ``m`` in all three columns on padding
+rows; the peel kernel (``kernels/peel.py``) and the jnp peel executors walk
+those rows and never search.  This module is the single home of the table
+math they share:
 
   * **chunk layout** — tables are cut into fixed-size chunks, one per Pallas
     grid step; ``chunk_layout`` sanitizes a requested chunk size (clamped so
     that ``n_chunks >= 1`` always holds, including zero-entry tables) and
-    ``pad_chunked`` pads the four table arrays to a whole number of chunks
-    with inert sentinel rows (anchor ``m``, empty probe range ``lo == hi``);
+    ``pad_chunked`` pads the four wedge-table arrays to a whole number of
+    chunks with inert sentinel rows (anchor ``m``, empty probe range
+    ``lo == hi``);
   * **BlockSpec helpers** — ``chunk_spec`` stages one chunk per grid step,
     ``replicated_spec`` replicates a whole array (adjacency, edge state)
     into VMEM at every step;
   * **the search primitive** — ``ranged_searchsorted`` is the branch-free
-    vectorized lower-bound binary search both phases use as their membership
-    test, and ``probe`` fuses it with the candidate gather and hit predicate
-    (``w ∈ N[lo:hi)``).
+    vectorized lower-bound binary search every membership test uses, and
+    ``probe`` fuses it with the candidate gather and hit predicate
+    (``w ∈ N[lo:hi)``); ``probe_np`` is its host mirror.
 
 Everything here is pure jax/numpy so it can be imported from kernels and
 from ``core/`` without cycles (``core.support`` re-exports
@@ -287,8 +291,9 @@ def probe(N: jnp.ndarray, cand_slot: jnp.ndarray, lo: jnp.ndarray,
 
     Returns ``(hit, safe)`` where ``safe`` is the (clamped) index of the
     matching slot — valid as a gather index whenever ``hit`` is True, and a
-    harmless in-bounds index otherwise.  This is the shared inner loop of
-    both kernels and of every jnp executor in ``core/``.
+    harmless in-bounds index otherwise.  This is the inner loop of the
+    support kernel and executors, and the one probe of the peel-table
+    builder.
     """
     w = N[cand_slot]
     idx = ranged_searchsorted(N, w, lo, hi, iters)

@@ -323,7 +323,9 @@ def truss_cell(mesh, *, log_m: int = 27, chunk: int = 1 << 14) -> dict:
     hi = sds((tab,), i32)
 
     S0 = sds((m,), i32)
-    meta = (sds((m,), i32), sds((m,), i32), sds((m,), jnp.bool_))
+    # per-device (m,) chunk ranges of each table slice, sharded like the rows
+    meta = (sds((n_dev * m,), i32), sds((n_dev * m,), i32),
+            sds((n_dev * m,), jnp.bool_))
 
     rec = {}
     sup = make_support_dist(mesh, axes, m=m, iters=20)
@@ -340,9 +342,11 @@ def truss_cell(mesh, *, log_m: int = 27, chunk: int = 1 << 14) -> dict:
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
             "collectives": parse_collectives(c.as_text()),
         }
-        peel = make_pkt_dist(mesh, axes, m=m, iters=20, chunk=chunk)
+        peel = make_pkt_dist(mesh, axes, m=m, chunk=chunk)
         t0 = time.time()
-        c2 = peel.lower(N, Eid, S0, e1, cs, lo, hi, *meta).compile()
+        # the peel table's resolved rows (e1, e2, e3) have the wedge rows'
+        # shape and sharding
+        c2 = peel.lower(S0, e1, cs, lo, *meta).compile()
         ma2 = c2.memory_analysis()
         rec["peel_loop"] = {
             "compile_s": round(time.time() - t0, 2),

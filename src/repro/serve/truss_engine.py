@@ -20,8 +20,10 @@ alone. This engine amortizes both:
   * **Batching** — a bucket is decomposed by a single ``jax.vmap`` of the
     support + peel pipeline from ``core/pkt.py`` over the stacked, padded
     operands.  Padding edges are pre-marked processed with sentinel support,
-    so they are inert in the level loop; padded wedge entries carry empty
-    probe ranges (lo == hi) and the anchor sentinel, so they never hit.
+    so they are inert in the level loop; padded support-table rows carry
+    empty probe ranges (lo == hi) and the anchor sentinel, so they never
+    hit, and padded peel-table rows carry the edge sentinel in all three
+    columns.
   * **Order-aligned results** — ``submit`` returns a ticket; results are
     delivered aligned to each submission's own edge-row order regardless of
     bucket membership or flush timing.
@@ -105,9 +107,8 @@ class BatchOperand(NamedTuple):
     s_lo: jnp.ndarray       # (sup_pad,)
     s_hi: jnp.ndarray       # (sup_pad,)
     p_e1: jnp.ndarray       # (peel_pad,) peel-table anchor edges
-    p_cand: jnp.ndarray     # (peel_pad,)
-    p_lo: jnp.ndarray       # (peel_pad,)
-    p_hi: jnp.ndarray       # (peel_pad,)
+    p_e2: jnp.ndarray       # (peel_pad,) closing edges (``PeelTables``)
+    p_e3: jnp.ndarray       # (peel_pad,)
     c_start: jnp.ndarray    # (m_pad,) first chunk of edge's entry range
     c_end: jnp.ndarray      # (m_pad,) last chunk (inclusive)
     has_entries: jnp.ndarray  # (m_pad,) bool
@@ -160,11 +161,11 @@ def _batched_truss(ops: BatchOperand, *, m: int, chunk: int, n_chunks: int,
             jnp.concatenate([S0, jnp.zeros((1,), jnp.int32)]),
             _SENTINEL_S)
         processed0 = ~edge_ok
-        tabs = PeelTables(op.p_e1, op.p_cand, op.p_lo, op.p_hi,
+        tabs = PeelTables(op.p_e1, op.p_e2, op.p_e3,
                           op.c_start, op.c_end, op.has_entries)
         S_ext, _, levels, subs, _ = _peel_loop(
-            op.N, op.Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-            n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
+            S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
+            mode=mode, interpret=interpret)
         return S_ext[:m], S0, levels, subs
 
     return jax.vmap(one)(ops)
@@ -194,19 +195,18 @@ def _batched_truss_dev(ops: CSROperand, *, m: int, chunk: int, n_chunks: int,
             s_e1, s_cand, s_lo, s_hi, op.N, op.Eid, m=m, mode=support_mode,
             chunk=sup_chunk, n_chunks=sup_n_chunks, iters=iters,
             interpret=interpret)
-        p_e1, p_cand, p_lo, p_hi, _off, c_start, c_end, has = \
-            support_mod._build_peel_table_dev(
-                op.u, op.v, op.Es, op.m_real, m=m, size=peel_pad, chunk=chunk)
+        *cols, _hits = support_mod._build_peel_table_dev(
+            op.u, op.v, op.Es, op.N, op.Eid, op.m_real, m=m, size=peel_pad,
+            chunk=chunk, iters=iters)
         edge_ok = jnp.arange(m + 1, dtype=jnp.int32) < op.m_real
         S_ext0 = jnp.where(
             edge_ok,
             jnp.concatenate([S0, jnp.zeros((1,), jnp.int32)]),
             _SENTINEL_S)
         processed0 = ~edge_ok
-        tabs = PeelTables(p_e1, p_cand, p_lo, p_hi, c_start, c_end, has)
         S_ext, _, levels, subs, _ = _peel_loop(
-            op.N, op.Eid, S_ext0, processed0, tabs, m=m, chunk=chunk,
-            n_chunks=n_chunks, iters=iters, mode=mode, interpret=interpret)
+            S_ext0, processed0, PeelTables(*cols), m=m, chunk=chunk,
+            n_chunks=n_chunks, mode=mode, interpret=interpret)
         return S_ext[:m], S0, levels, subs
 
     return jax.vmap(one)(ops)
@@ -477,9 +477,9 @@ class TrussEngine:
             operand = self._make_csr_operand(g, key)
         else:
             stab = support_mod.build_support_table(g)
-            ptab = support_mod.build_peel_table(g)
-            key = self._size_class(g, stab, ptab)
-            operand = self._make_operand(g, key, stab, ptab)
+            rows = support_mod.resolve_peel_table(g)
+            key = self._size_class(g, stab, _TableDims(rows.wedges))
+            operand = self._make_operand(g, key, stab, rows)
         self._pending.append(_Pending(
             ticket=ticket, g=g, n=n, in_keys=in_keys,
             key=key, E=E, operand=operand))
@@ -655,9 +655,9 @@ class TrussEngine:
         )
 
     def _make_operand(self, g: CSRGraph, key: SizeClass, stab,
-                      ptab) -> BatchOperand:
+                      rows: support_mod.PeelRows) -> BatchOperand:
         m_pad = key.m_pad
-        has_p, c_start, c_end = chunk_ranges(ptab.off, key.chunk, m_out=m_pad)
+        has_p, c_start, c_end = chunk_ranges(rows.off, key.chunk, m_out=m_pad)
         return BatchOperand(
             N=jnp.asarray(_pad1(g.N, 2 * m_pad, _PAD_N)),
             Eid=jnp.asarray(_pad1(g.Eid, 2 * m_pad, m_pad)),
@@ -665,10 +665,9 @@ class TrussEngine:
             s_cand=jnp.asarray(_pad1(stab.cand_slot, key.sup_pad, 0)),
             s_lo=jnp.asarray(_pad1(stab.lo, key.sup_pad, 0)),
             s_hi=jnp.asarray(_pad1(stab.hi, key.sup_pad, 0)),
-            p_e1=jnp.asarray(_pad1(ptab.e1, key.peel_pad, m_pad)),
-            p_cand=jnp.asarray(_pad1(ptab.cand_slot, key.peel_pad, 0)),
-            p_lo=jnp.asarray(_pad1(ptab.lo, key.peel_pad, 0)),
-            p_hi=jnp.asarray(_pad1(ptab.hi, key.peel_pad, 0)),
+            p_e1=jnp.asarray(_pad1(rows.e1, key.peel_pad, m_pad)),
+            p_e2=jnp.asarray(_pad1(rows.e2, key.peel_pad, m_pad)),
+            p_e3=jnp.asarray(_pad1(rows.e3, key.peel_pad, m_pad)),
             c_start=jnp.asarray(c_start),
             c_end=jnp.asarray(c_end),
             has_entries=jnp.asarray(has_p),

@@ -28,7 +28,7 @@ pkt = importlib.import_module("repro.core.pkt")
 N_V, M = 32737, 234101
 TABLE = 1 << 25                     # both tables, pow2-padded
 CHUNK = 1 << 14                     # auto_chunk for a 2^25-row table
-ITERS, S_ITERS = 13, 11             # peel / oriented support search bounds
+ITERS, S_ITERS = 13, 11             # peel-table / oriented support search bounds
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +55,7 @@ def _sds(sh, shape, dtype=jnp.int32):
 
 
 def _peel_tables(sh):
-    return pkt.PeelTables(*[_sds(sh, (TABLE,))] * 4, _sds(sh, (M,)),
+    return pkt.PeelTables(*[_sds(sh, (TABLE,))] * 3, _sds(sh, (M,)),
                           _sds(sh, (M,)), _sds(sh, (M,), jnp.bool_))
 
 
@@ -80,17 +80,18 @@ def test_support_device_jit_compiles(one_chip):
 def test_build_peel_table_dev_compiles(one_chip):
     s = one_chip
     c = support._build_peel_table_dev.lower(
-        _sds(s, (M,)), _sds(s, (M,)), _sds(s, (N_V + 1,)), _sds(s, ()),
-        m=M, size=TABLE, chunk=CHUNK).compile()
+        _sds(s, (M,)), _sds(s, (M,)), _sds(s, (N_V + 1,)),
+        _sds(s, (2 * M,)), _sds(s, (2 * M,)), _sds(s, ()),
+        m=M, size=TABLE, chunk=CHUNK, iters=ITERS).compile()
     ma = _fits_v5e(c)
-    assert ma.output_size_in_bytes >= 4 * 4 * TABLE   # the four row arrays
+    assert ma.output_size_in_bytes >= 3 * 4 * TABLE   # the three row arrays
 
 
 def test_pkt_peel_jit_compiles(one_chip):
     s = one_chip
     c = pkt._pkt_peel_jit.lower(
-        _sds(s, (2 * M,)), _sds(s, (2 * M,)), _sds(s, (M,)), _peel_tables(s),
-        m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK, iters=ITERS,
+        _sds(s, (M,)), _peel_tables(s),
+        m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK,
         mode="chunked", interpret=False).compile()
     _fits_v5e(c)
 
@@ -98,9 +99,8 @@ def test_pkt_peel_jit_compiles(one_chip):
 def test_peel_segment_jit_compiles(one_chip):
     s = one_chip
     c = pkt._peel_segment_jit.lower(
-        _sds(s, (2 * M,)), _sds(s, (2 * M,)), _sds(s, (M + 1,)),
-        _sds(s, (M + 1,), jnp.bool_), _sds(s, ()), None, _peel_tables(s),
-        m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK, iters=ITERS,
+        _sds(s, (M + 1,)), _sds(s, (M + 1,), jnp.bool_), _sds(s, ()), None,
+        _peel_tables(s), m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK,
         mode="chunked", interpret=False).compile()
     _fits_v5e(c)
 
@@ -157,10 +157,9 @@ def test_pallas_peel_kernel_lowers(one_chip):
 
     s, T = one_chip, _KN * _KCHUNK
     peel_decrements.lower(
-        _sds(s, (_KN,)), _sds(s, (1,)), *[_sds(s, (T,))] * 4,
-        _sds(s, (2 * _KM,)), _sds(s, (2 * _KM,)), *[_sds(s, (_KM + 1,))] * 3,
-        chunk=_KCHUNK, n_chunks=_KN, iters=ITERS, m=_KM,
-        interpret=False).compile()
+        _sds(s, (_KN,)), _sds(s, (1,)), *[_sds(s, (T,))] * 3,
+        *[_sds(s, (_KM + 1,))] * 3,
+        chunk=_KCHUNK, n_chunks=_KN, m=_KM, interpret=False).compile()
 
 
 def test_shapes_match_rmat_medium():

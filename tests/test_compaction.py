@@ -125,6 +125,32 @@ def test_peel_live_subset_whole_graph_is_full_peel():
     assert np.array_equal(out + 2, pkt(g).trussness)
 
 
+@pytest.mark.parametrize("table_mode", ["device", "numpy"])
+@pytest.mark.parametrize("compact", ["off", "on"])
+@pytest.mark.parametrize("mode", PEEL_MODES)
+def test_peel_live_subset_pinned(mode, compact, table_mode):
+    """A subset peel over the resolved rows of its own compacted graph: the
+    edges of the 4-truss, a third of them pinned at their death level,
+    peel to their trussness bitwise, in every executor and table mode."""
+    from repro.core.truss_inc import triangle_list
+
+    g = build_csr(rmat_edges(6, edge_factor=6, seed=5))
+    T = pkt(g).trussness
+    ids = np.nonzero(T >= 4)[0]
+    inside = np.zeros(g.m, bool)
+    inside[ids] = True
+    tri = triangle_list(g)
+    tri = tri[inside[tri].all(axis=1)]
+    S0 = np.bincount(tri.ravel(), minlength=g.m)[ids]
+    pinned = np.random.default_rng(3).random(ids.shape[0]) < 1 / 3
+    S0 = np.where(pinned, T[ids] - 2, S0)
+    kw = OFF if compact == "off" else AGGRESSIVE
+    out = peel_live_subset(g.El, ids, S0, pinned, chunk=16, mode=mode,
+                           table_mode=table_mode, **kw)
+    assert ids.shape[0] > 32 and pinned.any()
+    assert np.array_equal(out + 2, T[ids])
+
+
 @settings(max_examples=4, deadline=None,
           suppress_health_check=[HealthCheck.too_slow])
 @given(graphs(), st.integers(min_value=0, max_value=999))

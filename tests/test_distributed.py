@@ -47,6 +47,31 @@ print("OK", g.m)
     assert "OK" in out
 
 
+def test_pkt_dist_table_modes():
+    """Each device resolves its own slice of the peel table (``device``),
+    or takes an even share of the host-resolved rows (``numpy``): both
+    peel to the oracle bitwise, a triangle-free graph included."""
+    out = run_py("""
+import numpy as np, jax
+from repro.graphs.csr import build_csr, edges_from_arrays
+from repro.core import truss_numpy, pkt_dist
+rng = np.random.default_rng(7)
+n = 40
+mask = rng.random((n, n)) < 0.3
+src, dst = np.nonzero(np.triu(mask, 1))
+star = np.stack([np.zeros(9, np.int64), np.arange(1, 10)], axis=1)
+assert len(jax.devices()) >= 2
+for E in (edges_from_arrays(src, dst, n), star):
+    g = build_csr(E)
+    for tm in ("device", "numpy"):
+        for chunk in (8, 64):
+            t = pkt_dist(g, chunk=chunk, table_mode=tm)
+            assert np.array_equal(t, truss_numpy(g.El)), (tm, chunk)
+print("OK", g.m)
+""")
+    assert "OK" in out
+
+
 def test_pkt_dist_support_kernel_sharded():
     """support_mode="pallas": each shard lowers the support kernel over its
     own table slice (interpret mode off-TPU); result matches the oracle and

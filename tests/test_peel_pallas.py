@@ -111,9 +111,10 @@ def test_tiny_graph_huge_chunk(edges, chunk):
 
 def test_prepare_peel_always_one_chunk():
     g = build_csr(np.array([[0, 1], [1, 2]], np.int64))
-    ptab = support_mod.build_peel_table(g)
-    for chunk in (1, ptab.size, ptab.size + 1, 1 << 20):
-        tabs, c, n_chunks = prepare_peel(ptab, g.m, chunk)
+    rows = support_mod.resolve_peel_table(g)
+    assert rows.size == 0 and rows.wedges > 0
+    for chunk in (1, rows.wedges, rows.wedges + 1, 1 << 20):
+        tabs, c, n_chunks = prepare_peel(rows, g.m, chunk)
         assert n_chunks >= 1
         assert c >= 1
         assert tabs.e1.shape[0] == n_chunks * c
@@ -124,12 +125,12 @@ def test_prepare_peel_empty_graph_explicit():
     from repro.core.pkt import chunk_ranges
 
     g = build_csr(np.zeros((0, 2), np.int64))
-    ptab = support_mod.build_peel_table(g)
-    assert ptab.size == 0
-    tabs, chunk, n_chunks = prepare_peel(ptab, g.m, 1 << 14)
+    rows = support_mod.resolve_peel_table(g)
+    assert rows.size == rows.wedges == 0
+    tabs, chunk, n_chunks = prepare_peel(rows, g.m, 1 << 14)
     assert (chunk, n_chunks) == (1, 1)
-    assert np.asarray(tabs.e1).tolist() == [g.m]          # anchor sentinel
-    assert np.asarray(tabs.hi).tolist() == [0]            # empty probe range
+    for col in (tabs.e1, tabs.e2, tabs.e3):               # edge sentinel
+        assert np.asarray(col).tolist() == [g.m]
     assert tabs.c_start.shape == (0,) and tabs.has_entries.shape == (0,)
     # chunk_ranges itself: empty offset array, with and without m_out
     has, cs, ce = chunk_ranges(np.zeros(1, np.int64), 4)
@@ -139,14 +140,19 @@ def test_prepare_peel_empty_graph_explicit():
 
 
 def test_prepare_peel_entryless_support_table():
-    """A triangle-free orientation (star) has an *empty* support table; the
-    early-exit must produce inert tables, and both support executors must
-    return all-zero support."""
+    """A triangle-free graph (star) has an *empty* support table and a peel
+    table whose wedges all miss: the peel tables must be all sentinel rows
+    with no edge entered, and both support executors must return all-zero
+    support."""
     g = build_csr(_star_edges())
     stab = support_mod.build_support_table(g)
     assert stab.size == 0
-    tabs, chunk, n_chunks = prepare_peel(stab, g.m, 8)
-    assert (chunk, n_chunks) == (1, 1)
+    rows = support_mod.resolve_peel_table(g)
+    assert rows.size == 0 and rows.wedges == g.m
+    tabs, chunk, n_chunks = prepare_peel(rows, g.m, 8)
+    assert chunk * n_chunks >= rows.wedges
+    for col in (tabs.e1, tabs.e2, tabs.e3):
+        assert (np.asarray(col) == g.m).all()
     assert not np.asarray(tabs.has_entries).any()
     for mode in ("jnp", "pallas"):
         S = support_mod.compute_support(g, stab, mode=mode)
@@ -172,8 +178,8 @@ def test_peel_kernel_compiles_interpret():
     from repro.kernels.peel import peel_decrements
 
     g = build_csr(ring_of_cliques_edges(3, 4))
-    ptab = support_mod.build_peel_table(g)
-    tabs, chunk, n_chunks = prepare_peel(ptab, g.m, 16)
+    rows = support_mod.resolve_peel_table(g)
+    tabs, chunk, n_chunks = prepare_peel(rows, g.m, 16)
     m = g.m
     S0 = support_mod.compute_support(g)
     S_ext = jnp.concatenate([jnp.asarray(S0),
@@ -183,13 +189,56 @@ def test_peel_kernel_compiles_interpret():
     inCurr = ((processed == 0) & (S_ext == l)).astype(jnp.int32)
     dec = peel_decrements(
         jnp.ones((n_chunks,), jnp.int32), jnp.full((1,), l, jnp.int32),
-        tabs.e1, tabs.cand_slot, tabs.lo, tabs.hi,
-        jnp.asarray(g.N), jnp.asarray(g.Eid),
-        S_ext, processed, inCurr,
-        chunk=chunk, n_chunks=n_chunks,
-        iters=support_mod._search_iters(g), m=m, interpret=True)
+        tabs.e1, tabs.e2, tabs.e3, S_ext, processed, inCurr,
+        chunk=chunk, n_chunks=n_chunks, m=m, interpret=True)
     dec = np.asarray(dec)
     assert dec.shape == (m + 1,)
     # decrements only land on live edges above the frontier level
     live = np.asarray(S_ext[:m]) > l
     assert (dec[:m][~live] == 0).all()
+
+
+# ------------------------------------------- levels and sub-levels ----
+
+def _levels_recount(g):
+    """Plain numpy level / sub-level peel over the triangle list: (final S,
+    levels, sub-levels), the counts the peel's rule gives."""
+    from repro.core.truss_inc import triangle_list
+
+    tri = triangle_list(g)
+    S = np.bincount(tri.ravel(), minlength=g.m).astype(np.int64)
+    processed = np.zeros(g.m, bool)
+    levels = subs = 0
+    while not processed.all():
+        levels += 1
+        l = S[~processed].min()
+        curr = ~processed & (S == l)
+        while curr.any():
+            subs += 1
+            live = ~processed[tri].any(axis=1) & curr[tri].any(axis=1)
+            t = tri[live]
+            hit = ~curr[t] & (S[t] > l)
+            dec = np.bincount(t[hit], minlength=g.m)
+            d = ~processed & ~curr & (dec > 0)
+            S[d] = np.maximum(S[d] - dec[d], l)
+            processed |= curr
+            curr = ~processed & (S == l)
+    return S, levels, subs
+
+
+@pytest.mark.parametrize("table_mode", ["device", "numpy"])
+@pytest.mark.parametrize("compact", ["off", "on"])
+@pytest.mark.parametrize("mode", PEEL_MODES)
+def test_peel_levels_and_sublevels_match_a_recount(mode, compact,
+                                                   table_mode):
+    """Every executor over the resolved rows, compacting or not: trussness
+    bitwise the oracle's, levels and sub-levels those of a plain recount."""
+    g = build_csr(ADVERSARIAL["rmat"])
+    kw = (dict(compact_frac=None) if compact == "off"
+          else dict(compact_frac=0.99, compact_min=0))
+    res = pkt(g, mode=mode, chunk=16, table_mode=table_mode, **kw)
+    S, levels, subs = _levels_recount(g)
+    assert np.array_equal(res.trussness, truss_numpy(g.El))
+    assert np.array_equal(res.trussness, S + 2)
+    assert (res.levels, res.sublevels) == (levels, subs)
+    assert (res.compactions > 0) == (compact == "on")

@@ -22,7 +22,8 @@ import jax
 import repro
 from repro import spans
 from repro.core.pkt import pkt, truss_pkt
-from repro.core.support import build_peel_table
+from repro.core import support as support_mod
+from repro.core.support import resolve_peel_table
 from repro.graphs.csr import build_csr, degeneracy_order, relabel
 from repro.graphs.gen import ring_of_cliques_edges, rmat_edges
 
@@ -190,8 +191,58 @@ def test_chunk_visits_match_a_numpy_recount(ring, graph, mode, table_mode):
     if mode == "dense":
         assert res.chunk_visits == n_chunks * res.sublevels
     else:
-        subs, visits = _recount(g, build_peel_table(g).off, chunk, n_chunks)
+        subs, visits = _recount(g, resolve_peel_table(g).off, chunk,
+                                n_chunks)
         assert (res.sublevels, res.chunk_visits) == (subs, visits)
+
+
+@pytest.mark.parametrize("table_mode", ["numpy", "device"])
+def test_peel_segments_carry_table_rows_and_hits(ring, table_mode):
+    """Each segment's table: its wedge rows (``table_rows``) and the rows
+    the build kept (``table_hits``), three per triangle of the segment's
+    graph — 3 x 35 for the first segment of a 7-clique ring."""
+    E = ring_of_cliques_edges(4, 7)
+    g = build_csr(E)
+    T = len(_triangles(g.El))
+    assert T == 4 * 35
+    res = pkt(g, chunk=16, table_mode=table_mode, compact_frac=0.99,
+              compact_min=0)
+    segs = [r for r in spans.drain() if r.name == "pkt.peel_segment"]
+    assert len(segs) == res.compactions + 1 >= 2
+    first = segs[0].attrs
+    assert first["table_rows"] == support_mod.peel_table_size(g)
+    assert first["table_hits"] == 3 * T
+    for seg in segs[1:]:
+        assert 0 <= seg.attrs["table_hits"] <= seg.attrs["table_rows"]
+
+
+def test_resolve_runs_in_the_peel_table_build():
+    """The probe runs once, inside ``_build_peel_table_dev`` (where
+    ``tables_dev_ms`` reads it), and the peel jits take no probe bound and
+    run no probe: the chunked peel lowers to its level, sub-level and
+    chunk loops and nothing more."""
+    import inspect
+
+    pkt_mod = importlib.import_module("repro.core.pkt")
+    _, g = _graph("rmat")
+    dev = g.device_arrays()
+    build = support_mod._build_peel_table_dev.lower(
+        dev["El"][:, 0], dev["El"][:, 1], dev["Es"], dev["N"], dev["Eid"],
+        jax.numpy.int32(g.m), m=g.m, size=1 << 12, chunk=64,
+        iters=support_mod._search_iters(g))
+    assert "stablehlo.while" in build.as_text()       # the one probe
+    for fn in (pkt_mod._peel_segment_jit, pkt_mod._pkt_peel_jit,
+               pkt_mod._peel_loop):
+        params = inspect.signature(fn).parameters
+        assert not {"iters", "N", "Eid"} & set(params), fn
+    tabs, chunk, n_chunks, _ = pkt_mod.prepare_peel_device(g, 64)
+    S = jax.numpy.zeros((g.m + 1,), jax.numpy.int32)
+    proc = jax.numpy.zeros((g.m + 1,), bool).at[g.m].set(True)
+    for mode, loops in (("chunked", 3), ("dense", 3)):
+        text = pkt_mod._peel_segment_jit.lower(
+            S, proc, jax.numpy.int32(0), None, tabs, m=g.m, chunk=chunk,
+            n_chunks=n_chunks, mode=mode, interpret=True).as_text()
+        assert text.count("stablehlo.while") == loops, mode
 
 
 def test_counts_are_the_same_with_the_profiler_on(ring, tmp_path):
