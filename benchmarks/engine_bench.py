@@ -6,11 +6,6 @@ distinct shape recompiles, then dispatches one-at-a-time).  Batched = the
 ``TrussEngine`` bucketing the stream into pow2 size classes and vmapping one
 compiled pipeline per class.  Both are measured post-warmup (compiles paid),
 so the gap isolates dispatch/batching efficiency.
-
-The batched rows carry a support-executor column: one row per support mode
-(jnp vs the Pallas kernel), so the kernel-vs-jnp cost of the support phase
-is visible per stream.  Off-TPU the kernel rows run in interpret mode —
-expect them slower there; on a TPU runner they are the competitive path.
 """
 
 from __future__ import annotations
@@ -41,9 +36,9 @@ def _fleet(n_graphs: int, seed: int = 0) -> list[np.ndarray]:
     return [e for e in out if e.size]
 
 
-def run(n_graphs: int = 24, mode: str = "chunked", seed: int = 0,
-        support_modes=("jnp", "pallas")) -> list[str]:
-    """CSV rows: serial-vs-batched engine throughput per support mode."""
+def run(n_graphs: int = 24, mode: str = "chunked",
+        seed: int = 0) -> list[str]:
+    """CSV rows: serial-vs-batched engine throughput."""
     graphs = _fleet(n_graphs, seed)
 
     def serial():
@@ -55,20 +50,19 @@ def run(n_graphs: int = 24, mode: str = "chunked", seed: int = 0,
     out = [row(f"engine/serial/{mode}", t_serial,
                f"graphs={len(graphs)};graphs_per_sec={gps_serial:.2f}")]
 
-    for smode in support_modes:
-        # warmup pays per-bucket compiles (cached in jax's global jit cache);
-        # the timed pass on a fresh engine measures steady-state dispatch
-        TrussEngine(mode=mode, support_mode=smode).map(graphs)
+    # warmup pays per-bucket compiles (cached in jax's global jit cache);
+    # the timed pass on a fresh engine measures steady-state dispatch
+    TrussEngine(mode=mode).map(graphs)
 
-        def batched():
-            TrussEngine(mode=mode, support_mode=smode).map(graphs)
+    def batched():
+        TrussEngine(mode=mode).map(graphs)
 
-        t_batched = timeit(batched, warmup=0, reps=2)
-        gps_batched = len(graphs) / t_batched
-        out.append(row(
-            f"engine/batched/{mode}/sup-{smode}", t_batched,
-            f"graphs={len(graphs)};graphs_per_sec={gps_batched:.2f}"
-            f";speedup={t_serial / t_batched:.2f}x"))
+    t_batched = timeit(batched, warmup=0, reps=2)
+    gps_batched = len(graphs) / t_batched
+    out.append(row(
+        f"engine/batched/{mode}", t_batched,
+        f"graphs={len(graphs)};graphs_per_sec={gps_batched:.2f}"
+        f";speedup={t_serial / t_batched:.2f}x"))
     return out
 
 

@@ -4,22 +4,14 @@ Phases mirrored: support computation / SCAN+processing (peel) — plus the
 wedge-table construction our shape-static SPMD adaptation adds (DESIGN.md
 §7.3), reported honestly as its own phase.
 
-Both phases now carry their own mode axis: support is timed per support
-executor (jnp / pallas, ``core/support.py`` vs ``kernels/support.py``) and
-peel per peel executor (dense / chunked / pallas), and a row is emitted for
-every (support_mode, peel_mode) combination so the support-vs-peel split
-exposes where each pipeline's time goes.  On non-TPU backends the Pallas
-kernels run in *interpret* mode, which is orders of magnitude slower than
-compiled XLA — so pallas rows are only emitted for graphs whose wedge table
-fits ``PALLAS_MAX_WEDGES`` (those rows are about lowering coverage and shape
-behaviour, not competitive time; on a TPU runner the cap is ignored).
+The peel is timed per peel executor (dense / chunked), one row each, so
+the support-vs-peel split exposes where each pipeline's time goes.
 """
 
 from __future__ import annotations
 
 import time
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import support as support_mod
@@ -27,16 +19,11 @@ from repro.core.pkt import _pkt_peel_jit, prepare_peel
 from repro.graphs.datasets import GRAPH_SUITE
 from benchmarks.common import prep_graph, timeit, row
 
-#: interpret-mode pallas is only timed below this wedge-table size on CPU
-PALLAS_MAX_WEDGES = 1 << 16
-
-MODES = ("dense", "chunked", "pallas")
-SUPPORT_MODES = support_mod.SUPPORT_MODES
+MODES = ("dense", "chunked")
 
 
-def run(suite=None, modes=MODES, support_modes=SUPPORT_MODES) -> list[str]:
-    """CSV rows: per-phase seconds for every executor pair on the suite."""
-    on_tpu = jax.default_backend() == "tpu"
+def run(suite=None, modes=MODES) -> list[str]:
+    """CSV rows: per-phase seconds for every peel executor on the suite."""
     out = []
     for name in suite or GRAPH_SUITE:
         g, stats = prep_graph(name, order="kco")
@@ -46,43 +33,28 @@ def run(suite=None, modes=MODES, support_modes=SUPPORT_MODES) -> list[str]:
         rows = support_mod.resolve_peel_table(g)
         t_tables = time.perf_counter() - t0
 
-        t_support = {}
-        for smode in support_modes:
-            if smode == "pallas" and not on_tpu \
-                    and stab.size > PALLAS_MAX_WEDGES:
-                continue
-            t_support[smode] = timeit(
-                lambda: support_mod.compute_support(g, stab, mode=smode))
+        t_sup = timeit(lambda: support_mod.compute_support(g, stab))
         S0 = support_mod.compute_support(g, stab)
 
         tabs, chunk, n_chunks = prepare_peel(rows, g.m, None)   # tuned/auto chunk policy
 
-        t_peel = {}
         for pmode in modes:
-            if pmode == "pallas" and not on_tpu \
-                    and rows.wedges > PALLAS_MAX_WEDGES:
-                continue
-
             def peel():
                 # fresh S0 upload per call: _pkt_peel_jit donates its S0
                 S, _, _ = _pkt_peel_jit(jnp.asarray(S0), tabs,
                                         m=g.m, chunk=chunk,
-                                        n_chunks=n_chunks,
-                                        mode=pmode, interpret=not on_tpu)
+                                        n_chunks=n_chunks, mode=pmode)
                 S.block_until_ready()
 
-            t_peel[pmode] = timeit(peel, warmup=1, reps=2)
-
-        for smode, t_sup in t_support.items():
-            for pmode, t_p in t_peel.items():
-                tot = t_tables + t_sup + t_p
-                out.append(row(
-                    f"fig4/{name}/sup-{smode}+peel-{pmode}", tot,
-                    f"support%={100 * t_sup / tot:.1f}"
-                    f";peel%={100 * t_p / tot:.1f}"
-                    f";tables%={100 * t_tables / tot:.1f}"
-                    f";support_us={t_sup * 1e6:.1f}"
-                    f";peel_us={t_p * 1e6:.1f}"))
+            t_p = timeit(peel, warmup=1, reps=2)
+            tot = t_tables + t_sup + t_p
+            out.append(row(
+                f"fig4/{name}/peel-{pmode}", tot,
+                f"support%={100 * t_sup / tot:.1f}"
+                f";peel%={100 * t_p / tot:.1f}"
+                f";tables%={100 * t_tables / tot:.1f}"
+                f";support_us={t_sup * 1e6:.1f}"
+                f";peel_us={t_p * 1e6:.1f}"))
     return out
 
 

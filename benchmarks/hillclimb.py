@@ -1,16 +1,14 @@
 """Chunk-policy autotuner: measure, then let ``auto_chunk`` consume the result.
 
 ``kernels.wedge_common.auto_chunk`` picks the wedge-table chunk size (one
-Pallas grid step / one chunk-skipping unit) whenever the caller passes
-``chunk=None``.  Its recorded-defaults formula (split the table into
-``AUTO_CHUNK_TARGET`` chunks, clamp to the VMEM band) is a heuristic; this
-bench closes the loop by *measuring*: for every benchmark graph it sweeps the
-pow2 chunk candidates over the real executors, scores each candidate by its
-normalized warm decomposition time summed across the executor pairs that
-consume the chunk (chunked/jnp — the serving default; ``--kernels`` adds
-pallas/pallas on TPU hosts, where its timings are real rather than
-interpret-mode emulation), and records the winner per pow2
-peel-table-size bucket.
+chunk-skipping unit) whenever the caller passes ``chunk=None``.  Its
+recorded-defaults formula (split the table into ``AUTO_CHUNK_TARGET``
+chunks, clamp to a band) is a heuristic; this bench closes the loop by
+*measuring*: for every benchmark graph it sweeps the pow2 chunk candidates
+over the peel executors that consume the chunk (``chunked``, the serving
+default), scores each candidate by its normalized warm decomposition time
+summed across them, and records the winner per pow2 peel-table-size
+bucket.
 
 The emitted table (``--emit``, default
 ``src/repro/kernels/tuned_chunks.json``) is exactly what ``auto_chunk``
@@ -40,14 +38,10 @@ from repro.kernels import wedge_common
 #: default graph suite: one per size regime the serving fleet actually sees
 GRAPHS = ("ba-small", "er-small", "rmat-small")
 
-#: executor pairs whose hot loop the chunk size shapes (peel_mode,
-#: support_mode); scores are normalized within each pair so no single
-#: executor's absolute speed dominates the vote.  The default sweeps the
-#: serving path only: on CPU the Pallas pair runs in *interpret mode*,
-#: whose timings reflect the emulator rather than any accelerator, so it
-#: is opt-in (``--kernels`` / ``kernel_pair=True``) for TPU hosts.
-PAIRS = (("chunked", "jnp"),)
-KERNEL_PAIR = ("pallas", "pallas")
+#: peel executors whose hot loop the chunk size shapes; scores are
+#: normalized within each mode so no single executor's absolute speed
+#: dominates the vote
+MODES = ("chunked",)
 
 
 def chunk_candidates(table_size: int) -> list[int]:
@@ -62,27 +56,26 @@ def chunk_candidates(table_size: int) -> list[int]:
     return out or [pad]
 
 
-def sweep_graph(name: str, *, reps: int = 3, pairs=PAIRS) -> dict:
-    """Time every (chunk candidate × executor pair) on one graph.
+def sweep_graph(name: str, *, reps: int = 3, modes=MODES) -> dict:
+    """Time every (chunk candidate × peel mode) on one graph.
 
     Returns ``{"name", "bucket", "table_size", "chunks": {chunk: score},
-    "best": chunk}`` where score is the sum over executor pairs of the
-    candidate's warm time divided by the pair's best candidate time (1.0 =
-    won that pair outright).
+    "best": chunk}`` where score is the sum over peel modes of the
+    candidate's warm time divided by the mode's best candidate time (1.0 =
+    won that mode outright).
     """
     g, _ = prep_graph(name)
     ptab = support_mod.build_peel_table(g)
     pad = wedge_common.next_pow2(max(1, ptab.size))
     cands = chunk_candidates(ptab.size)
     times: dict[int, dict[int, float]] = {c: {} for c in cands}
-    for pi, (mode, smode) in enumerate(pairs):
+    for pi, mode in enumerate(modes):
         for c in cands:
             times[c][pi] = timeit(
-                lambda c=c, mode=mode, smode=smode: pkt(
-                    g, chunk=c, mode=mode, support_mode=smode),
+                lambda c=c, mode=mode: pkt(g, chunk=c, mode=mode),
                 reps=reps)
     scores: dict[int, float] = {}
-    for pi in range(len(pairs)):
+    for pi in range(len(modes)):
         best = min(times[c][pi] for c in cands)
         for c in cands:
             scores[c] = scores.get(c, 0.0) + times[c][pi] / max(best, 1e-12)
@@ -93,10 +86,9 @@ def sweep_graph(name: str, *, reps: int = 3, pairs=PAIRS) -> dict:
             "best": int(best_chunk)}
 
 
-def tune(graphs=GRAPHS, *, reps: int = 3, kernel_pair: bool = False) -> dict:
+def tune(graphs=GRAPHS, *, reps: int = 3) -> dict:
     """Sweep the suite and vote per bucket (lowest summed score wins)."""
-    pairs = PAIRS + ((KERNEL_PAIR,) if kernel_pair else ())
-    sweeps = [sweep_graph(name, reps=reps, pairs=pairs) for name in graphs]
+    sweeps = [sweep_graph(name, reps=reps) for name in graphs]
     votes: dict[int, dict[int, float]] = {}
     for sw in sweeps:
         b = votes.setdefault(sw["bucket"], {})
@@ -109,10 +101,10 @@ def tune(graphs=GRAPHS, *, reps: int = 3, kernel_pair: bool = False) -> dict:
             "graphs": list(graphs), "buckets": buckets, "sweeps": sweeps}
 
 
-def run(graphs=GRAPHS, *, reps: int = 3, kernel_pair: bool = False,
+def run(graphs=GRAPHS, *, reps: int = 3,
         emit_path: str | None = None) -> dict:
     """Bench-harness adapter: tune, optionally emit, return the table doc."""
-    doc = tune(graphs, reps=reps, kernel_pair=kernel_pair)
+    doc = tune(graphs, reps=reps)
     if emit_path:
         with open(emit_path, "w") as f:
             json.dump({k: doc[k] for k in
@@ -140,17 +132,13 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--smoke", action="store_true",
                     help="single graph, 2 reps (CI)")
-    ap.add_argument("--kernels", action="store_true",
-                    help="also sweep the pallas/pallas pair (TPU hosts; "
-                         "interpret-mode timings are emulator noise)")
     ap.add_argument("--emit", nargs="?", const=str(
         wedge_common.TUNED_CHUNKS_PATH), default=None, metavar="PATH",
         help="write the tuned table (default: the path auto_chunk loads)")
     args = ap.parse_args()
     graphs = args.graphs[:1] if args.smoke else args.graphs
     reps = 2 if args.smoke else args.reps
-    doc = run(graphs, reps=reps, kernel_pair=args.kernels,
-              emit_path=args.emit)
+    doc = run(graphs, reps=reps, emit_path=args.emit)
     for sw in doc["sweeps"]:
         print(f"{sw['name']}: table={sw['table_size']} "
               f"bucket=2^{sw['bucket']} best_chunk={sw['best']} "

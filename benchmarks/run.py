@@ -11,8 +11,8 @@
   hier    — community-index build/query + label parity  (DESIGN.md §11)
   hillclimb— chunk-policy autotune sweep (feeds auto_chunk, §16)
 
-``--smoke`` is the CI gate: a tiny RMAT graph decomposed by every
-(peel mode × support mode) executor pair, Ros, and the numpy oracle;
+``--smoke`` is the CI gate: a tiny RMAT graph decomposed by every peel
+mode, both table modes, Ros, the batched engine and the numpy oracle;
 agreement is asserted (exit 1 on mismatch) and a machine-readable
 BENCH_smoke.json is written for workflow artifacts.
 """
@@ -47,21 +47,15 @@ def smoke(out_path: str = "BENCH_smoke.json") -> int:
         report["ok"] = report["ok"] and same
         return same
 
-    from repro.core.support import SUPPORT_MODES
-
     for mode in PEEL_MODES:
-        for support_mode in SUPPORT_MODES:
-            t0 = time.perf_counter()
-            res = pkt(g, mode=mode, support_mode=support_mode,
-                      phase_timings=True)
-            dt = time.perf_counter() - t0
-            key = mode if support_mode == "jnp" \
-                else f"{mode}+sup-{support_mode}"
-            report["modes"][key] = {
-                "seconds": dt, "agrees": check(f"pkt/{key}", res.trussness),
-                "levels": res.levels, "sublevels": res.sublevels,
-                "phases": {k: round(v, 6) for k, v in res.phases.items()},
-            }
+        t0 = time.perf_counter()
+        res = pkt(g, mode=mode, phase_timings=True)
+        dt = time.perf_counter() - t0
+        report["modes"][mode] = {
+            "seconds": dt, "agrees": check(f"pkt/{mode}", res.trussness),
+            "levels": res.levels, "sublevels": res.sublevels,
+            "phases": {k: round(v, 6) for k, v in res.phases.items()},
+        }
 
     # table_mode axis: host-built tables (the parity oracle) vs the default
     # device builders — phase breakdown shows where table-build time lives.

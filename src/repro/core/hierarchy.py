@@ -190,12 +190,11 @@ class TrussHierarchy:
     """
 
     def __init__(self, trussness: np.ndarray, triangles: np.ndarray, *,
-                 mode: str = "device", interpret: bool | None = None):
+                 mode: str = "device"):
         if mode not in HIER_MODES:
             raise ValueError(
                 f"mode must be one of {HIER_MODES}, got {mode!r}")
         self.mode = mode
-        self.interpret = interpret  # accepted for symmetry; flood is pure XLA
         self.T = np.asarray(trussness, dtype=np.int64)
         self.m = int(self.T.shape[0])
         tri = np.asarray(triangles, dtype=np.int64)
@@ -293,7 +292,7 @@ class TrussHierarchy:
     # ------------------------------------------------------- device builder --
 
     def _pad_dims(self) -> tuple[int, int]:
-        # Labels are pure jnp (no pallas tiling), so the label array only
+        # Labels are pure jnp (no kernel tiling), so the label array only
         # needs *size-class* padding for compile reuse, not a full pow2:
         # round m+1 up to the nearest of {0.75 * 2^b, 2^b}.  Half-step
         # classes keep the O(log m) distinct compiled shapes while capping
@@ -498,8 +497,7 @@ class TrussHierarchy:
         and the translated labels stay canonical without a re-scan.  Levels
         <= ``k_hi`` come back dirty and rebuild lazily.
         """
-        h = TrussHierarchy(trussness, triangles, mode=self.mode,
-                           interpret=self.interpret)
+        h = TrussHierarchy(trussness, triangles, mode=self.mode)
         old_to_new = np.asarray(old_to_new, dtype=np.int64)
         for k in range(max(int(k_hi) + 1, 2), h.k_max + 1):
             old = (self._labels[k - 2]

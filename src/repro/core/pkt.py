@@ -21,19 +21,13 @@ which two edges close it, depends only on the graph, so the table builder
 runs it once and keeps the hits; the peel gathers edge state at three
 edge ids per row and never searches.
 
-Three peel modes (``mode`` / ``peel_mode``):
+Two peel modes (``mode`` ∈ ``PEEL_MODES``), bitwise identical:
   mode="chunked" (default): work-efficient chunk-skipping while_loop.
-  mode="dense":  every sub-level scans the whole wedge table with frontier
+  mode="dense":  every sub-level scans the whole peel table with frontier
                  masking — the naive SPMD port, kept as a benchmark foil.
-  mode="pallas": the chunk scan runs as a VMEM-blocked Pallas kernel
-                 (kernels/peel.py) — one wedge-table chunk per grid step,
-                 chunk-skipping degraded to compute masking (grids are
-                 static).  Bitwise-identical results to the other two modes.
 
-The support phase has its own independent executor axis
-(``support_mode`` ∈ ``core.support.SUPPORT_MODES``): "jnp" is the flat XLA
-program, "pallas" the chunked kernel in kernels/support.py.  Any
-(support_mode × peel_mode) combination is valid and all six produce
+The support phase has one executor, the flat XLA program of
+``core.support`` (``_support_device_jit``); both peel modes over it give
 bitwise-identical trussness (tests/test_parity_matrix.py asserts it).
 
 The peel loop is written against *padded* edge state so the batched engine
@@ -63,7 +57,7 @@ from repro.testing.chaos import fault_point
 #: this module initialises no JAX backend
 _SENTINEL_S = np.int32(1 << 30)
 
-PEEL_MODES = ("chunked", "dense", "pallas")
+PEEL_MODES = ("chunked", "dense")
 
 
 class PeelTables(NamedTuple):
@@ -247,8 +241,8 @@ def _active_chunk_mask(inCurr, tabs: PeelTables, m: int, n_chunks: int):
 
 
 def _peel_loop(S_ext0, processed0, tabs: PeelTables, *, m: int, chunk: int,
-               n_chunks: int, mode: str, interpret: bool = True, pinned=None,
-               stop_live=None, reduce=None):
+               n_chunks: int, mode: str, pinned=None, stop_live=None,
+               reduce=None):
     """Full level/sub-level peel over extended (m+1,) edge state.
 
     ``S_ext0``/``processed0`` define which slots are live: slot m must be the
@@ -306,19 +300,6 @@ def _peel_loop(S_ext0, processed0, tabs: PeelTables, *, m: int, chunk: int,
                 return chunk_contrib(c, dec, S_ext, processed, inCurr, l)
             dec = jax.lax.fori_loop(0, n_chunks, body, dec0)
             visits = jnp.int32(n_chunks)
-        elif mode == "pallas":
-            from repro.kernels.peel import peel_decrement_fold
-            active = _active_chunk_mask(inCurr, tabs, m, n_chunks)
-            visits = jnp.sum(active.astype(jnp.int32))
-            pin = (jnp.zeros((m + 1,), jnp.int32) if pinned is None
-                   else pinned.astype(jnp.int32))
-            dec = peel_decrement_fold(
-                active.astype(jnp.int32),
-                jnp.reshape(l, (1,)).astype(jnp.int32),
-                tabs.e1, tabs.e2, tabs.e3,
-                S_ext, processed.astype(jnp.int32),
-                inCurr.astype(jnp.int32), pin,
-                chunk=chunk, n_chunks=n_chunks, m=m, interpret=interpret)
         else:  # chunked: visit only chunks overlapping the frontier
             active = _active_chunk_mask(inCurr, tabs, m, n_chunks)
             n_active = jnp.sum(active.astype(jnp.int32))
@@ -383,30 +364,29 @@ def _peel_loop(S_ext0, processed0, tabs: PeelTables, *, m: int, chunk: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "mode", "interpret"),
+    static_argnames=("m", "chunk", "n_chunks", "mode"),
     donate_argnums=(0,),  # S0: consumed into the peel state, never reread
 )
 def _pkt_peel_jit(S0, tabs: PeelTables, *, m: int, chunk: int,
-                  n_chunks: int, mode: str = "chunked",
-                  interpret: bool = True):
+                  n_chunks: int, mode: str = "chunked"):
     """Runs the full level/sub-level peel; returns (S_final, levels, sublevels)."""
     # extended edge state: slot m is a sentinel (processed, never in frontier)
     S_ext0 = jnp.concatenate([S0.astype(jnp.int32), jnp.full((1,), _SENTINEL_S)])
     processed0 = jnp.zeros((m + 1,), jnp.bool_).at[m].set(True)
     S_ext, _, levels, subs, _ = _peel_loop(
         S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
-        mode=mode, interpret=interpret)
+        mode=mode)
     return S_ext[:m], levels, subs
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "mode", "interpret"),
+    static_argnames=("m", "chunk", "n_chunks", "mode"),
     donate_argnums=(0, 1),  # peel-state buffers: never reread by the caller
 )
 def _peel_segment_jit(S_ext0, processed0, stop_live, pinned,
                       tabs: PeelTables, *, m: int, chunk: int, n_chunks: int,
-                      mode: str, interpret: bool):
+                      mode: str):
     """One compaction segment: peel until done or ≤ ``stop_live`` edges live.
 
     Returns (S_ext, processed, counts): ``counts`` stacks levels,
@@ -417,7 +397,7 @@ def _peel_segment_jit(S_ext0, processed0, stop_live, pinned,
     """
     S_ext, processed, levels, subs, visits = _peel_loop(
         S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
-        mode=mode, interpret=interpret, pinned=pinned, stop_live=stop_live)
+        mode=mode, pinned=pinned, stop_live=stop_live)
     return S_ext, processed, jnp.stack([levels, subs, visits])
 
 
@@ -501,7 +481,7 @@ def _make_subproblem(El_rows: np.ndarray, ids: np.ndarray,
 
 
 def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
-                    interpret: bool, table_mode: str,
+                    table_mode: str,
                     compact_frac: float | None, compact_min: int,
                     chunk_req: int | None) -> tuple[int, int, int, int]:
     """Run ``problem`` to the fixed point, compacting between segments.
@@ -536,7 +516,7 @@ def _segmented_peel(problem: dict, out: np.ndarray, *, mode: str,
                 problem["S_ext0"], problem["processed0"],
                 jnp.int32(live_target), problem["pinned"], problem["tabs"],
                 m=m, chunk=problem["chunk"], n_chunks=problem["n_chunks"],
-                mode=mode, interpret=interpret)
+                mode=mode)
             S_np, proc_np, seg, hits = jax.device_get(
                 (S_ext, processed, seg, problem["table_hits"]))
             sp.set(levels=int(seg[0]), sublevels=int(seg[1]),
@@ -566,7 +546,6 @@ def peel_live_subset(El: np.ndarray, live_ids: np.ndarray,
                      S0_live: np.ndarray,
                      pinned_live: np.ndarray | None = None, *,
                      chunk: int | None = None, mode: str = "chunked",
-                     interpret: bool | None = None,
                      table_mode: str = "device",
                      compact_frac: float | None = _COMPACT_FRAC,
                      compact_min: int = _COMPACT_MIN) -> np.ndarray:
@@ -589,43 +568,35 @@ def peel_live_subset(El: np.ndarray, live_ids: np.ndarray,
         # ascending ids are what make the compacted relabeling
         # order-preserving — the tie-break replay is silently wrong otherwise
         raise ValueError("live_ids must be strictly increasing edge ids")
-    interpret = wedge_common.resolve_interpret(interpret, peel_mode=mode)
     out = np.zeros(k, np.int32)
     problem = _make_subproblem(
         np.asarray(El)[live_ids], np.arange(k, dtype=np.int64),
         np.asarray(S0_live, dtype=np.int32),
         None if pinned_live is None else np.asarray(pinned_live, bool),
         chunk_req=chunk, table_mode=table_mode)
-    _segmented_peel(problem, out, mode=mode, interpret=interpret,
-                    table_mode=table_mode, compact_frac=compact_frac,
-                    compact_min=compact_min, chunk_req=chunk)
+    _segmented_peel(problem, out, mode=mode, table_mode=table_mode,
+                    compact_frac=compact_frac, compact_min=compact_min,
+                    chunk_req=chunk)
     return out
 
 
 def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
-        peel_mode: str | None = None, support_mode: str = "jnp",
         table_mode: str | None = None,
         support_table: support_mod.WedgeTable | None = None,
         peel_table: support_mod.WedgeTable | None = None,
-        interpret: bool | None = None,
         compact_frac: float | None = _COMPACT_FRAC,
         compact_min: int = _COMPACT_MIN,
         phase_timings: bool = False) -> PKTResult:
     """Full PKT truss decomposition of one CSR graph.
 
-    Every executor pairing produces bitwise-identical trussness
-    (``tests/test_parity_matrix.py``).
+    Both peel modes and both table modes produce bitwise-identical
+    trussness (``tests/test_parity_matrix.py``).
 
     Args:
         g: the graph as a :class:`~repro.graphs.csr.CSRGraph`.
         chunk: wedge-table chunk size (pow2; ``None`` derives it from the
             table size, see ``kernels.wedge_common.auto_chunk``).
-        mode: peel executor — one of ``PEEL_MODES`` ("chunked", "dense",
-            "pallas"); alias ``peel_mode`` wins when both are given.
-        peel_mode: alias for ``mode``.
-        support_mode: support executor — one of
-            ``support.SUPPORT_MODES`` ("jnp", "pallas"); the two executor
-            axes are independent (see module docstring).
+        mode: peel executor — one of ``PEEL_MODES`` ("chunked", "dense").
         table_mode: where the wedge tables are built
             (``support.TABLE_MODES``): "device" — the default, unless
             prebuilt host tables are passed — constructs them as jitted XLA
@@ -636,8 +607,6 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             ``table_mode="numpy"`` unless overridden).
         peel_table: optional prebuilt host peel wedge table (same
             implication); it is resolved to triangle rows on the host.
-        interpret: force/forbid Pallas interpret mode (default: interpret
-            when not on a TPU).
         compact_frac: live-edge compaction threshold (DESIGN.md §10): once
             a peel segment leaves fewer than ``compact_frac · m`` edges
             live (and more than ``compact_min``), survivors are gathered
@@ -656,24 +625,16 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
         chunk-visit and compaction counters.
 
     Raises:
-        ValueError: unknown ``mode`` / ``support_mode`` / ``table_mode``.
-        NotImplementedError: a Pallas executor or ``interpret=True`` on a
-            TPU backend (``kernels.wedge_common.resolve_interpret``).
+        ValueError: unknown ``mode`` / ``table_mode``.
     """
-    mode = mode if peel_mode is None else peel_mode
     if mode not in PEEL_MODES:
         raise ValueError(f"mode must be one of {PEEL_MODES}, got {mode!r}")
-    if support_mode not in support_mod.SUPPORT_MODES:
-        raise ValueError(f"support_mode must be one of "
-                         f"{support_mod.SUPPORT_MODES}, got {support_mode!r}")
     if table_mode is None:
         table_mode = ("numpy" if (support_table is not None
                                   or peel_table is not None) else "device")
     if table_mode not in support_mod.TABLE_MODES:
         raise ValueError(f"table_mode must be one of "
                          f"{support_mod.TABLE_MODES}, got {table_mode!r}")
-    interpret = wedge_common.resolve_interpret(
-        interpret, peel_mode=mode, support_mode=support_mode)
     if g.m == 0:
         return PKTResult(np.zeros(0, np.int32), np.zeros(0, np.int32), 0, 0,
                          phases=(dict.fromkeys(_PHASE_SPANS.values(), 0.0)
@@ -681,11 +642,10 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
 
     with spans.span("pkt", m=g.m) as root:
         # ---- support phase -------------------------------------------------
-        fault_point("support", rung=f"{support_mode}/{table_mode}")
+        fault_point("support", rung=table_mode)
         if table_mode == "device" and support_table is None:
             with spans.span("pkt.support") as sp:
-                S0_dev, rows = support_mod._support_device(
-                    g, mode=support_mode, chunk=chunk, interpret=interpret)
+                S0_dev, rows = support_mod._support_device(g)
                 sp.set(padded_rows=rows)
                 S0 = np.asarray(S0_dev)   # the readback waits for the device
         else:
@@ -694,9 +654,7 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
                         else support_mod.build_support_table(g))
                 sp.set(rows=stab.size)
             with spans.span("pkt.support", rows=stab.size):
-                S0 = support_mod.compute_support(
-                    g, stab, mode=support_mode, chunk=chunk,
-                    interpret=interpret)
+                S0 = support_mod.compute_support(g, stab)
             S0_dev = jnp.asarray(S0)
 
         # ---- peel tables ---------------------------------------------------
@@ -726,9 +684,9 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             ids=np.arange(m, dtype=np.int64))
         S_out = np.zeros(m, np.int32)
         levels, subs, visits, compactions = _segmented_peel(
-            problem, S_out, mode=mode, interpret=interpret,
-            table_mode=table_mode, compact_frac=compact_frac,
-            compact_min=compact_min, chunk_req=chunk)
+            problem, S_out, mode=mode, table_mode=table_mode,
+            compact_frac=compact_frac, compact_min=compact_min,
+            chunk_req=chunk)
         return PKTResult(
             trussness=S_out.astype(np.int32) + 2,
             support=S0,
@@ -779,7 +737,6 @@ def align_to_input(trussness: np.ndarray, g: CSRGraph,
 
 def truss_pkt(edges: np.ndarray, *, reorder: bool = True,
               chunk: int | None = None, mode: str = "chunked",
-              support_mode: str = "jnp",
               table_mode: str | None = None,
               compact_frac: float | None = _COMPACT_FRAC,
               compact_min: int = _COMPACT_MIN) -> np.ndarray:
@@ -814,8 +771,7 @@ def truss_pkt(edges: np.ndarray, *, reorder: bool = True,
                 row_keys = edge_keys(lo, hi, n)
             g = build_csr(r_edges, n)
             sp.set(n=n, m=g.m)
-        res = pkt(g, chunk=chunk, mode=mode, support_mode=support_mode,
-                  table_mode=table_mode, compact_frac=compact_frac,
-                  compact_min=compact_min)
+        res = pkt(g, chunk=chunk, mode=mode, table_mode=table_mode,
+                  compact_frac=compact_frac, compact_min=compact_min)
         with spans.span("truss_pkt.align"):
             return align_to_input(res.trussness, g, None, n, keys=row_keys)

@@ -15,10 +15,8 @@ idiom natural to an SPMD mesh:
     communication — the distributed analogue of the paper's per-sub-level
     barrier;
   * support computation fans out the same way (each device builds and probes
-    its slice of the oriented wedge table, then one psum of the partial
-    supports); per shard it runs either as the flat jnp program or —
-    ``support_mode="pallas"`` — as the chunked kernel from
-    ``kernels/support.py``.  Both modes are bitwise identical.
+    its slice of the oriented wedge table with the flat jnp program, then
+    one psum of the partial supports).
 
 Work per sub-level per device: the chunks of its slice that overlap the
 frontier. Communication per sub-level: one all-reduce of an m-vector.
@@ -48,32 +46,21 @@ def _psum(x, axes: Sequence[str]):
 
 
 def _dist_support_body(N, Eid, e1, cand, lo, hi, *, m: int, iters: int,
-                       axes: Sequence[str], mode: str = "jnp",
-                       chunk: int = 0, interpret: bool = True):
-    """Sharded AM4 support over this device's table slice (inside shard_map).
-
-    ``mode="pallas"`` evaluates the slice with the chunked support kernel
-    (the caller guarantees the slice length is a multiple of ``chunk``);
-    integer-exact addition and one psum make the two modes bitwise
-    identical.
-    """
-    n_chunks = e1.shape[0] // chunk if chunk else 1   # jnp ignores both
-    S = support_mod.support_from_table_arrays(
-        e1, cand, lo, hi, N, Eid, m=m, mode=mode, chunk=chunk,
-        n_chunks=n_chunks, iters=iters, interpret=interpret)
+                       axes: Sequence[str]):
+    """Sharded AM4 support over this device's table slice (inside shard_map):
+    integer-exact addition and one psum give the single-device support."""
+    S = support_mod._support_jit(N, Eid, e1, cand, lo, hi, iters, m)
     return _psum(S, axes)
 
 
 def _dist_support_dev_body(u, v, Es, Eo, N, Eid, *, m: int, per_shard: int,
-                           iters: int, axes: Sequence[str], mode: str,
-                           chunk: int, interpret: bool):
+                           iters: int, axes: Sequence[str]):
     """Build this device's support-table slice, then probe it."""
     start = jax.lax.axis_index(tuple(axes)) * per_shard
     e1, cand, lo, hi, _ = support_mod.support_rows(
         u, v, Es, Eo, jnp.int32(m), m=m, size=per_shard, start=start)
     return _dist_support_body(N, Eid, e1, cand, lo, hi, m=m, iters=iters,
-                              axes=axes, mode=mode, chunk=chunk,
-                              interpret=interpret)
+                              axes=axes)
 
 
 def _dist_peel_rows_body(u, v, Es, N, Eid, *, m: int, per_shard: int,
@@ -126,8 +113,7 @@ def make_pkt_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *, m: int,
 
 @functools.lru_cache(maxsize=None)
 def make_support_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
-                      m: int, iters: int, mode: str = "jnp", chunk: int = 0,
-                      interpret: bool = True):
+                      m: int, iters: int):
     """Jitted shard_map support over prebuilt sharded tables (DESIGN.md §6).
 
     Wedge-table shards live per-device along ``axes``; each device counts
@@ -136,8 +122,7 @@ def make_support_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
     """
     rep, sh = P(), P(tuple(axes))
     fn = jax.shard_map(
-        functools.partial(_dist_support_body, m=m, iters=iters, axes=axes,
-                          mode=mode, chunk=chunk, interpret=interpret),
+        functools.partial(_dist_support_body, m=m, iters=iters, axes=axes),
         mesh=mesh, in_specs=(rep, rep, sh, sh, sh, sh), out_specs=rep,
         check_vma=False)
     return jax.jit(fn)
@@ -145,13 +130,11 @@ def make_support_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
 
 @functools.lru_cache(maxsize=None)
 def _support_dev_dist(mesh: jax.sharding.Mesh, axes: tuple[str, ...], *,
-                      m: int, per_shard: int, iters: int, mode: str,
-                      chunk: int, interpret: bool):
+                      m: int, per_shard: int, iters: int):
     """Jitted shard_map: each device builds and probes its support slice."""
     return jax.jit(jax.shard_map(
         functools.partial(_dist_support_dev_body, m=m, per_shard=per_shard,
-                          iters=iters, axes=axes, mode=mode, chunk=chunk,
-                          interpret=interpret),
+                          iters=iters, axes=axes),
         mesh=mesh, in_specs=(P(),) * 6, out_specs=P(), check_vma=False))
 
 
@@ -189,8 +172,7 @@ def _host_peel_shards(g: CSRGraph, n_shards: int, per: int,
 
 def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
              axes: Sequence[str] = ("data",), chunk: int | None = None,
-             support_mode: str = "jnp", table_mode: str = "device",
-             interpret: bool | None = None) -> np.ndarray:
+             table_mode: str = "device") -> np.ndarray:
     """Run distributed PKT over ``mesh``. Returns trussness (m,).
 
     ``mesh`` defaults to a 1-D mesh over every device.  The CSR arrays are
@@ -198,25 +180,17 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
     each device builds only its own slice of both wedge tables, so the
     tables are split across the devices from the start and never pass
     through the host or one device.  "numpy" keeps the host builders as the
-    parity oracle and uploads each slice straight to its device.
-    ``support_mode`` selects the per-shard support executor ("jnp" or
-    "pallas"); the peel is the sharded chunk-skipping loop either way.
-    ``chunk`` (``None``: ``kernels.wedge_common.auto_chunk`` of the
-    per-device slice) sets the peel chunk.
+    parity oracle and uploads each slice straight to its device.  The peel
+    is the sharded chunk-skipping loop.  ``chunk`` (``None``:
+    ``kernels.wedge_common.auto_chunk`` of the per-device slice) sets the
+    peel chunk.
 
     Raises:
-        ValueError: unknown ``support_mode`` / ``table_mode``.
-        NotImplementedError: ``support_mode="pallas"`` or
-            ``interpret=True`` on a TPU backend.
+        ValueError: unknown ``table_mode``.
     """
-    if support_mode not in support_mod.SUPPORT_MODES:
-        raise ValueError(f"support_mode must be one of "
-                         f"{support_mod.SUPPORT_MODES}, got {support_mode!r}")
     if table_mode not in support_mod.TABLE_MODES:
         raise ValueError(f"table_mode must be one of "
                          f"{support_mod.TABLE_MODES}, got {table_mode!r}")
-    interpret = wedge_common.resolve_interpret(interpret,
-                                               support_mode=support_mode)
     if mesh is None:
         mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
         axes = ("data",)
@@ -234,17 +208,10 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
     # ---- support: each shard probes its slice, one psum ---------------------
     s_iters = support_mod._search_iters(g, oriented=True)
     per_shard = max(1, -(-support_mod.support_table_size(g) // n_shards))
-    sup_chunk = 0
-    if support_mode == "pallas":
-        # each shard lowers the kernel over its slice: the slice must be a
-        # whole number of chunks, so round the per-shard length up to one
-        sup_chunk = wedge_common.pow2_chunk(1 << 13, chunk)
-        per_shard = -(-per_shard // sup_chunk) * sup_chunk
     support_mod._check_table_size(per_shard * n_shards)
     if table_mode == "device":
         sup_fn = _support_dev_dist(mesh, axes, m=g.m, per_shard=per_shard,
-                                   iters=s_iters, mode=support_mode,
-                                   chunk=sup_chunk, interpret=interpret)
+                                   iters=s_iters)
         S0 = sup_fn(csr["u"], csr["v"], csr["Es"], csr["Eo"], csr["N"],
                     csr["Eid"])
     else:
@@ -252,9 +219,7 @@ def pkt_dist(g: CSRGraph, mesh: jax.sharding.Mesh | None = None,
         ssize = per_shard * n_shards
         s_tab = [jax.device_put(wedge_common.pad1(a, ssize, fill), sh) for a, fill in (
             (stab.e1, g.m), (stab.cand_slot, 0), (stab.lo, 0), (stab.hi, 0))]
-        sup_fn = make_support_dist(mesh, axes, m=g.m, iters=s_iters,
-                                   mode=support_mode, chunk=sup_chunk,
-                                   interpret=interpret)
+        sup_fn = make_support_dist(mesh, axes, m=g.m, iters=s_iters)
         S0 = sup_fn(csr["N"], csr["Eid"], *s_tab)
 
     # ---- peel tables: each shard holds a whole number of chunks -------------

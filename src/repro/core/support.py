@@ -17,22 +17,11 @@ edge (u,v) with w scanned from N⁺(v). Work: Θ(m + Σ_v d⁻(v)·d⁺(v)·log 
 the ordering-dependence (Table 2) is preserved: relabeling by coreness shrinks
 d⁺ exactly as in the paper.
 
-Two execution modes (``compute_support(mode=...)``), bitwise identical:
-
-  mode="jnp" (default): the wedge table is evaluated as one flat jnp
-      gather/search/scatter program (``_support_jit``) — XLA fuses it, but
-      every probe round-trips through HBM.
-  mode="pallas": the table is cut into fixed chunks and evaluated by the
-      Pallas kernel in ``kernels/support.py`` (DESIGN.md §2) — one chunk per
-      grid step, the candidate gather fused with the ranged binary search in
-      VMEM, per-chunk triangle partials accumulated on-chip.  The kernel
-      emits increment-target streams; the support scatter-add happens once
-      outside, so integer-exact addition makes the two modes agree bitwise.
-      Off-TPU the kernel runs in interpret mode; it does not lower for the
-      TPU yet, so a TPU backend refuses it (ROADMAP Speed 2).
-
-The peel phase has the same split (``core.pkt.pkt(mode=...)``); the two
-kernels share layout and search machinery via ``kernels/wedge_common.py``.
+The wedge table is evaluated as one flat jnp gather/search/scatter program
+(``_support_jit``; fused with the device table build in
+``_support_device_jit``) — XLA fuses it, but every probe round-trips
+through HBM.  The support and peel-table builders share their search
+machinery via ``kernels/wedge_common.py``.
 """
 
 from __future__ import annotations
@@ -47,15 +36,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.graphs.csr import CSRGraph
-from repro.kernels.wedge_common import (chunk_layout, next_pow2, pad_chunked,
-                                        pow2_chunk, probe, probe_np,
-                                        resolve_interpret)
+from repro.kernels.wedge_common import next_pow2, probe, probe_np
 # re-export: the triangle-list engine binary-searches through this module's
 # namespace (kernels.wedge_common is the canonical home)
 from repro.kernels.wedge_common import ranged_searchsorted  # noqa: F401
-
-#: executors for the support phase; "pallas" = kernels/support.py
-SUPPORT_MODES = ("jnp", "pallas")
 
 #: where wedge tables are constructed: "numpy" is the original host builder
 #: (kept as the parity oracle), "device" the jitted XLA builder below —
@@ -199,9 +183,9 @@ def resolve_peel_table(g: CSRGraph,
 # where a ``searchsorted`` over the offsets costs ceil(log2(m+1)) dependent
 # gathers per row.  Rows are materialized to a *static* pow2-padded ``size``
 # (the exact entry count is data-dependent; the cheap O(m) host calculators
-# below bound it before the jit runs).  Support-table padding follows
-# ``wedge_common.pad_chunked``: anchor sentinel ``m``, empty probe range
-# ``lo == hi == 0``.  The peel builder probes its wedge rows in the same jit
+# below bound it before the jit runs).  Support-table padding rows carry
+# the anchor sentinel ``m`` and an empty probe range ``lo == hi == 0``.
+# The peel builder probes its wedge rows in the same jit
 # and keeps only the hits, as triangle rows ``(e1, e2, e3)`` (``PeelRows``),
 # with the sentinel ``m`` in all three columns on the rows left over.
 # ``m_real`` is a dynamic scalar so the batched engine can reuse one compiled
@@ -284,7 +268,8 @@ def support_rows(u, v, Es, Eo, m_real, *, m: int, size: int, start=0):
 
     ``u``/``v``: (m,) edge endpoints (rows >= ``m_real`` are inert padding);
     ``Es``: (n_pad+1,) CSR offsets; ``Eo``: (n_pad,).  Returns
-    ``(e1, cand_slot, lo, hi, off)`` with the pad_chunked sentinel contract.
+    ``(e1, cand_slot, lo, hi, off)``; rows past the table carry the anchor
+    sentinel ``m`` and an empty probe range.
     """
     ar = jnp.arange(m, dtype=jnp.int32)
     cnt = jnp.where(ar < m_real, Es[v + 1] - Eo[v], 0)
@@ -389,46 +374,19 @@ def _build_peel_table_dev(u, v, Es, N, Eid, m_real, *, m: int, size: int,
     return e1, e2, e3, c_start, c_end, has, off[m]
 
 
-def support_from_table_arrays(e1, cand, lo, hi, N, Eid, *, m: int, mode: str,
-                              chunk: int, n_chunks: int, iters: int,
-                              interpret: bool):
-    """Run the selected support executor over prepared table arrays → (m,) S.
-
-    Trace-level helper (call inside a jit): the single home of the
-    executor dispatch + sentinel/target-folding contract, shared by the
-    fused single-graph program below and the batched engine
-    (``serve.truss_engine._batched_truss_dev``).  Table arrays follow the
-    ``pad_chunked`` convention and must span ``n_chunks * chunk`` rows.
-    """
-    if mode == "pallas":
-        from repro.kernels.support import support_accumulate
-
-        S, _ = support_accumulate(
-            e1, cand, lo, hi, N, Eid, chunk=chunk, n_chunks=n_chunks,
-            iters=iters, m=m, interpret=interpret)
-        return S[:m]
+@functools.partial(jax.jit, static_argnames=("m", "size", "iters"))
+def _support_device_jit(u, v, Es, Eo, N, Eid, m_real, *, m: int, size: int,
+                        iters: int):
+    """Fused device program: build the oriented table *and* run the support
+    executor in one jit — one compile on the open path, and XLA can fuse
+    the row construction into the probe (the table is never materialized
+    to HBM)."""
+    e1, cand, lo, hi, _ = _build_support_table_dev(
+        u, v, Es, Eo, m_real, m=m, size=size)
     return _support_jit(N, Eid, e1, cand, lo, hi, iters, m)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "size", "mode", "chunk",
-                                             "n_chunks", "iters",
-                                             "interpret"))
-def _support_device_jit(u, v, Es, Eo, N, Eid, m_real, *, m: int, size: int,
-                        mode: str, chunk: int, n_chunks: int, iters: int,
-                        interpret: bool):
-    """Fused device program: build the oriented table *and* run the support
-    executor in one jit — one compile on the open path, and in jnp mode XLA
-    can fuse the row construction into the probe (the table is never
-    materialized to HBM)."""
-    e1, cand, lo, hi, _ = _build_support_table_dev(
-        u, v, Es, Eo, m_real, m=m, size=size)
-    return support_from_table_arrays(
-        e1, cand, lo, hi, N, Eid, m=m, mode=mode, chunk=chunk,
-        n_chunks=n_chunks, iters=iters, interpret=interpret)
-
-
-def _support_device(g: CSRGraph, *, mode: str, chunk: int | None,
-                    interpret: bool):
+def _support_device(g: CSRGraph):
     """Support phase with the table built on device; returns a (m,) device
     array (no host round-trip — ``pkt`` feeds it straight to the peel) and
     the table's padded row count.
@@ -441,17 +399,15 @@ def _support_device(g: CSRGraph, *, mode: str, chunk: int | None,
     size_pad = next_pow2(size)
     _check_table_size(size_pad)
     dev = g.device_arrays()
-    chunk_eff = pow2_chunk(size_pad, chunk, size=size)
     S = _support_device_jit(
         dev["El"][:, 0], dev["El"][:, 1], dev["Es"], dev["Eo"],
         dev["N"], dev["Eid"], jnp.int32(g.m), m=g.m, size=size_pad,
-        mode=mode, chunk=chunk_eff, n_chunks=size_pad // chunk_eff,
-        iters=_search_iters(g, oriented=True), interpret=interpret)
+        iters=_search_iters(g, oriented=True))
     return S, size_pad
 
 
 # ``ranged_searchsorted`` lives in kernels/wedge_common.py (shared with the
-# Pallas kernels) and is re-exported here for its established call sites
+# table builders) and is re-exported here for its established call sites
 # (core/pkt.py, core/pkt_dist.py, core/triangle_list.py, benchmarks).
 
 
@@ -502,54 +458,28 @@ def _support_jit(N, Eid, e1, cand_slot, lo, hi, iters: int, m: int):
 
 
 def compute_support(g: CSRGraph, table: WedgeTable | None = None, *,
-                    mode: str = "jnp", chunk: int | None = None,
-                    interpret: bool | None = None,
                     table_mode: str | None = None) -> np.ndarray:
     """Edge support (triangles per edge) via the AM4 adaptation. Returns (m,).
 
-    ``mode`` selects the executor (see module docstring): "jnp" is the flat
-    XLA program, "pallas" the chunked VMEM kernel (``chunk`` entries per grid
-    step, auto-derived from the table size when None; ``interpret``
-    forces/forbids interpret mode, default off-TPU).  ``table_mode`` selects
-    where the wedge table is constructed (``TABLE_MODES``): "device" (the
-    default when no prebuilt ``table`` is passed) runs the jitted XLA
-    builder, "numpy" the original host builder.  On a TPU backend
-    ``mode="pallas"`` and ``interpret=True`` raise ``NotImplementedError``
-    (``kernels.wedge_common.resolve_interpret``).
+    ``table_mode`` selects where the wedge table is constructed
+    (``TABLE_MODES``): "device" (the default when no prebuilt ``table`` is
+    passed) runs the jitted XLA builder, "numpy" the original host builder.
     """
-    if mode not in SUPPORT_MODES:
-        raise ValueError(f"mode must be one of {SUPPORT_MODES}, got {mode!r}")
     if table_mode is None:
         table_mode = "numpy" if table is not None else "device"
     if table_mode not in TABLE_MODES:
         raise ValueError(
             f"table_mode must be one of {TABLE_MODES}, got {table_mode!r}")
-    interpret = resolve_interpret(interpret, support_mode=mode)
     if g.m == 0:
         return np.zeros(0, np.int32)
     if table_mode == "device" and table is None:
-        S, _ = _support_device(g, mode=mode, chunk=chunk,
-                               interpret=interpret)
+        S, _ = _support_device(g)
         return np.asarray(S)
     if table is None:
         table = build_support_table(g)
     if table.size == 0:
         # triangle-free under the orientation (e.g. stars): nothing to probe
         return np.zeros(g.m, np.int32)
-    if mode == "pallas":
-        from repro.kernels.support import support_counts
-
-        chunk_eff, n_chunks = chunk_layout(table.size, chunk)
-        e1, cand, lo, hi = pad_chunked(
-            table.e1, table.cand_slot, table.lo, table.hi,
-            m=g.m, chunk=chunk_eff, n_chunks=n_chunks)
-        S_ext, _ = support_counts(
-            jnp.asarray(e1), jnp.asarray(cand), jnp.asarray(lo),
-            jnp.asarray(hi), jnp.asarray(g.N), jnp.asarray(g.Eid),
-            chunk=chunk_eff, n_chunks=n_chunks,
-            iters=_search_iters(g, oriented=True), m=g.m,
-            interpret=interpret)
-        return np.asarray(S_ext)[: g.m]
     S = _support_jit(
         jnp.asarray(g.N), jnp.asarray(g.Eid),
         jnp.asarray(table.e1), jnp.asarray(table.cand_slot),

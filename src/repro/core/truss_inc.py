@@ -406,7 +406,6 @@ class IncrementalTruss:
             batch entry point).
         n: vertex-space size (default: max id + 1; grows with updates).
         mode: peel executor (see ``core.pkt.pkt``).
-        support_mode: support executor.
         table_mode: wedge-table builder ("device" / "numpy", §10).
         hier_mode: community-index builder ("device" / "host", §11).
         insert_mode: insertion repair strategy ("batched" / "sequential",
@@ -421,29 +420,20 @@ class IncrementalTruss:
         compact_frac: live-edge compaction threshold for full recomputes
             (``None`` disables; §10).
         compact_min: minimum live-edge count for compaction.
-        interpret: force/forbid Pallas interpret mode.
 
     Raises:
         ValueError: unknown mode axis, invalid edge array, or
             out-of-range ``local_frac``.
-        NotImplementedError: a Pallas executor or ``interpret=True`` on a
-            TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
 
     def __init__(self, edges, *, n: int | None = None, mode: str = "chunked",
-                 support_mode: str = "jnp", table_mode: str = "device",
-                 hier_mode: str = "device", insert_mode: str = "batched",
-                 chunk: int | None = None,
+                 table_mode: str = "device", hier_mode: str = "device",
+                 insert_mode: str = "batched", chunk: int | None = None,
                  local_frac: float = 0.25, host_peel_max: int = 4096,
                  compact_frac: float | None = _COMPACT_FRAC,
-                 compact_min: int = _COMPACT_MIN,
-                 interpret: bool | None = None):
+                 compact_min: int = _COMPACT_MIN):
         if mode not in PEEL_MODES:
             raise ValueError(f"mode must be one of {PEEL_MODES}, got {mode!r}")
-        if support_mode not in support_mod.SUPPORT_MODES:
-            raise ValueError(
-                f"support_mode must be one of {support_mod.SUPPORT_MODES}, "
-                f"got {support_mode!r}")
         if table_mode not in support_mod.TABLE_MODES:
             raise ValueError(
                 f"table_mode must be one of {support_mod.TABLE_MODES}, "
@@ -460,7 +450,6 @@ class IncrementalTruss:
         if not 0.0 <= local_frac <= 1.0:
             raise ValueError("local_frac must be in [0, 1]")
         self.mode = mode
-        self.support_mode = support_mode
         self.table_mode = table_mode
         self.hier_mode = hier_mode
         self.insert_mode = insert_mode
@@ -471,8 +460,6 @@ class IncrementalTruss:
                       else wedge_common.next_pow2(chunk))
         self.local_frac = float(local_frac)
         self.host_peel_max = int(host_peel_max)
-        self.interpret = wedge_common.resolve_interpret(
-            interpret, peel_mode=mode, support_mode=support_mode)
         self.stats = {"updates": 0, "local": 0, "full": 0, "noop": 0,
                       "update_seconds": 0.0, "last": None}
         E, _, _, n_seen = canonical_edges_with_rows(edges)
@@ -545,11 +532,9 @@ class IncrementalTruss:
             raise ValueError(
                 f"mode must be one of {HIER_MODES}, got {mode!r}")
         if mode != self.hier_mode:
-            return TrussHierarchy(self.T, self.tri, mode=mode,
-                                  interpret=self.interpret)
+            return TrussHierarchy(self.T, self.tri, mode=mode)
         if self._hier is None:
-            self._hier = TrussHierarchy(self.T, self.tri, mode=mode,
-                                        interpret=self.interpret)
+            self._hier = TrussHierarchy(self.T, self.tri, mode=mode)
         return self._hier
 
     # ------------------------------------------------------------- update --
@@ -1028,7 +1013,7 @@ class IncrementalTruss:
         # keeps compacting as the region itself peels away
         S_fin = peel_live_subset(
             g.El, L, S0, ~in_A[L], chunk=self.chunk, mode=self.mode,
-            interpret=self.interpret, table_mode=self.table_mode,
+            table_mode=self.table_mode,
             compact_frac=self.compact_frac, compact_min=self.compact_min)
         return S_fin.astype(np.int64) + 2
 
@@ -1093,11 +1078,9 @@ class IncrementalTruss:
             r_edges = relabel(E, perm)
             gr = build_csr(r_edges, self.n)
             res = pkt(gr, chunk=self.chunk, mode=self.mode,
-                      support_mode=self.support_mode,
                       table_mode=self.table_mode,
                       compact_frac=self.compact_frac,
-                      compact_min=self.compact_min, interpret=self.interpret,
-                      phase_timings=True)
+                      compact_min=self.compact_min, phase_timings=True)
             #: phase breakdown of the most recent full (re)build — the open
             #: path's table-build vs support vs peel cost (benchmarks read it)
             self.open_phases = dict(res.phases or {})

@@ -1,21 +1,14 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""The degree-bucketed Pallas intersect kernel (validated in interpret mode
+on CPU), and the wedge-table machinery the XLA executors share.
 
-The paper's two custom-kernel-worthy hot spots are the support phase's
-oriented wedge-table scan (Alg. 3/AM4; support.py, plus the older
-degree-bucketed intersect.py/ops.py variant) and the peel phase's wedge-table
-SCAN (Alg. 5; peel.py). Both wedge-table kernels share their chunk layout,
-padding policy, and ranged-binary-search probe via wedge_common.py, and both
-fold their scatter on-chip into a VMEM-resident (m+1,) accumulator block
-(DESIGN.md §16). The LM stack deliberately stays pure-XLA so compiled
-cost_analysis stays honest for the roofline.
+``intersect.py``/``ops.py`` intersect oriented adjacency rows bucketed by
+degree class; ``ref.py`` is their pure-jnp oracle.  ``wedge_common.py``
+holds the chunk layout, padding policy and ranged-binary-search probe of
+the support and peel-table builders in ``core/``.
 """
 
 from repro.kernels.intersect import intersect_blocked
 from repro.kernels.ops import compute_support_kernel
-from repro.kernels.peel import peel_decrements, peel_decrement_fold
 from repro.kernels.ref import intersect_ref
-from repro.kernels.support import support_accumulate, support_counts
 
-__all__ = ["intersect_blocked", "compute_support_kernel", "intersect_ref",
-           "peel_decrements", "peel_decrement_fold",
-           "support_accumulate", "support_counts"]
+__all__ = ["intersect_blocked", "compute_support_kernel", "intersect_ref"]

@@ -13,12 +13,12 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.graphs.csr import CSRGraph
 from repro.core import support as support_mod
 from repro.kernels.intersect import intersect_blocked
-from repro.kernels.wedge_common import interpret_default as _interpret_default
 
 _DEG_CLASSES = (8, 16, 32, 64, 128, 256)
 
@@ -59,7 +59,7 @@ def compute_support_kernel(g: CSRGraph, *, interpret: bool | None = None,
     if g.m == 0:
         return np.zeros(0, np.int32)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = jax.default_backend() != "tpu"  # off the TPU
 
     u = g.El[:, 0].astype(np.int64)
     v = g.El[:, 1].astype(np.int64)

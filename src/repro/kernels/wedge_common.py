@@ -1,25 +1,21 @@
-"""Shared wedge-table machinery for the truss kernels and table builders.
+"""Shared wedge-table machinery for the truss table builders and executors.
 
 A *wedge table* has one row per (anchor edge, candidate adjacency slot)
 pair, with a probe range ``[lo, hi)`` into the CSR adjacency array ``N``.
-The support kernel (``kernels/support.py``) walks the oriented AM4 table
-and probes every row.  The peel's full-adjacency ProcessSubLevel table is
+The support executor (``core.support``) walks the oriented AM4 table and
+probes every row.  The peel's full-adjacency ProcessSubLevel table is
 probed once, by its builder (``core.support.peel_rows`` and the host
 ``resolve_peel_table``), which keeps the hits as resolved triangle rows
 ``(e1, e2, e3)`` with the sentinel ``m`` in all three columns on padding
-rows; the peel kernel (``kernels/peel.py``) and the jnp peel executors walk
-those rows and never search.  This module is the single home of the table
-math they share:
+rows; the peel executors walk those rows and never search.  This module is
+the single home of the table math they share:
 
-  * **chunk layout** — tables are cut into fixed-size chunks, one per Pallas
-    grid step; ``chunk_layout`` sanitizes a requested chunk size (clamped so
-    that ``n_chunks >= 1`` always holds, including zero-entry tables) and
-    ``pad_chunked`` pads the four wedge-table arrays to a whole number of
-    chunks with inert sentinel rows (anchor ``m``, empty probe range
-    ``lo == hi``);
+  * **chunk layout** — tables are cut into fixed-size chunks, the unit the
+    chunk-skipping peel visits; ``chunk_layout`` sanitizes a requested chunk
+    size (clamped so that ``n_chunks >= 1`` always holds, including
+    zero-entry tables) and ``pow2_chunk`` picks one for a pow2-padded table;
   * **BlockSpec helpers** — ``chunk_spec`` stages one chunk per grid step,
-    ``replicated_spec`` replicates a whole array (adjacency, edge state)
-    into VMEM at every step;
+    ``replicated_spec`` replicates a whole array into VMEM at every step;
   * **the search primitive** — ``ranged_searchsorted`` is the branch-free
     vectorized lower-bound binary search every membership test uses, and
     ``probe`` fuses it with the candidate gather and hit predicate
@@ -48,37 +44,6 @@ from jax.experimental import pallas as pl
 PAD_N = np.int32(1 << 30)
 
 
-def interpret_default() -> bool:
-    """Pallas interpret mode unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
-
-
-def resolve_interpret(interpret: bool | None, *, peel_mode: str = "chunked",
-                      support_mode: str = "jnp") -> bool:
-    """The interpret flag for an entry point; refuses Pallas on a TPU.
-
-    Neither Pallas kernel lowers for the TPU yet: Mosaic refuses their
-    rank-1 blocks, 1-D gathers and in-kernel scatter-adds (ROADMAP Speed
-    2).  On a TPU backend a Pallas executor, or interpret mode, is refused
-    here with ``NotImplementedError`` — before any work starts, instead of
-    failing inside Mosaic mid-run or running the interpreter on the chip.
-    Off the TPU ``interpret`` defaults to True, as before.
-    """
-    if interpret_default():
-        return True if interpret is None else bool(interpret)
-    refused = [f"{axis}='pallas'" for axis, mode in
-               (("mode", peel_mode), ("support_mode", support_mode))
-               if mode == "pallas"]
-    if interpret:
-        refused.append("interpret=True")
-    if refused:
-        raise NotImplementedError(
-            f"{', '.join(refused)} is not supported on a TPU backend: the "
-            f"Pallas kernels do not lower for TPU yet (ROADMAP Speed 2); use "
-            f"the default XLA executors (mode='chunked', support_mode='jnp')")
-    return False
-
-
 def next_pow2(x: int) -> int:
     """Smallest power of two >= x (>= 1)."""
     return 1 << max(0, int(x - 1).bit_length())
@@ -87,8 +52,8 @@ def next_pow2(x: int) -> int:
 #: auto-chunk policy: aim for this many chunks per wedge table, so that the
 #: chunk-skipping while_loop has skippable units even on small graphs …
 AUTO_CHUNK_TARGET = 16
-#: … clamped to this band (below: per-chunk dispatch overhead dominates;
-#: above: a chunk's VMEM block outgrows the kernel budget)
+#: … clamped to this band (below: per-chunk loop overhead dominates;
+#: above: a chunk is too coarse for the frontier to skip much of it)
 AUTO_CHUNK_MIN = 1 << 7
 AUTO_CHUNK_MAX = 1 << 14
 
@@ -150,7 +115,7 @@ def auto_chunk(size: int, *, target: int = AUTO_CHUNK_TARGET,
     scanned the whole table every sub-level while still paying the
     while_loop machinery — the chunked-slower-than-dense pathology
     BENCH_smoke.json showed on tiny graphs.  Large tables still get the
-    VMEM-budget chunk ``hi``.
+    cap ``hi``.
     """
     global _TUNED_CHUNKS
     size = max(1, int(size))
@@ -193,7 +158,7 @@ def chunk_layout(size: int, chunk: int | None = None) -> tuple[int, int]:
     Returns ``(chunk, n_chunks)`` with ``1 <= chunk`` and ``n_chunks >= 1``:
     a chunk larger than the table, zero, or negative is clamped; a zero-entry
     table yields one all-padding chunk of size 1 (callers that want to skip
-    the kernel entirely for empty tables early-exit before this).
+    the peel entirely for empty tables early-exit before this).
     ``chunk=None`` derives the size from the table via ``auto_chunk``.
     """
     size = max(1, int(size))
@@ -201,27 +166,6 @@ def chunk_layout(size: int, chunk: int | None = None) -> tuple[int, int]:
         chunk = auto_chunk(size)
     chunk = max(1, min(int(chunk), size))
     return chunk, -(-size // chunk)
-
-
-def pad_chunked(e1: np.ndarray, cand_slot: np.ndarray, lo: np.ndarray,
-                hi: np.ndarray, *, m: int, chunk: int,
-                n_chunks: int) -> tuple[np.ndarray, np.ndarray, np.ndarray,
-                                        np.ndarray]:
-    """Pad the four wedge-table arrays to ``n_chunks * chunk`` inert rows.
-
-    Padding rows carry the anchor sentinel ``m`` and an empty probe range
-    (``lo == hi == 0``), so they can never produce a hit and any scatter
-    they feed lands on the absorbing slot ``m``.
-    """
-    nw = int(e1.shape[0])
-    pad = n_chunks * chunk - nw
-    assert pad >= 0, (nw, chunk, n_chunks)
-    return (
-        np.concatenate([e1, np.full(pad, m, np.int32)]).astype(np.int32),
-        np.concatenate([cand_slot, np.zeros(pad, np.int32)]).astype(np.int32),
-        np.concatenate([lo, np.zeros(pad, np.int32)]).astype(np.int32),
-        np.concatenate([hi, np.zeros(pad, np.int32)]).astype(np.int32),
-    )
 
 
 def chunk_spec(chunk: int) -> pl.BlockSpec:
@@ -292,8 +236,7 @@ def probe(N: jnp.ndarray, cand_slot: jnp.ndarray, lo: jnp.ndarray,
     Returns ``(hit, safe)`` where ``safe`` is the (clamped) index of the
     matching slot — valid as a gather index whenever ``hit`` is True, and a
     harmless in-bounds index otherwise.  This is the inner loop of the
-    support kernel and executors, and the one probe of the peel-table
-    builder.
+    support executor, and the one probe of the peel-table builder.
     """
     w = N[cand_slot]
     idx = ranged_searchsorted(N, w, lo, hi, iters)

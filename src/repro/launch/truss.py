@@ -55,7 +55,6 @@ import numpy as np
 from repro.compile_cache import enable_compile_cache
 from repro.graphs.datasets import named_graph
 from repro.graphs.csr import build_csr, relabel, degeneracy_order
-from repro.kernels.wedge_common import resolve_interpret
 from repro.core import (pkt, truss_wc, truss_ros, truss_trilist, truss_numpy,
                         pkt_dist)
 
@@ -189,16 +188,14 @@ def run_update_stream(args) -> None:
 
     E = named_graph(args.graph)
     n = int(E.max()) + 1
-    eng = TrussEngine(mode=args.mode, support_mode=args.support_mode,
-                      table_mode=args.table_mode, hier_mode=args.hier_mode,
-                      insert_mode=args.insert_mode,
+    eng = TrussEngine(mode=args.mode, table_mode=args.table_mode,
+                      hier_mode=args.hier_mode, insert_mode=args.insert_mode,
                       chunk=args.chunk)
     t0 = time.perf_counter()
     h = eng.open(E, local_frac=args.local_frac)
     t_open = time.perf_counter() - t0
     print(f"graph={args.graph} n={n} m={h.m} open {t_open:.3f}s "
-          f"mode={args.mode} sup={args.support_mode} "
-          f"insert={args.insert_mode}")
+          f"mode={args.mode} insert={args.insert_mode}")
     if args.query_communities:
         # build the index up front so the stream exercises its survival
         # (local repairs remap untouched levels, dirty the rest)
@@ -361,9 +358,8 @@ def run_serve(args) -> None:
 
     E = named_graph(args.graph)
     n = int(E.max()) + 1
-    engine_kwargs = dict(mode=args.mode, support_mode=args.support_mode,
-                         table_mode=args.table_mode, hier_mode=args.hier_mode,
-                         chunk=args.chunk)
+    engine_kwargs = dict(mode=args.mode, table_mode=args.table_mode,
+                         hier_mode=args.hier_mode, chunk=args.chunk)
     # a replay measures latency, not shedding: admit the whole schedule
     sched = TrussScheduler(
         max_batch=args.max_batch, max_delay_ms=args.max_delay_ms,
@@ -435,9 +431,8 @@ def run_query_communities(args) -> None:
     from repro.serve.truss_engine import TrussEngine
 
     E = named_graph(args.graph)
-    eng = TrussEngine(mode=args.mode, support_mode=args.support_mode,
-                      table_mode=args.table_mode, hier_mode=args.hier_mode,
-                      chunk=args.chunk)
+    eng = TrussEngine(mode=args.mode, table_mode=args.table_mode,
+                      hier_mode=args.hier_mode, chunk=args.chunk)
     t0 = time.perf_counter()
     h = eng.open(E)
     t_open = time.perf_counter() - t0
@@ -466,10 +461,8 @@ def main(argv=None):
                     help="peel chunk size (default: derived from the table "
                          "size, see kernels.wedge_common.auto_chunk)")
     from repro.core.pkt import PEEL_MODES
-    from repro.core.support import SUPPORT_MODES, TABLE_MODES
+    from repro.core.support import TABLE_MODES
     ap.add_argument("--mode", default="chunked", choices=list(PEEL_MODES))
-    ap.add_argument("--support-mode", default="jnp",
-                    choices=list(SUPPORT_MODES))
     ap.add_argument("--table-mode", default="device",
                     choices=list(TABLE_MODES),
                     help="where wedge tables are built: jitted XLA on "
@@ -522,9 +515,6 @@ def main(argv=None):
                     help="per-request deadline for --serve; expired "
                          "requests fail with a typed DeadlineExceeded")
     args = ap.parse_args(argv)
-    # refuse Pallas executors on a TPU before any graph is built
-    resolve_interpret(None, peel_mode=args.mode,
-                      support_mode=args.support_mode)
     enable_compile_cache()
 
     if args.serve:
@@ -547,16 +537,13 @@ def main(argv=None):
     t0 = time.perf_counter()
     if args.engine == "pkt":
         res = pkt(g, chunk=args.chunk, mode=args.mode,
-                  support_mode=args.support_mode,
                   table_mode=args.table_mode,
                   compact_frac=args.compact_frac or None)
         truss = res.trussness
         extra = (f"levels={res.levels} sublevels={res.sublevels} "
                  f"compactions={res.compactions}")
     elif args.engine == "dist":
-        truss = pkt_dist(g, chunk=args.chunk,
-                         support_mode=args.support_mode,
-                         table_mode=args.table_mode)
+        truss = pkt_dist(g, chunk=args.chunk, table_mode=args.table_mode)
         extra = ""
     elif args.engine == "trilist":
         truss = truss_trilist(g)

@@ -12,10 +12,11 @@ top of the engine's existing exception safety:
   :class:`DeadlineExceeded` are never retried;
 - a per-site **degradation ladder** (:class:`Ladder`): consecutive
   failures demote the site to a slower but bitwise-identical executor
-  rung (pallas → jnp → host-numpy); after enough consecutive successes
-  at a demoted rung the ladder *probes* the faster rung on live
-  traffic — probe failures fall back silently without charging the
-  request — and re-promotes after consecutive probe successes.  Every
+  rung (dense → chunked → host-numpy for a flush); after enough
+  consecutive successes at a demoted rung the ladder *probes* the faster
+  rung on live traffic — probe failures fall back silently without
+  charging the request — and re-promotes after consecutive probe
+  successes.  Every
   demotion logs a WARNING naming the site, the new rung and the
   exception that caused it, so a fault never hides behind a slower rung;
 - **deadline enforcement**: an absolute deadline aborts the retry loop
@@ -196,8 +197,8 @@ def override_attrs(obj, **attrs):
     """Temporarily set attributes on ``obj``, restoring on exit.
 
     The mechanism by which ladder rungs are applied: executor-mode
-    attributes (``mode``, ``support_mode``, ``table_mode``,
-    ``host_peel_max``) are overridden for the duration of one dispatch.
+    attributes (``mode``, ``table_mode``, ``host_peel_max``) are
+    overridden for the duration of one dispatch.
     """
     saved = {k: getattr(obj, k) for k in attrs}
     for k, v in attrs.items():

@@ -100,8 +100,7 @@ _REGION_OVERRIDES = {
 #: ladder overrides for the support-build site (open / full rebuild)
 _SUPPORT_OVERRIDES = {
     "default": {},
-    "jnp": {"support_mode": "jnp"},
-    "numpy": {"support_mode": "jnp", "table_mode": "numpy"},
+    "numpy": {"table_mode": "numpy"},
 }
 
 
@@ -239,7 +238,7 @@ class TrussScheduler:
             requests queued until :meth:`start` (tests use this to stage
             traffic deterministically).
         **engine_kwargs: forwarded to :class:`TrussEngine` when ``engine``
-            is ``None`` (``mode``, ``support_mode``, ``table_mode``, …).
+            is ``None`` (``mode``, ``table_mode``, ``hier_mode``, …).
 
     Raises:
         ValueError: non-positive ``max_batch``/``max_queue``/
@@ -325,22 +324,20 @@ class TrussScheduler:
     def _build_ladders(self, opts: dict) -> dict[str, Ladder]:
         """Per-site degradation ladders from the engine's configured modes.
 
-        Every rung pairing is one of the repo's parity-gated executor
-        axes, so demotion changes latency, never results; rungs equal to
-        the configured executor are deduplicated away.
+        Every rung is one of the repo's parity-gated executor modes, so
+        demotion changes latency, never results; rungs equal to the
+        configured executor are deduplicated away.
         """
         e = self.engine
-        flush = [f"{e.mode}+{e.support_mode}"]
-        if (e.mode, e.support_mode) != ("chunked", "jnp"):
-            flush.append("chunked+jnp")
+        flush = [e.mode]
+        if e.mode != "chunked":
+            flush.append("chunked")
         flush.append("host")
         region = ["default"]
         if e.mode != "chunked":
             region.append("chunked")
         region.append("host")
         support = ["default"]
-        if e.support_mode != "jnp":
-            support.append("jnp")
         if e.table_mode != "numpy":
             support.append("numpy")
         hier = ["default"]
@@ -956,9 +953,9 @@ class TrussScheduler:
     def _resilient_open(self, req: _Request) -> TrussHandle:
         """Open under the support-site ladder (engine attrs overridden).
 
-        A demoted rung builds the handle with fallback support executors;
-        the handle's own attributes are then reset to the engine defaults
-        so it is not permanently demoted.
+        A demoted rung builds the handle with the host table builder; the
+        handle's own attribute is then reset to the engine default so it is
+        not permanently demoted.
         """
         def call(rungs):
             ov = _SUPPORT_OVERRIDES[rungs["support"]]
@@ -969,7 +966,6 @@ class TrussScheduler:
             call, ladders={"support": self._ladders["support"]},
             primary="support", policy=self.retry, deadline=req.t_deadline,
             kind="open", on_retry=self._count_retry)
-        h._inc.support_mode = self.engine.support_mode  # noqa: SLF001
         h._inc.table_mode = self.engine.table_mode      # noqa: SLF001
         return h
 
@@ -1099,8 +1095,8 @@ class TrussScheduler:
         """Flush every due bucket: full, past deadline, or forced (drain).
 
         Each bucket flush runs under the flush-site ladder: retries stay
-        on the engine's configured executors, demotion falls back to the
-        ``chunked+jnp`` pair and finally to the host-numpy reference —
+        on the engine's configured peel mode, demotion falls back to the
+        ``chunked`` peel and finally to the host-numpy reference —
         all bitwise-identical.  Requests already past their deadline are
         rejected before the dispatch (and their tickets discarded);
         read-only submits are deadline-checked again at delivery.
@@ -1133,8 +1129,7 @@ class TrussScheduler:
                 if rung == "host":
                     self.engine.flush_host(only=[key])
                 else:
-                    m, sm = rung.split("+")
-                    self.engine.flush(only=[key], mode=m, support_mode=sm)
+                    self.engine.flush(only=[key], mode=rung)
             try:
                 run_with_resilience(
                     flush, ladders={"flush": self._ladders["flush"]},

@@ -36,10 +36,8 @@ Usage:
     trussness_b = eng.result(t2)      # flushes pending work once
     trussness_a = eng.result(t1)      # already computed
 
-``mode`` selects the peel executor and ``support_mode`` the support executor
-exactly as in ``core.pkt.pkt`` — the kernel paths vmap too: Pallas grids
-gain a leading batch dimension, so one bucket dispatch lowers each kernel
-once for the whole batch.  Submissions larger than ``max_edges`` canonical
+``mode`` selects the peel executor exactly as in ``core.pkt.pkt``.
+Submissions larger than ``max_edges`` canonical
 edges are rejected at ``submit`` time with a clear error (the padded
 operands of an oversized graph would otherwise compile a bucket no steady
 workload ever reuses, and can exhaust device memory).
@@ -84,8 +82,6 @@ class SizeClass(NamedTuple):
     chunk: int        # peel chunk size (pow2, <= peel_pad)
     n_chunks: int     # peel_pad // chunk
     iters: int        # binary-search iteration bound for 2*m_pad-length rows
-    sup_chunk: int    # support-kernel chunk size (pow2, <= sup_pad)
-    sup_n_chunks: int  # sup_pad // sup_chunk
     n_pad: int        # padded vertex count (pow2; 0 in table_mode="numpy",
     #                   whose operands carry no vertex-indexed arrays)
 
@@ -135,26 +131,14 @@ class CSROperand(NamedTuple):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "iters", "mode",
-                     "support_mode", "sup_chunk", "sup_n_chunks",
-                     "interpret"),
+    static_argnames=("m", "chunk", "n_chunks", "iters", "mode"),
 )
 def _batched_truss(ops: BatchOperand, *, m: int, chunk: int, n_chunks: int,
-                   iters: int, mode: str, support_mode: str, sup_chunk: int,
-                   sup_n_chunks: int, interpret: bool):
+                   iters: int, mode: str):
     """vmap of (support → peel) across one bucket of padded graphs."""
     def one(op: BatchOperand):
-        if support_mode == "pallas":
-            from repro.kernels.support import support_accumulate
-
-            S_acc, _ = support_accumulate(
-                op.s_e1, op.s_cand, op.s_lo, op.s_hi, op.N, op.Eid,
-                chunk=sup_chunk, n_chunks=sup_n_chunks, iters=iters, m=m,
-                interpret=interpret)
-            S0 = S_acc[:m]
-        else:
-            S0 = support_mod._support_jit(
-                op.N, op.Eid, op.s_e1, op.s_cand, op.s_lo, op.s_hi, iters, m)
+        S0 = support_mod._support_jit(
+            op.N, op.Eid, op.s_e1, op.s_cand, op.s_lo, op.s_hi, iters, m)
         edge_ok = jnp.arange(m + 1, dtype=jnp.int32) < op.m_real
         S_ext0 = jnp.where(
             edge_ok,
@@ -165,7 +149,7 @@ def _batched_truss(ops: BatchOperand, *, m: int, chunk: int, n_chunks: int,
                           op.c_start, op.c_end, op.has_entries)
         S_ext, _, levels, subs, _ = _peel_loop(
             S_ext0, processed0, tabs, m=m, chunk=chunk, n_chunks=n_chunks,
-            mode=mode, interpret=interpret)
+            mode=mode)
         return S_ext[:m], S0, levels, subs
 
     return jax.vmap(one)(ops)
@@ -173,14 +157,11 @@ def _batched_truss(ops: BatchOperand, *, m: int, chunk: int, n_chunks: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "chunk", "n_chunks", "iters", "mode",
-                     "support_mode", "sup_chunk", "sup_n_chunks", "sup_pad",
-                     "peel_pad", "interpret"),
+    static_argnames=("m", "chunk", "n_chunks", "iters", "mode", "sup_pad",
+                     "peel_pad"),
 )
 def _batched_truss_dev(ops: CSROperand, *, m: int, chunk: int, n_chunks: int,
-                       iters: int, mode: str, support_mode: str,
-                       sup_chunk: int, sup_n_chunks: int, sup_pad: int,
-                       peel_pad: int, interpret: bool):
+                       iters: int, mode: str, sup_pad: int, peel_pad: int):
     """vmap of (build tables → support → peel) across one bucket of graphs.
 
     The ``table_mode="device"`` pipeline: both wedge tables are built by the
@@ -191,10 +172,8 @@ def _batched_truss_dev(ops: CSROperand, *, m: int, chunk: int, n_chunks: int,
     def one(op: CSROperand):
         s_e1, s_cand, s_lo, s_hi, _ = support_mod._build_support_table_dev(
             op.u, op.v, op.Es, op.Eo, op.m_real, m=m, size=sup_pad)
-        S0 = support_mod.support_from_table_arrays(
-            s_e1, s_cand, s_lo, s_hi, op.N, op.Eid, m=m, mode=support_mode,
-            chunk=sup_chunk, n_chunks=sup_n_chunks, iters=iters,
-            interpret=interpret)
+        S0 = support_mod._support_jit(
+            op.N, op.Eid, s_e1, s_cand, s_lo, s_hi, iters, m)
         *cols, _hits = support_mod._build_peel_table_dev(
             op.u, op.v, op.Es, op.N, op.Eid, op.m_real, m=m, size=peel_pad,
             chunk=chunk, iters=iters)
@@ -206,7 +185,7 @@ def _batched_truss_dev(ops: CSROperand, *, m: int, chunk: int, n_chunks: int,
         processed0 = ~edge_ok
         S_ext, _, levels, subs, _ = _peel_loop(
             S_ext0, processed0, PeelTables(*cols), m=m, chunk=chunk,
-            n_chunks=n_chunks, mode=mode, interpret=interpret)
+            n_chunks=n_chunks, mode=mode)
         return S_ext[:m], S0, levels, subs
 
     return jax.vmap(one)(ops)
@@ -354,7 +333,6 @@ class TrussEngine:
 
     Args:
         mode: peel executor for every decomposition (see ``core.pkt.pkt``).
-        support_mode: support executor (same axes as ``pkt``).
         table_mode: wedge-table builder — "device" ships CSR-only operands
             and builds both tables inside the batched jit (§10); "numpy" is
             the host parity oracle.
@@ -369,27 +347,18 @@ class TrussEngine:
         max_pending: auto-flush threshold — ``submit`` triggers a full
             ``flush`` once this many submissions are queued.
         max_edges: reject submissions beyond this many canonical edges.
-        interpret: force/forbid Pallas interpret mode (default: interpret
-            when not on a TPU).
 
     Raises:
         ValueError: unknown mode axis, or non-positive ``chunk`` /
             ``max_edges``.
-        NotImplementedError: a Pallas executor or ``interpret=True`` on a
-            TPU backend (``kernels.wedge_common.resolve_interpret``).
     """
 
-    def __init__(self, *, mode: str = "chunked", support_mode: str = "jnp",
-                 table_mode: str = "device", hier_mode: str = "device",
-                 insert_mode: str = "batched", chunk: int | None = None,
-                 reorder: bool = True, max_pending: int = 32,
-                 max_edges: int = 1 << 22, interpret: bool | None = None):
+    def __init__(self, *, mode: str = "chunked", table_mode: str = "device",
+                 hier_mode: str = "device", insert_mode: str = "batched",
+                 chunk: int | None = None, reorder: bool = True,
+                 max_pending: int = 32, max_edges: int = 1 << 22):
         if mode not in PEEL_MODES:
             raise ValueError(f"mode must be one of {PEEL_MODES}, got {mode!r}")
-        if support_mode not in support_mod.SUPPORT_MODES:
-            raise ValueError(f"support_mode must be one of "
-                             f"{support_mod.SUPPORT_MODES}, "
-                             f"got {support_mode!r}")
         if table_mode not in support_mod.TABLE_MODES:
             raise ValueError(f"table_mode must be one of "
                              f"{support_mod.TABLE_MODES}, got {table_mode!r}")
@@ -404,7 +373,6 @@ class TrussEngine:
         if max_edges < 1:
             raise ValueError("max_edges must be positive")
         self.mode = mode
-        self.support_mode = support_mode
         self.table_mode = table_mode
         self.hier_mode = hier_mode
         self.insert_mode = insert_mode
@@ -412,8 +380,6 @@ class TrussEngine:
         self.chunk = None if chunk is None else _next_pow2(chunk)
         self.reorder = reorder
         self.max_pending = max_pending
-        self.interpret = wedge_common.resolve_interpret(
-            interpret, peel_mode=mode, support_mode=support_mode)
         self._pending: list[_Pending] = []
         self._results: dict[int, np.ndarray] = {}
         self._next_ticket = 0
@@ -525,12 +491,11 @@ class TrussEngine:
         for this handle (``None``: engine default, §13).
         """
         inc = IncrementalTruss(
-            edges, mode=self.mode, support_mode=self.support_mode,
-            table_mode=self.table_mode, hier_mode=self.hier_mode,
+            edges, mode=self.mode, table_mode=self.table_mode,
+            hier_mode=self.hier_mode,
             insert_mode=(self.insert_mode if insert_mode is None
                          else insert_mode),
-            chunk=self.chunk, local_frac=local_frac,
-            interpret=self.interpret)
+            chunk=self.chunk, local_frac=local_frac)
         h = TrussHandle(self._next_handle, inc)
         self._next_handle += 1
         self._handles[h.hid] = h
@@ -636,10 +601,9 @@ class TrussEngine:
         chunk = wedge_common.pow2_chunk(peel_pad, self.chunk)
         n_chunks = peel_pad // chunk
         iters = int(np.ceil(np.log2(2 * m_pad + 1))) + 1
-        sup_chunk = wedge_common.pow2_chunk(sup_pad, self.chunk)
         n_pad = _next_pow2(g.n + 1) if self.table_mode == "device" else 0
         return SizeClass(m_pad, sup_pad, peel_pad, chunk, n_chunks, iters,
-                         sup_chunk, sup_pad // sup_chunk, n_pad)
+                         n_pad)
 
     def _make_csr_operand(self, g: CSRGraph, key: SizeClass) -> CSROperand:
         m_pad = key.m_pad
@@ -703,8 +667,7 @@ class TrussEngine:
                 return p.key
         return None
 
-    def flush(self, only=None, *, mode: str | None = None,
-              support_mode: str | None = None) -> None:
+    def flush(self, only=None, *, mode: str | None = None) -> None:
         """Decompose pending graphs, bucket by bucket.
 
         Args:
@@ -715,7 +678,6 @@ class TrussEngine:
                 configured mode) — the resilience layer's degradation-
                 ladder hook (DESIGN.md §15); results are bitwise-identical
                 across modes.
-            support_mode: per-call support-executor override, same contract.
 
         Ordering contract: each bucket's results are materialized (and its
         submissions removed from the pending queue) only after its batched
@@ -729,15 +691,9 @@ class TrussEngine:
         ``tests/test_truss_engine.py``).
         """
         eff_mode = self.mode if mode is None else mode
-        eff_support = self.support_mode if support_mode is None \
-            else support_mode
         if eff_mode not in PEEL_MODES:
             raise ValueError(
                 f"mode must be one of {PEEL_MODES}, got {eff_mode!r}")
-        if eff_support not in support_mod.SUPPORT_MODES:
-            raise ValueError(
-                f"support_mode must be one of {support_mod.SUPPORT_MODES}, "
-                f"got {eff_support!r}")
         if not self._pending:
             return
         by_key: dict[SizeClass, list[_Pending]] = {}
@@ -758,15 +714,11 @@ class TrussEngine:
                 S, S0, levels, subs = _batched_truss_dev(
                     ops, m=key.m_pad, chunk=key.chunk,
                     n_chunks=key.n_chunks, iters=key.iters, mode=eff_mode,
-                    support_mode=eff_support, sup_chunk=key.sup_chunk,
-                    sup_n_chunks=key.sup_n_chunks, sup_pad=key.sup_pad,
-                    peel_pad=key.peel_pad, interpret=self.interpret)
+                    sup_pad=key.sup_pad, peel_pad=key.peel_pad)
             else:
                 S, S0, levels, subs = _batched_truss(
                     ops, m=key.m_pad, chunk=key.chunk, n_chunks=key.n_chunks,
-                    iters=key.iters, mode=eff_mode,
-                    support_mode=eff_support, sup_chunk=key.sup_chunk,
-                    sup_n_chunks=key.sup_n_chunks, interpret=self.interpret)
+                    iters=key.iters, mode=eff_mode)
             S = np.asarray(S)
             for i, p in enumerate(group):
                 truss = (S[i][: p.g.m] + 2).astype(np.int64)
@@ -837,12 +789,10 @@ class TrussEngine:
 
 
 def truss_batched(graphs, *, mode: str = "chunked",
-                  support_mode: str = "jnp", table_mode: str = "device",
-                  chunk: int | None = None,
+                  table_mode: str = "device", chunk: int | None = None,
                   reorder: bool = True) -> list[np.ndarray]:
     """One-shot convenience: decompose a list of edge arrays, order-aligned."""
     graphs = list(graphs)
-    eng = TrussEngine(mode=mode, support_mode=support_mode,
-                      table_mode=table_mode, chunk=chunk,
+    eng = TrussEngine(mode=mode, table_mode=table_mode, chunk=chunk,
                       reorder=reorder, max_pending=len(graphs) or 1)
     return eng.map(graphs)

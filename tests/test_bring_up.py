@@ -1,9 +1,9 @@
 """What running on the chip relies on, checked on the CPU.
 
-* the TPU guard: with the backend query patched to report a TPU, every
-  entry point refuses the Pallas executors and interpret mode up front
-  (``wedge_common.resolve_interpret``), while the default executors still
-  run;
+* every entry point validates each executor option it takes and refuses
+  an unknown executor (``"pallas"`` among them) with a ``ValueError``
+  naming the option, and the CLI rejects the removed executor flags;
+  with the backend reported as a TPU the default executors still run;
 * ``benchmarks/run.py`` exits non-zero when a bench raises or writes an
   ``ERROR`` row, and ``table4`` refuses to spawn JAX children off the CPU;
 * the compile-cache helper honours ``JAX_COMPILATION_CACHE_DIR`` and
@@ -24,7 +24,6 @@ import pytest
 
 from repro.graphs.csr import build_csr
 from repro.graphs.datasets import named_graph
-from repro.kernels import wedge_common
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
@@ -33,8 +32,11 @@ if str(ROOT) not in sys.path:
 
 @pytest.fixture
 def on_tpu(monkeypatch):
-    """Make the guard see a TPU backend (the computation stays on the CPU)."""
-    monkeypatch.setattr(wedge_common, "interpret_default", lambda: False)
+    """Report a TPU backend to the program (the computation stays on the
+    CPU), so no entry point can branch on the backend's name."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 def _graph():
@@ -50,32 +52,31 @@ def _entry_points():
     return {
         "pkt": lambda **kw: pkt(_graph(), **kw),
         "truss_pkt": lambda **kw: truss_pkt(E, **kw),
-        "compute_support": lambda mode="jnp", support_mode="jnp", **kw:
-            compute_support(_graph(), mode=support_mode, **kw),
-        "pkt_dist": lambda mode="chunked", **kw: pkt_dist(_graph(), **kw),
+        "compute_support": lambda **kw: compute_support(_graph(), **kw),
+        "pkt_dist": lambda **kw: pkt_dist(_graph(), **kw),
         "IncrementalTruss": lambda **kw: IncrementalTruss(E, **kw),
         "TrussEngine": lambda **kw: TrussEngine(**kw),
         "TrussScheduler": lambda **kw: TrussScheduler(start=False, **kw),
     }
 
 
-_REFUSED = {"pkt": ["mode", "support_mode", "interpret"],
-            "truss_pkt": ["mode", "support_mode"],
-            "compute_support": ["support_mode", "interpret"],
-            "pkt_dist": ["support_mode", "interpret"],
-            "IncrementalTruss": ["mode", "support_mode", "interpret"],
-            "TrussEngine": ["mode", "support_mode", "interpret"],
-            "TrussScheduler": ["mode", "support_mode", "interpret"]}
-_OPTION = {"mode": {"mode": "pallas"}, "support_mode": {"support_mode":
-                                                        "pallas"},
-           "interpret": {"interpret": True}}
+#: every executor option each entry point takes
+_EXECUTOR_AXES = {
+    "pkt": ["mode", "table_mode"],
+    "truss_pkt": ["mode", "table_mode"],
+    "compute_support": ["table_mode"],
+    "pkt_dist": ["table_mode"],
+    "IncrementalTruss": ["mode", "table_mode", "hier_mode", "insert_mode"],
+    "TrussEngine": ["mode", "table_mode", "hier_mode", "insert_mode"],
+    "TrussScheduler": ["mode", "table_mode", "hier_mode", "insert_mode"],
+}
 
 
-@pytest.mark.parametrize("entry,option", [
-    (e, o) for e, opts in _REFUSED.items() for o in opts])
-def test_tpu_refuses_pallas_and_interpret(on_tpu, entry, option):
-    with pytest.raises(NotImplementedError, match="ROADMAP Speed 2"):
-        _entry_points()[entry](**_OPTION[option])
+@pytest.mark.parametrize("entry,axis", [
+    (e, a) for e, axes in _EXECUTOR_AXES.items() for a in axes])
+def test_entry_points_refuse_unknown_executor(entry, axis):
+    with pytest.raises(ValueError, match=rf"^{axis} must be one of"):
+        _entry_points()[entry](**{axis: "pallas"})
 
 
 def test_tpu_default_executors_still_run(on_tpu):
@@ -86,18 +87,16 @@ def test_tpu_default_executors_still_run(on_tpu):
                           truss_numpy(_graph().El))
 
 
-def test_cli_refuses_pallas_on_tpu(on_tpu, monkeypatch):
+@pytest.mark.parametrize("flags", [["--mode", "pallas"],
+                                   ["--support-mode", "jnp"]],
+                         ids=["mode-pallas", "support-mode"])
+def test_cli_rejects_removed_executor_flags(flags, monkeypatch):
     from repro.launch import truss as cli
 
     monkeypatch.setattr(cli, "enable_compile_cache", lambda: None)
-    with pytest.raises(NotImplementedError):
-        cli.main(["--graph", "triangle", "--mode", "pallas"])
-
-
-def test_guard_is_inert_off_tpu():
-    assert wedge_common.resolve_interpret(None, peel_mode="pallas",
-                                          support_mode="pallas") is True
-    assert wedge_common.resolve_interpret(False) is False
+    with pytest.raises(SystemExit) as exc:
+        cli.main(["--graph", "triangle", *flags])
+    assert exc.value.code == 2
 
 
 # ---- benchmarks/run.py ------------------------------------------------------
@@ -191,7 +190,7 @@ def test_importing_entry_points_initialises_no_backend():
 def test_ladder_demotion_logs_a_warning(caplog):
     from repro.serve.resilience import Ladder, RetryPolicy, run_with_resilience
 
-    ladder = Ladder(("chunked+jnp", "host"), demote_after=1)
+    ladder = Ladder(("chunked", "host"), demote_after=1)
     calls = []
 
     def call(rungs):
@@ -205,7 +204,7 @@ def test_ladder_demotion_logs_a_warning(caplog):
             call, ladders={"flush": ladder}, primary="flush",
             policy=RetryPolicy(max_retries=1, base_delay_s=0.0,
                                max_delay_s=0.0))
-    assert out == "done" and calls == ["chunked+jnp", "host"]
+    assert out == "done" and calls == ["chunked", "host"]
     (rec,) = [r for r in caplog.records if r.levelno == logging.WARNING]
     msg = rec.getMessage()
     assert "flush" in msg and "'host'" in msg and "dispatch lost" in msg

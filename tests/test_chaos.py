@@ -52,8 +52,8 @@ def test_fault_plan_times_rules_fire_exactly_n_times():
     with plan:
         for _ in range(2):
             with pytest.raises(InjectedFault) as ei:
-                fault_point("flush", rung="pallas")
-            assert ei.value.site == "flush" and ei.value.rung == "pallas"
+                fault_point("flush", rung="dense")
+            assert ei.value.site == "flush" and ei.value.rung == "dense"
         assert fault_point("flush") is None         # rule exhausted
     st = plan.stats()
     assert st["calls"]["flush"] == 3 and st["injected"]["flush"] == 2
@@ -78,13 +78,13 @@ def test_fault_plan_rate_rules_are_seed_deterministic():
 
 def test_fault_plan_rung_filter_and_modes():
     plan = (FaultPlan()
-            .add("flush", rung="pallas", times=5)
+            .add("flush", rung="dense", times=5)
             .add("support", mode="corrupt", times=1)
             .add("region", mode="delay", delay_s=0.05, times=1))
     with plan:
         assert fault_point("flush", rung="chunked") is None  # filtered out
         with pytest.raises(InjectedFault):
-            fault_point("flush", rung="pallas")
+            fault_point("flush", rung="dense")
         assert fault_point("support") == "corrupt"
         t0 = time.perf_counter()
         assert fault_point("region") is None        # delay mode: sleeps
@@ -241,7 +241,6 @@ def test_support_faults_are_retried_to_parity(times):
             h = sched.open_async(e).result(timeout=120)
             st = sched.stats()
         # a demoted open must hand back a handle on the engine's executors
-        assert h._inc.support_mode == sched.engine.support_mode
         assert h._inc.table_mode == sched.engine.table_mode
         q = sched.query_async(h, e).result(timeout=120)
     assert np.array_equal(q, want)
@@ -299,29 +298,29 @@ def test_delay_fault_past_deadline_is_a_typed_deadline_error():
 # --------------------------------------------- ladder demotion/re-promotion --
 
 
-def test_pallas_failure_degrades_to_jnp_then_repromotes():
-    """Acceptance: forced pallas failures demote to the jnp rung with
-    identical outputs, then recovery probes re-promote to pallas."""
+def test_dense_failure_degrades_to_chunked_then_repromotes():
+    """Acceptance: forced dense-peel failures demote to the chunked rung
+    with identical outputs, then recovery probes re-promote to dense."""
     e = _er_edges(14, 0.4, 26)
     want = _expected(e)
-    plan = FaultPlan().add("flush", rung="pallas", times=2)
+    plan = FaultPlan().add("flush", rung="dense", times=2)
     with plan:
-        with TrussScheduler(mode="pallas", interpret=True, max_batch=1,
+        with TrussScheduler(mode="dense", max_batch=1,
                             max_delay_ms=0.0, retry=_FAST,
                             ladder={"demote_after": 2, "probe_after": 1,
                                     "promote_after": 1}) as sched:
             outs = [sched.submit_async(e).result(timeout=120)
                     for _ in range(3)]
             st = sched.stats()
-    for out in outs:                # demoted and pallas results identical
+    for out in outs:                # demoted and dense results identical
         assert np.array_equal(out, want)
     flush = st["resilience"]["flush"]
-    assert flush["rungs"][0] == "pallas+jnp"
-    assert flush["failures"] == 2           # two forced pallas failures
-    assert flush["demotions"] == 1          # -> chunked+jnp
+    assert flush["rungs"] == ["dense", "chunked", "host"]
+    assert flush["failures"] == 2           # two forced dense failures
+    assert flush["demotions"] == 1          # -> chunked
     assert flush["probes"] == 1             # recovery probe on live traffic
-    assert flush["promotions"] == 1         # back on pallas
-    assert flush["rung"] == "pallas+jnp"
+    assert flush["promotions"] == 1         # back on dense
+    assert flush["rung"] == "dense"
     assert plan.stats()["injected"]["flush"] == 2
 
 

@@ -7,8 +7,7 @@ TPU compiler, which refuses what the chip cannot run — unaligned blocks,
 unsupported gathers, programs that do not fit the device.  The topology is
 described inside a module fixture (never at import, in a ``skipif`` or in
 ``parametrize``), so every pytest worker collects the same tests and only
-the one that runs this file loads the TPU library.  The two Pallas kernels
-do not lower yet (ROADMAP Speed 2); their strict xfails flip when they do.
+the one that runs this file loads the TPU library.
 """
 
 import importlib
@@ -72,8 +71,7 @@ def test_support_device_jit_compiles(one_chip):
     c = support._support_device_jit.lower(
         _sds(s, (M,)), _sds(s, (M,)), _sds(s, (N_V + 1,)), _sds(s, (N_V,)),
         _sds(s, (2 * M,)), _sds(s, (2 * M,)), _sds(s, ()),
-        m=M, size=TABLE, mode="jnp", chunk=CHUNK, n_chunks=TABLE // CHUNK,
-        iters=S_ITERS, interpret=False).compile()
+        m=M, size=TABLE, iters=S_ITERS).compile()
     _fits_v5e(c)
 
 
@@ -92,7 +90,7 @@ def test_pkt_peel_jit_compiles(one_chip):
     c = pkt._pkt_peel_jit.lower(
         _sds(s, (M,)), _peel_tables(s),
         m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK,
-        mode="chunked", interpret=False).compile()
+        mode="chunked").compile()
     _fits_v5e(c)
 
 
@@ -101,7 +99,7 @@ def test_peel_segment_jit_compiles(one_chip):
     c = pkt._peel_segment_jit.lower(
         _sds(s, (M + 1,)), _sds(s, (M + 1,), jnp.bool_), _sds(s, ()), None,
         _peel_tables(s), m=M, chunk=CHUNK, n_chunks=TABLE // CHUNK,
-        mode="chunked", interpret=False).compile()
+        mode="chunked").compile()
     _fits_v5e(c)
 
 
@@ -117,9 +115,7 @@ def test_engine_batched_flush_compiles(one_chip):
         u=_sds(s, (B, m_pad)), v=_sds(s, (B, m_pad)), m_real=_sds(s, (B,)))
     c = _batched_truss_dev.lower(
         ops, m=m_pad, chunk=chunk, n_chunks=tab // chunk, iters=14,
-        mode="chunked", support_mode="jnp", sup_chunk=chunk,
-        sup_n_chunks=tab // chunk, sup_pad=tab, peel_pad=tab,
-        interpret=False).compile()
+        mode="chunked", sup_pad=tab, peel_pad=tab).compile()
     _fits_v5e(c)
 
 
@@ -131,35 +127,6 @@ def test_hierarchy_flood_jit_compiles(one_chip):
         _sds(s, (rows, 3)), _sds(s, (rows,)), _sds(s, ()), _sds(s, ()),
         _sds(s, (mp,)), sz=rows, mp=mp).compile()
     _fits_v5e(c)
-
-
-# ---- the Pallas kernels: refused by Mosaic today (ROADMAP Speed 2) ----------
-
-_KM, _KCHUNK, _KN = 1 << 14, 1 << 10, 16
-
-
-@pytest.mark.xfail(strict=True, reason="support kernel does not lower for "
-                   "TPU yet: rank-1 blocks, 1-D gathers, in-kernel scatter")
-def test_pallas_support_kernel_lowers(one_chip):
-    from repro.kernels.support import support_counts
-
-    s, T = one_chip, _KN * _KCHUNK
-    support_counts.lower(
-        *[_sds(s, (T,))] * 4, _sds(s, (2 * _KM,)), _sds(s, (2 * _KM,)),
-        chunk=_KCHUNK, n_chunks=_KN, iters=S_ITERS, m=_KM,
-        interpret=False).compile()
-
-
-@pytest.mark.xfail(strict=True, reason="peel kernel does not lower for "
-                   "TPU yet: rank-1 blocks, 1-D gathers, in-kernel scatter")
-def test_pallas_peel_kernel_lowers(one_chip):
-    from repro.kernels.peel import peel_decrements
-
-    s, T = one_chip, _KN * _KCHUNK
-    peel_decrements.lower(
-        _sds(s, (_KN,)), _sds(s, (1,)), *[_sds(s, (T,))] * 3,
-        *[_sds(s, (_KM + 1,))] * 3,
-        chunk=_KCHUNK, n_chunks=_KN, m=_KM, interpret=False).compile()
 
 
 def test_shapes_match_rmat_medium():

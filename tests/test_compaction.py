@@ -1,7 +1,7 @@
 """Live-edge compaction (DESIGN.md §10): peeling with compaction enabled —
 at any threshold — must be bitwise identical to the uncompacted run, across
-the full (support × peel) executor matrix, both table modes, the batched
-engine, and the incremental layer's compacted region re-peel.
+both peel executors, both table modes, the batched engine, and the
+incremental layer's compacted region re-peel.
 
 Runs under real ``hypothesis`` and under the deterministic fallback shim.
 """
@@ -14,12 +14,10 @@ from hypothesis import strategies as st
 
 from repro.core.pkt import PEEL_MODES, peel_live_subset, pkt, truss_pkt
 from repro.core.ref import truss_numpy
-from repro.core.support import SUPPORT_MODES, compute_support
+from repro.core.support import compute_support
 from repro.graphs.csr import build_csr, edges_from_arrays
 from repro.graphs.gen import (barabasi_albert_edges, ring_of_cliques_edges,
                               rmat_edges)
-
-MATRIX = [(pm, sm) for pm in PEEL_MODES for sm in SUPPORT_MODES]
 
 #: "aggressive" compaction: compact at every level boundary, no size floor —
 #: maximally different execution schedule from the single-segment run
@@ -53,7 +51,7 @@ def graphs(draw):
           suppress_health_check=[HealthCheck.too_slow])
 @given(graphs())
 def test_compaction_parity_matrix(E):
-    """All 6 executor pairs × threshold ∈ {off, aggressive}: bitwise equal
+    """Both peel executors × threshold ∈ {off, aggressive}: bitwise equal
     (multi-clique graphs peel over several levels, so aggressive compaction
     really does segment the run)."""
     if E.shape[0] == 0:
@@ -62,13 +60,13 @@ def test_compaction_parity_matrix(E):
     base = pkt(g, **OFF)
     if g.m <= 90:
         assert np.array_equal(base.trussness, truss_numpy(g.El))
-    for pm, sm in MATRIX:
+    for pm in PEEL_MODES:
         for thresh in (OFF, AGGRESSIVE):
-            res = pkt(g, mode=pm, support_mode=sm, **thresh)
-            assert np.array_equal(res.trussness, base.trussness), (pm, sm)
-            assert np.array_equal(res.support, base.support), (pm, sm)
+            res = pkt(g, mode=pm, **thresh)
+            assert np.array_equal(res.trussness, base.trussness), pm
+            assert np.array_equal(res.support, base.support), pm
             assert (res.levels, res.sublevels) == \
-                (base.levels, base.sublevels), (pm, sm, thresh)
+                (base.levels, base.sublevels), (pm, thresh)
 
 
 @pytest.mark.parametrize("table_mode", ["numpy", "device"])
@@ -109,10 +107,9 @@ def test_engine_table_mode_parity():
              np.array([[0, 1], [1, 2], [2, 3]], np.int64),
              rmat_edges(5, edge_factor=4, seed=4)]
     base = truss_batched(fleet, table_mode="numpy")
-    for sm in SUPPORT_MODES:
-        got = truss_batched(fleet, table_mode="device", support_mode=sm)
-        for b, g_ in zip(base, got):
-            assert np.array_equal(b, g_), sm
+    got = truss_batched(fleet, table_mode="device")
+    for b, g_ in zip(base, got):
+        assert np.array_equal(b, g_)
 
 
 def test_peel_live_subset_whole_graph_is_full_peel():

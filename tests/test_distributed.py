@@ -72,29 +72,6 @@ print("OK", g.m)
     assert "OK" in out
 
 
-def test_pkt_dist_support_kernel_sharded():
-    """support_mode="pallas": each shard lowers the support kernel over its
-    own table slice (interpret mode off-TPU); result matches the oracle and
-    the jnp support path bitwise."""
-    out = run_py("""
-import numpy as np, jax
-from repro.graphs.csr import build_csr, edges_from_arrays
-from repro.core import truss_numpy, pkt_dist
-rng = np.random.default_rng(11)
-n = 40
-mask = rng.random((n, n)) < 0.25
-src, dst = np.nonzero(np.triu(mask, 1))
-g = build_csr(edges_from_arrays(src, dst, n))
-assert len(jax.devices()) >= 2
-a = pkt_dist(g, chunk=64, support_mode="jnp")
-b = pkt_dist(g, chunk=64, support_mode="pallas")
-assert np.array_equal(a, b)
-assert np.array_equal(b, truss_numpy(g.El))
-print("OK", g.m)
-""")
-    assert "OK" in out
-
-
 def test_train_step_sharded_small_mesh():
     """Real sharded execution (2x4 mesh): two steps run and loss is finite,
     and the sharded result matches single-device execution."""

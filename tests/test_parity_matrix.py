@@ -1,9 +1,10 @@
-"""Property-based cross-mode parity: the full executor matrix must agree.
+"""Property-based cross-mode parity: the peel executors must agree.
 
-Every combination in {dense, chunked, pallas} peel × {jnp, pallas} support
-must produce bitwise-identical trussness, initial support, and level/
-sub-level counts — and match the brute-force oracle (``core.ref.truss_numpy``,
-the definitional O(m·Δ²)-per-round peel) on graphs small enough to afford it.
+Both peel modes {dense, chunked} must produce bitwise-identical trussness,
+initial support, and level/sub-level counts — and match the brute-force
+oracle (``core.ref.truss_numpy``, the definitional O(m·Δ²)-per-round peel)
+on graphs small enough to afford it, and on the Graph500 Kronecker graphs
+of the benchmark's family at any size the tests run.
 
 Graph population: random Erdős–Rényi and power-law (Barabási–Albert) draws
 via ``graphs/gen.py``, plus the adversarial shapes that historically break
@@ -25,12 +26,10 @@ from hypothesis import strategies as st
 
 from repro.core.pkt import PEEL_MODES, pkt
 from repro.core.ref import truss_numpy
-from repro.core.support import SUPPORT_MODES, compute_support
-from repro.graphs.csr import build_csr, edges_from_arrays
+from repro.graphs.csr import (build_csr, degeneracy_order, edges_from_arrays,
+                              relabel)
 from repro.graphs.gen import (barabasi_albert_edges, erdos_renyi_edges,
                               ring_of_cliques_edges, rmat_edges)
-
-MATRIX = [(pm, sm) for pm in PEEL_MODES for sm in SUPPORT_MODES]
 
 #: brute-force oracle bound — small enough that every example stays cheap
 ORACLE_MAX_M = 90
@@ -84,16 +83,16 @@ def raw_graph(draw):
 
 
 def _assert_matrix_agrees(raw_edges, *, chunk=1 << 14):
-    """Canonicalize, run all six executors, compare bitwise (+ oracle)."""
+    """Canonicalize, run both peel executors, compare bitwise (+ oracle)."""
     E = edges_from_arrays(raw_edges[:, 0], raw_edges[:, 1])
     g = build_csr(E)
-    base = pkt(g, mode="chunked", support_mode="jnp", chunk=chunk)
-    for pm, sm in MATRIX:
-        res = pkt(g, mode=pm, support_mode=sm, chunk=chunk)
-        assert np.array_equal(res.trussness, base.trussness), (pm, sm)
-        assert np.array_equal(res.support, base.support), (pm, sm)
+    base = pkt(g, mode="chunked", chunk=chunk)
+    for pm in PEEL_MODES:
+        res = pkt(g, mode=pm, chunk=chunk)
+        assert np.array_equal(res.trussness, base.trussness), pm
+        assert np.array_equal(res.support, base.support), pm
         assert (res.levels, res.sublevels) == (base.levels, base.sublevels), \
-            (pm, sm)
+            pm
     if g.m <= ORACLE_MAX_M:
         assert np.array_equal(base.trussness, truss_numpy(g.El))
     return base
@@ -106,19 +105,6 @@ def _assert_matrix_agrees(raw_edges, *, chunk=1 << 14):
 @given(raw_graph())
 def test_parity_matrix_random(edges):
     _assert_matrix_agrees(edges)
-
-
-@settings(max_examples=10, deadline=None,
-          suppress_health_check=[HealthCheck.too_slow])
-@given(raw_graph())
-def test_support_mode_parity_random(edges):
-    """The cheap half of the matrix at higher example volume: support only."""
-    E = edges_from_arrays(edges[:, 0], edges[:, 1])
-    g = build_csr(E)
-    a = compute_support(g, mode="jnp")
-    b = compute_support(g, mode="pallas")
-    assert np.array_equal(a, b)
-    assert a.dtype == b.dtype
 
 
 # -------------------------------------------------------- named fixtures ----
@@ -143,32 +129,34 @@ def test_parity_matrix_named(name):
     raw = NAMED[name]
     if name == "empty":
         g = build_csr(raw)
-        for pm, sm in MATRIX:
-            res = pkt(g, mode=pm, support_mode=sm)
-            assert res.trussness.shape == (0,), (pm, sm)
+        for pm in PEEL_MODES:
+            res = pkt(g, mode=pm)
+            assert res.trussness.shape == (0,), pm
         return
     _assert_matrix_agrees(raw)
 
 
 def test_parity_matrix_small_chunks():
-    """Chunk boundaries must not affect any executor pair."""
+    """Chunk boundaries must not affect either executor."""
     raw = ring_of_cliques_edges(3, 5)
     for chunk in (4, 32):
         _assert_matrix_agrees(raw, chunk=chunk)
 
 
-def test_invalid_support_mode_rejected():
-    g = build_csr(np.array([[0, 1]], np.int64))
-    with pytest.raises(ValueError, match="support_mode"):
-        pkt(g, support_mode="warp")
-    with pytest.raises(ValueError, match="mode"):
-        compute_support(g, mode="warp")
-
-
-def test_peel_mode_alias_wins_over_mode():
-    g = build_csr(_clique(5))
-    a = pkt(g, mode="dense", peel_mode="chunked")
-    b = pkt(g, mode="chunked")
-    assert np.array_equal(a.trussness, b.trussness)
-    with pytest.raises(ValueError, match="mode"):
-        pkt(g, mode="chunked", peel_mode="warp")
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("scale", [6, 7, 8])
+def test_graph500_rmat_parity(scale, seed):
+    """Graph500 Kronecker graphs (A/B/C .57/.19/.19, edge factor 16),
+    relabelled by coreness as the benchmark's one-shot cell does: both
+    executors and the oracle agree on every count."""
+    E = rmat_edges(scale, edge_factor=16, seed=seed)
+    n = int(E.max()) + 1
+    g = build_csr(relabel(E, degeneracy_order(E, n)), n)
+    ref = truss_numpy(g.El)
+    dense, chunked = (pkt(g, mode=pm) for pm in ("dense", "chunked"))
+    assert np.array_equal(chunked.trussness, ref)
+    assert np.array_equal(dense.trussness, ref)
+    assert np.array_equal(dense.support, chunked.support)
+    assert (dense.levels, dense.sublevels, dense.compactions) == \
+        (chunked.levels, chunked.sublevels, chunked.compactions)
+    assert chunked.chunk_visits <= dense.chunk_visits

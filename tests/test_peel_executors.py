@@ -1,11 +1,9 @@
-"""Peel-phase Pallas kernel: bitwise parity with the chunked/dense executors
-and the numpy oracle, on random and adversarial graphs; plus the chunk-clamp
-regression for tiny graphs."""
+"""Peel executors: the dense and chunked modes agree bitwise with each other
+and with the numpy oracle, on random and adversarial graphs; plus the
+chunk-clamp regression for tiny graphs and a level / sub-level recount."""
 
 import numpy as np
 import pytest
-
-import jax.numpy as jnp
 
 from repro.graphs.csr import build_csr, edges_from_arrays
 from repro.graphs.gen import ring_of_cliques_edges, rmat_edges
@@ -49,32 +47,33 @@ ADVERSARIAL = {
 
 # ---------------------------------------------------------------- parity ----
 
+def assert_executors_agree(g, **kw):
+    """``dense`` and ``chunked`` give the same result, field by field, and
+    the oracle's trussness.  ``chunk_visits`` counts each executor's own
+    work: dense visits every chunk a sub-level, chunked only the
+    frontier's, so chunked never visits more."""
+    dense = pkt(g, mode="dense", **kw)
+    chunked = pkt(g, mode="chunked", **kw)
+    assert np.array_equal(dense.trussness, chunked.trussness)
+    assert np.array_equal(dense.support, chunked.support)
+    assert (dense.levels, dense.sublevels, dense.compactions,
+            dense.phases) == (chunked.levels, chunked.sublevels,
+                              chunked.compactions, chunked.phases)
+    assert chunked.chunk_visits <= dense.chunk_visits
+    assert np.array_equal(chunked.trussness, truss_numpy(g.El))
+    return dense, chunked
+
+
 @pytest.mark.parametrize("seed", range(5))
-def test_pallas_parity_random(seed):
+def test_executor_parity_random(seed):
     E = _er_edges(12 + 8 * seed, 0.15 + 0.08 * seed, 100 + seed)
-    if E.size == 0:
-        return
-    g = build_csr(E)
-    ref = truss_numpy(g.El)
-    chunked = pkt(g, mode="chunked")
-    pallas = pkt(g, mode="pallas")
-    # bitwise-equal across every field of the result, and oracle-correct
-    assert np.array_equal(pallas.trussness, chunked.trussness)
-    assert np.array_equal(pallas.support, chunked.support)
-    assert (pallas.levels, pallas.sublevels) == \
-        (chunked.levels, chunked.sublevels)
-    assert np.array_equal(pallas.trussness, ref)
+    assert E.size
+    assert_executors_agree(build_csr(E), chunk=8)
 
 
 @pytest.mark.parametrize("name", sorted(ADVERSARIAL))
-def test_pallas_parity_adversarial(name):
-    g = build_csr(ADVERSARIAL[name])
-    ref = truss_numpy(g.El)
-    for chunk in (8, 1 << 14):
-        chunked = pkt(g, mode="chunked", chunk=chunk).trussness
-        pallas = pkt(g, mode="pallas", chunk=chunk).trussness
-        assert np.array_equal(pallas, chunked), (name, chunk)
-        assert np.array_equal(pallas, ref), (name, chunk)
+def test_executor_parity_adversarial(name):
+    assert_executors_agree(build_csr(ADVERSARIAL[name]), chunk=8)
 
 
 def test_all_modes_agree_multi_chunk():
@@ -142,7 +141,7 @@ def test_prepare_peel_empty_graph_explicit():
 def test_prepare_peel_entryless_support_table():
     """A triangle-free graph (star) has an *empty* support table and a peel
     table whose wedges all miss: the peel tables must be all sentinel rows
-    with no edge entered, and both support executors must return all-zero
+    with no edge entered, and the support executor must return all-zero
     support."""
     g = build_csr(_star_edges())
     stab = support_mod.build_support_table(g)
@@ -154,9 +153,8 @@ def test_prepare_peel_entryless_support_table():
     for col in (tabs.e1, tabs.e2, tabs.e3):
         assert (np.asarray(col) == g.m).all()
     assert not np.asarray(tabs.has_entries).any()
-    for mode in ("jnp", "pallas"):
-        S = support_mod.compute_support(g, stab, mode=mode)
-        assert S.shape == (g.m,) and (S == 0).all(), mode
+    S = support_mod.compute_support(g, stab)
+    assert S.shape == (g.m,) and (S == 0).all()
 
 
 @pytest.mark.parametrize("mode", PEEL_MODES)
@@ -169,33 +167,6 @@ def test_triangle_free_graph_all_modes(mode):
         res = pkt(g, mode=mode)
         assert (res.trussness == 2).all()
         assert (res.support == 0).all()
-
-
-# ------------------------------------------------------- kernel lowering ----
-
-def test_peel_kernel_compiles_interpret():
-    """The CI lowering gate: jit + interpret-mode pallas_call end-to-end."""
-    from repro.kernels.peel import peel_decrements
-
-    g = build_csr(ring_of_cliques_edges(3, 4))
-    rows = support_mod.resolve_peel_table(g)
-    tabs, chunk, n_chunks = prepare_peel(rows, g.m, 16)
-    m = g.m
-    S0 = support_mod.compute_support(g)
-    S_ext = jnp.concatenate([jnp.asarray(S0),
-                             jnp.full((1,), 1 << 30, jnp.int32)])
-    processed = jnp.zeros((m + 1,), jnp.int32).at[m].set(1)
-    l = int(S0.min())
-    inCurr = ((processed == 0) & (S_ext == l)).astype(jnp.int32)
-    dec = peel_decrements(
-        jnp.ones((n_chunks,), jnp.int32), jnp.full((1,), l, jnp.int32),
-        tabs.e1, tabs.e2, tabs.e3, S_ext, processed, inCurr,
-        chunk=chunk, n_chunks=n_chunks, m=m, interpret=True)
-    dec = np.asarray(dec)
-    assert dec.shape == (m + 1,)
-    # decrements only land on live edges above the frontier level
-    live = np.asarray(S_ext[:m]) > l
-    assert (dec[:m][~live] == 0).all()
 
 
 # ------------------------------------------- levels and sub-levels ----

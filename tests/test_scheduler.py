@@ -280,3 +280,31 @@ def test_stats_shape():
     assert st["counters"]["submit"] == 1
     assert st["counters"]["done"] == 1
     assert "engine" in st
+
+
+# ------------------------------------------------------ degradation ladders --
+
+#: each site's rungs, fastest first, by the engine option that shapes them
+_FLUSH_RUNGS = {"chunked": ["chunked", "host"],
+                "dense": ["dense", "chunked", "host"]}
+_REGION_RUNGS = {"chunked": ["default", "host"],
+                 "dense": ["default", "chunked", "host"]}
+_SUPPORT_RUNGS = {"device": ["default", "numpy"], "numpy": ["default"]}
+_HIER_RUNGS = {"device": ["default", "host"], "host": ["default"]}
+
+
+@pytest.mark.parametrize("hier_mode", ["device", "host"])
+@pytest.mark.parametrize("table_mode", ["device", "numpy"])
+@pytest.mark.parametrize("mode", ["chunked", "dense"])
+def test_ladder_rungs_follow_engine_config(mode, table_mode, hier_mode):
+    """Every site's ladder holds the configured executor, then each slower
+    executor that gives the same result, and nothing else."""
+    sched = TrussScheduler(start=False, mode=mode, table_mode=table_mode,
+                           hier_mode=hier_mode)
+    ladders = sched.stats()["resilience"]
+    sched.close()
+    assert {site: lad["rungs"] for site, lad in ladders.items()} == {
+        "flush": _FLUSH_RUNGS[mode], "region": _REGION_RUNGS[mode],
+        "support": _SUPPORT_RUNGS[table_mode],
+        "hierarchy": _HIER_RUNGS[hier_mode]}
+    assert all(lad["rung"] == lad["rungs"][0] for lad in ladders.values())

@@ -21,7 +21,7 @@ import jax
 
 import repro
 from repro import spans
-from repro.core.pkt import pkt, truss_pkt
+from repro.core.pkt import PEEL_MODES, pkt, truss_pkt
 from repro.core import support as support_mod
 from repro.core.support import resolve_peel_table
 from repro.graphs.csr import build_csr, degeneracy_order, relabel
@@ -177,7 +177,7 @@ def _recount(g, off, chunk, n_chunks):
     return subs, visits
 
 
-@pytest.mark.parametrize("mode", ["chunked", "dense", "pallas"])
+@pytest.mark.parametrize("mode", PEEL_MODES)
 @pytest.mark.parametrize("graph", ["rmat", "cliques"])
 @pytest.mark.parametrize("table_mode", ["numpy", "device"])
 def test_chunk_visits_match_a_numpy_recount(ring, graph, mode, table_mode):
@@ -194,6 +194,30 @@ def test_chunk_visits_match_a_numpy_recount(ring, graph, mode, table_mode):
         subs, visits = _recount(g, resolve_peel_table(g).off, chunk,
                                 n_chunks)
         assert (res.sublevels, res.chunk_visits) == (subs, visits)
+
+
+@pytest.mark.parametrize("table_mode", ["numpy", "device"])
+@pytest.mark.parametrize("mode", PEEL_MODES)
+def test_chunk_visits_sum_over_segments(ring, mode, table_mode):
+    """A compacting decomposition of a Graph500 Kronecker graph: the
+    ``pkt.peel_segment`` spans' chunk visits and sub-levels sum to the
+    result's, and a dense segment visits every one of its chunks at each of
+    its sub-levels — the counters ``peel_scan_pct`` reads, segment by
+    segment."""
+    g = build_csr(rmat_edges(8, edge_factor=16))
+    res = pkt(g, mode=mode, table_mode=table_mode, compact_frac=0.9,
+              compact_min=1)
+    segs = [r.attrs for r in spans.drain() if r.name == "pkt.peel_segment"]
+    assert len(segs) == res.compactions + 1 >= 2
+    assert [s["segment"] for s in segs] == list(range(len(segs)))
+    assert sum(s["chunk_visits"] for s in segs) == res.chunk_visits
+    assert sum(s["sublevels"] for s in segs) == res.sublevels
+    assert sum(s["levels"] for s in segs) == res.levels
+    for s in segs:
+        if mode == "dense":
+            assert s["chunk_visits"] == s["n_chunks"] * s["sublevels"]
+        else:
+            assert s["chunk_visits"] <= s["n_chunks"] * s["sublevels"]
 
 
 @pytest.mark.parametrize("table_mode", ["numpy", "device"])
@@ -241,7 +265,7 @@ def test_resolve_runs_in_the_peel_table_build():
     for mode, loops in (("chunked", 3), ("dense", 3)):
         text = pkt_mod._peel_segment_jit.lower(
             S, proc, jax.numpy.int32(0), None, tabs, m=g.m, chunk=chunk,
-            n_chunks=n_chunks, mode=mode, interpret=True).as_text()
+            n_chunks=n_chunks, mode=mode).as_text()
         assert text.count("stablehlo.while") == loops, mode
 
 

@@ -77,28 +77,13 @@ def _prep(eng, edges):
         support_mod.build_peel_table(g)
 
 
-@pytest.mark.parametrize("mode", ["dense", "pallas"])
+@pytest.mark.parametrize("mode", ["dense"])
 def test_engine_mode_parity(mode):
     fleet = [_er_edges(14, 0.35, 20), ring_of_cliques_edges(3, 4)]
     base = truss_batched(fleet, mode="chunked")
     got = truss_batched(fleet, mode=mode)
     for b, g_ in zip(base, got):
         assert np.array_equal(b, g_)
-
-
-def test_engine_support_mode_parity():
-    """The batched support kernel path agrees with the batched jnp path."""
-    fleet = [_er_edges(14, 0.35, 21), ring_of_cliques_edges(3, 4),
-             np.array([[0, 1], [1, 2]], np.int64)]
-    base = truss_batched(fleet, support_mode="jnp")
-    got = truss_batched(fleet, support_mode="pallas")
-    for b, g_ in zip(base, got):
-        assert np.array_equal(b, g_)
-
-
-def test_engine_invalid_support_mode_rejected():
-    with pytest.raises(ValueError, match="support_mode"):
-        TrussEngine(support_mode="warp")
 
 
 def test_row_alignment_swapped_and_duplicate_rows():

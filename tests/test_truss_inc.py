@@ -172,10 +172,10 @@ def test_ring_of_cliques_bridge_churn():
         _assert_state_exact(inc)
 
 
-@pytest.mark.parametrize("mode", ["chunked", "dense", "pallas"])
+@pytest.mark.parametrize("mode", ["chunked", "dense"])
 def test_masked_peel_executor_modes(mode):
-    """The pinned-boundary jax re-peel agrees across all three peel
-    executors (the pinned mask is threaded through each)."""
+    """The pinned-boundary jax re-peel agrees across both peel executors
+    (the pinned mask is threaded through each)."""
     inc = IncrementalTruss(ring_of_cliques_edges(3, 4), mode=mode,
                            local_frac=1.0, host_peel_max=0)
     inc.update(add_edges=np.array([[0, 2], [1, 9]]),
@@ -282,7 +282,7 @@ def test_triangles_through_subset_anchors():
 
 #: Region-size regimes × executors × table modes the batched insertion path
 #: must agree across, bitwise: host-mirror regions, masked-device regions,
-#: forced mid-peel compaction, all three peel executors, both wedge-table
+#: forced mid-peel compaction, both peel executors, both wedge-table
 #: builders, and the forced full-recompute fallback.
 BATCH_AXES = {
     "host-region": dict(local_frac=1.0),
@@ -290,7 +290,6 @@ BATCH_AXES = {
     "compacting": dict(local_frac=1.0, host_peel_max=0,
                        compact_frac=0.9, compact_min=1),
     "dense": dict(local_frac=1.0, host_peel_max=0, mode="dense"),
-    "pallas": dict(local_frac=1.0, host_peel_max=0, mode="pallas"),
     "numpy-table": dict(local_frac=1.0, host_peel_max=0, table_mode="numpy"),
     "forced-fallback": dict(local_frac=0.0),
 }
