@@ -30,10 +30,10 @@ many times (DESIGN.md §11):
     converge to the same canonical labels.
 
 Triangle connectivity comes from the decomposition's triangle list — the
-same (T, 3) edge-id rows the wedge-table pipeline enumerates
-(``core.truss_inc.triangle_list``) and that incremental handles already
-maintain across updates, so a handle's index build does zero extra triangle
-work.
+same (T, 3) edge-id rows a handle's full rebuild selects from its peel
+table and maintains across updates, so a handle's index build does zero
+extra triangle work (``hierarchy_from_graph`` enumerates them with
+``core.truss_inc.triangle_list``).
 
 Levels build lazily and cache; ``core/truss_inc.py`` keeps a handle's index
 alive across ``update`` batches by remapping the untouched high levels

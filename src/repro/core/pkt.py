@@ -79,6 +79,35 @@ class PeelTables(NamedTuple):
     has_entries: jnp.ndarray  # (m,) bool
 
 
+class TriangleRows(NamedTuple):
+    """Every triangle of a ``pkt`` graph once, taken from its whole-graph
+    peel table (``pkt(..., triangles=True)``).
+
+    The first ``count`` rows of ``rows`` are ``(e1, e2, e3)`` edge ids,
+    ``e1`` the lowest; ``table_hits`` is the rows the table kept (3T).
+    Device-built values stay on the device until ``read``.
+    """
+
+    rows: object            # (out, 3) int32, device or host
+    count: object           # rows selected, device or host scalar
+    table_hits: object      # rows the peel table kept, device or host scalar
+
+    def read(self) -> tuple[np.ndarray, int]:
+        """The ``(T, 3)`` rows on the host, and ``table_hits``.
+
+        Raises:
+            RuntimeError: the rows are not a third of the table's, or did
+                not fit ``rows``: the list would miss triangles.
+        """
+        rows, count, hits = jax.device_get(tuple(self))
+        count, hits = int(count), int(hits)
+        if 3 * count != hits or count > rows.shape[0]:
+            raise RuntimeError(
+                f"triangle rows disagree with their peel table: {count} "
+                f"selected into {rows.shape[0]} slots, {hits} table rows")
+        return np.asarray(rows[:count]), hits
+
+
 @dataclasses.dataclass(frozen=True)
 class PKTResult:
     """Full output of one ``pkt`` decomposition, with phase accounting."""
@@ -94,6 +123,8 @@ class PKTResult:
     #: (each phase span then waits for its device work before it closes, so
     #: attribution is honest but adds barriers)
     phases: dict | None = None
+    #: ``pkt(..., triangles=True)``: the triangles of ``g``, each once
+    triangles: TriangleRows | None = None
 
 
 #: ``pkt``'s phase spans, by the ``PKTResult.phases`` key each feeds
@@ -586,7 +617,7 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
         peel_table: support_mod.WedgeTable | None = None,
         compact_frac: float | None = _COMPACT_FRAC,
         compact_min: int = _COMPACT_MIN,
-        phase_timings: bool = False) -> PKTResult:
+        phase_timings: bool = False, triangles: bool = False) -> PKTResult:
     """Full PKT truss decomposition of one CSR graph.
 
     Both peel modes and both table modes produce bitwise-identical
@@ -618,11 +649,16 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             {tables, support, peel, compact} wall-time split, read from
             the phase spans (``repro.spans``); adds a sync barrier at the
             end of each phase.
+        triangles: also return every triangle of ``g`` once
+            (``PKTResult.triangles``), selected from the whole-graph peel
+            table this call builds anyway; the device selection is one
+            small jit dispatched before the peel, read back by the caller.
 
     Returns:
         :class:`PKTResult` — per-edge trussness (support + 2, aligned to
         ``g.El`` rows), initial support, and the level, sub-level,
-        chunk-visit and compaction counters.
+        chunk-visit and compaction counters; with ``triangles``, the
+        triangle rows.
 
     Raises:
         ValueError: unknown ``mode`` / ``table_mode``.
@@ -638,7 +674,10 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
     if g.m == 0:
         return PKTResult(np.zeros(0, np.int32), np.zeros(0, np.int32), 0, 0,
                          phases=(dict.fromkeys(_PHASE_SPANS.values(), 0.0)
-                                 if phase_timings else None))
+                                 if phase_timings else None),
+                         triangles=(TriangleRows(np.zeros((0, 3), np.int32),
+                                                 0, 0)
+                                    if triangles else None))
 
     with spans.span("pkt", m=g.m) as root:
         # ---- support phase -------------------------------------------------
@@ -658,15 +697,24 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             S0_dev = jnp.asarray(S0)
 
         # ---- peel tables ---------------------------------------------------
+        tri = None
         with spans.span("pkt.tables", table="peel") as sp:
             if table_mode == "device" and peel_table is None:
                 tabs, chunk_eff, n_chunks, hits = prepare_peel_device(g, chunk)
                 wedges = support_mod.peel_table_size(g)
+                if triangles:
+                    tri = _select_triangles(tabs, hits, int(S0.sum()) // 3)
             else:
                 rows = support_mod.resolve_peel_table(g, peel_table)
                 tabs, chunk_eff, n_chunks = prepare_peel(rows, g.m, chunk)
                 hits, wedges = rows.size, rows.wedges
                 sp.set(rows=rows.size)
+                if triangles:
+                    keep = (rows.e1 < rows.e2) & (rows.e1 < rows.e3)
+                    tri = TriangleRows(
+                        np.stack([rows.e1[keep], rows.e2[keep],
+                                  rows.e3[keep]], axis=1),
+                        int(keep.sum()), hits)
             sp.set(padded_rows=chunk_eff * n_chunks, chunk=chunk_eff,
                    n_chunks=n_chunks)
             if phase_timings:
@@ -696,7 +744,25 @@ def pkt(g: CSRGraph, *, chunk: int | None = None, mode: str = "chunked",
             chunk_visits=visits,
             phases=(spans.seconds_by_name(root, _PHASE_SPANS)
                     if phase_timings else None),
+            triangles=tri,
         )
+
+
+def _select_triangles(tabs: PeelTables, hits, n_tri: int) -> TriangleRows:
+    """Dispatch the selection of every triangle once from a whole-graph
+    device peel table (``support._triangle_rows_dev``).
+
+    ``n_tri`` (a third of the support's sum) sizes it: the selection
+    reads the table's first ``next_pow2(3 n_tri)`` rows, where the build
+    compacted its 3T kept rows, into ``next_pow2(n_tri)`` slots.  Both
+    are pow2 classes of the graph, so a graph that drifts under updates
+    seldom compiles a new one; ``TriangleRows.read`` checks the count.
+    """
+    rows = min(wedge_common.next_pow2(max(1, 3 * n_tri)), tabs.e1.shape[0])
+    sel, count = support_mod._triangle_rows_dev(
+        tabs.e1, tabs.e2, tabs.e3, rows=rows,
+        out=wedge_common.next_pow2(max(1, n_tri)))
+    return TriangleRows(sel, count, hits)
 
 
 def align_to_input(trussness: np.ndarray, g: CSRGraph,

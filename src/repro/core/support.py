@@ -374,6 +374,26 @@ def _build_peel_table_dev(u, v, Es, N, Eid, m_real, *, m: int, size: int,
     return e1, e2, e3, c_start, c_end, has, off[m]
 
 
+@functools.partial(jax.jit, static_argnames=("rows", "out"))
+def _triangle_rows_dev(e1, e2, e3, *, rows: int, out: int):
+    """Every triangle once, from a whole-graph peel table: the rows whose
+    anchor ``e1`` is the lowest of the three edges.
+
+    Reads the table's first ``rows`` rows (its kept rows lie there) and
+    compacts the selected ones stably to the front of an ``(out, 3)``
+    array (prefix sum plus a scatter, as ``peel_rows`` keeps its hits).
+    Padding rows carry the sentinel in all three columns, so the strict
+    comparisons drop them.  Returns the rows and how many were selected.
+    """
+    e1, e2, e3 = e1[:rows], e2[:rows], e3[:rows]
+    keep = (e1 < e2) & (e1 < e3)
+    pos = _prefix_sum(keep.astype(jnp.int32))
+    dst = jnp.where(keep, pos - 1, out)                 # the rest are dropped
+    tri = jnp.zeros((out, 3), jnp.int32).at[dst].set(
+        jnp.stack([e1, e2, e3], axis=1), mode="drop")
+    return tri, pos[-1]
+
+
 @functools.partial(jax.jit, static_argnames=("m", "size", "iters"))
 def _support_device_jit(u, v, Es, Eo, N, Eid, m_real, *, m: int, size: int,
                         iters: int):

@@ -216,16 +216,32 @@ def triangles_through(g: CSRGraph,
 def triangle_list(g: CSRGraph) -> np.ndarray:
     """All triangles of ``g``, each exactly once, as a (T, 3) edge-id array.
 
-    Enumerated with the full-adjacency wedge probe anchored at every edge
-    (each triangle surfaces once per member edge) and kept at its lowest
-    member id.  Built once per full decomposition; updates maintain the
-    list incrementally.
+    Enumerated on the host with the full-adjacency wedge probe anchored at
+    every edge (each triangle surfaces once per member edge) and kept at
+    its lowest member id.  The tests' oracle and the hierarchy helper
+    (``hierarchy.hierarchy_from_graph``); a handle's full rebuild takes
+    its list from the peel table ``pkt`` builds instead
+    (``PKTResult.triangles``), and updates maintain it incrementally.
     """
     if g.m == 0:
         return np.zeros((0, 3), np.int64)
     a, e2, e3 = triangles_through(g, np.arange(g.m, dtype=np.int64))
     keep = (a < e2) & (a < e3)
     return np.sort(np.stack([a[keep], e2[keep], e3[keep]], axis=1), axis=1)
+
+
+def _canonical_triangles(tri: np.ndarray, m: int) -> np.ndarray:
+    """``tri``'s rows, each sorted, in lexicographic order, as int64.
+
+    Two edges of a triangle fix the third, so a row's lowest and middle
+    ids key it uniquely.
+    """
+    a, b, c = (col.astype(np.int64) for col in tri.T)
+    lo = np.minimum(np.minimum(a, b), c)
+    hi = np.maximum(np.maximum(a, b), c)
+    mid = a + b + c - lo - hi
+    order = np.argsort(lo * m + mid)
+    return np.stack([lo[order], mid[order], hi[order]], axis=1)
 
 
 class _Incidence:
@@ -1080,7 +1096,8 @@ class IncrementalTruss:
             res = pkt(gr, chunk=self.chunk, mode=self.mode,
                       table_mode=self.table_mode,
                       compact_frac=self.compact_frac,
-                      compact_min=self.compact_min, phase_timings=True)
+                      compact_min=self.compact_min, phase_timings=True,
+                      triangles=True)
             #: phase breakdown of the most recent full (re)build — the open
             #: path's table-build vs support vs peel cost (benchmarks read it)
             self.open_phases = dict(res.phases or {})
@@ -1088,11 +1105,15 @@ class IncrementalTruss:
             v = g.El[:, 1].astype(np.int64)
             rl, rh = perm[u], perm[v]
             keys = edge_keys(np.minimum(rl, rh), np.maximum(rl, rh), self.n)
-            T = align_to_input(res.trussness, gr, None, self.n, keys=keys)
-            S = align_to_input(res.support, gr, None, self.n, keys=keys)
-            with spans.span("inc.triangle_list"):
-                tri = triangle_list(g)
-            self._commit(g, T, S.astype(np.int32), tri)
+            # gr's id of each of g's edges
+            pos = align_to_input(np.arange(gr.m), gr, None, self.n, keys=keys)
+            with spans.span("inc.triangle_list") as sp:
+                rows, hits = res.triangles.read()
+                sp.set(rows=rows.shape[0], table_hits=hits)
+                g_id = np.empty(g.m, np.int32)
+                g_id[pos] = np.arange(g.m, dtype=np.int32)
+                tri = _canonical_triangles(g_id[rows], g.m)
+            self._commit(g, res.trussness[pos], res.support[pos], tri)
 
     def check_invariants(self, *, sample: int = 64, seed: int = 0) -> int:
         """Cheap consistency check over a sampled edge set (DESIGN.md §15).

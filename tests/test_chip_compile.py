@@ -85,6 +85,16 @@ def test_build_peel_table_dev_compiles(one_chip):
     assert ma.output_size_in_bytes >= 3 * 4 * TABLE   # the three row arrays
 
 
+def test_triangle_rows_dev_compiles(one_chip):
+    """The full rebuild's selection of each triangle once from the peel
+    table: a whole table read into a quarter of its rows (T <= rows / 3)."""
+    s = one_chip
+    c = support._triangle_rows_dev.lower(
+        *[_sds(s, (TABLE,))] * 3, rows=TABLE, out=TABLE // 4).compile()
+    ma = _fits_v5e(c)
+    assert ma.output_size_in_bytes >= 3 * 4 * TABLE // 4
+
+
 def test_pkt_peel_jit_compiles(one_chip):
     s = one_chip
     c = pkt._pkt_peel_jit.lower(

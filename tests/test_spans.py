@@ -240,6 +240,48 @@ def test_peel_segments_carry_table_rows_and_hits(ring, table_mode):
         assert 0 <= seg.attrs["table_hits"] <= seg.attrs["table_rows"]
 
 
+@pytest.mark.parametrize("table_mode", ["numpy", "device"])
+def test_rebuild_triangle_list_span_counts_its_table_rows(ring, table_mode):
+    """A handle's full rebuild takes its triangle list from the peel table:
+    the ``inc.triangle_list`` span's ``rows`` (triangles returned) are a
+    third of its ``table_hits`` (rows the first segment's table kept), at
+    open and at a rebuild forced by ``local_frac=0``."""
+    from repro.core.truss_inc import IncrementalTruss
+
+    E, _ = _graph("rmat")
+    inc = IncrementalTruss(E, table_mode=table_mode, local_frac=0.0,
+                           compact_frac=0.99, compact_min=0)
+    inc.update(remove_edges=E[::9])
+    recs = spans.drain()
+    lists = [r.attrs for r in recs if r.name == "inc.triangle_list"]
+    firsts = [r.attrs for r in recs
+              if r.name == "pkt.peel_segment" and r.attrs["segment"] == 0]
+    assert len(lists) == len(firsts) == 2
+    for tl, seg in zip(lists, firsts):
+        assert 3 * tl["rows"] == tl["table_hits"] == seg["table_hits"] > 0
+    assert lists[-1]["rows"] == inc.triangles.shape[0]
+
+
+def test_one_shot_path_selects_no_triangles(ring, monkeypatch):
+    """``truss_pkt`` and ``pkt(g)`` return no triangle list and never
+    dispatch the selection jit, so the one-shot path runs exactly its
+    device programs; ``pkt(g, triangles=True)`` dispatches it once."""
+    calls = []
+    select = support_mod._triangle_rows_dev
+
+    def counted(*args, **kw):
+        calls.append(kw)
+        return select(*args, **kw)
+
+    monkeypatch.setattr(support_mod, "_triangle_rows_dev", counted)
+    E, g = _graph("rmat")
+    truss_pkt(E)
+    assert pkt(g).triangles is None
+    assert calls == []
+    rows, hits = pkt(g, triangles=True).triangles.read()
+    assert len(calls) == 1 and 3 * rows.shape[0] == hits
+
+
 def test_resolve_runs_in_the_peel_table_build():
     """The probe runs once, inside ``_build_peel_table_dev`` (where
     ``tables_dev_ms`` reads it), and the peel jits take no probe bound and

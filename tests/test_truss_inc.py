@@ -6,7 +6,7 @@ import pytest
 from hypothesis import given, settings, strategies as st, HealthCheck
 
 from repro.graphs.csr import edges_from_arrays
-from repro.graphs.gen import ring_of_cliques_edges
+from repro.graphs.gen import ring_of_cliques_edges, rmat_edges
 from repro.core.pkt import truss_pkt
 from repro.core.support import compute_support
 from repro.core.truss_inc import (INSERT_MODES, IncrementalTruss, _Incidence,
@@ -276,6 +276,55 @@ def test_triangles_through_subset_anchors():
         want = {tuple(sorted(int(y) for y in row if y != x))
                 for row in tri if (row == x).any()}
         assert got == want, x
+
+
+def _lexsorted(tri):
+    return tri[np.lexsort((tri[:, 2], tri[:, 1], tri[:, 0]))]
+
+
+#: graphs a full rebuild's triangle list is checked on (edges, handle
+#: options): random, a clique, triangle-free (a path and a star), and a
+#: Graph500 Kronecker graph whose rebuild peel compacts
+REBUILD_GRAPHS = {
+    "er": lambda: (_er_edges(24, 0.35, 21), {}),
+    "k6": lambda: (edges_from_arrays(*np.triu_indices(6, 1), 6), {}),
+    "path-star": lambda: (np.array([[i, i + 1] for i in range(8)]
+                                   + [[20, 20 + j] for j in range(1, 9)]), {}),
+    "kron8": lambda: (rmat_edges(8, edge_factor=16),
+                      dict(compact_frac=0.9, compact_min=1)),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(REBUILD_GRAPHS))
+@pytest.mark.parametrize("table_mode", ["device", "numpy"])
+def test_full_rebuild_takes_triangles_from_the_peel_table(table_mode, graph):
+    """The triangle list a full rebuild commits, selected from ``pkt``'s
+    peel table, is the host enumerator's list in lexicographic order, and
+    its per-edge row counts are the support — after open and after a batch
+    forced to a full rebuild (``local_frac=0``)."""
+    from repro import spans
+
+    E, kw = REBUILD_GRAPHS[graph]()
+    spans.drain()
+    inc = IncrementalTruss(E, table_mode=table_mode, local_frac=0.0, **kw)
+    if graph == "kron8":
+        assert any(r.name == "pkt.compact" for r in spans.drain())
+
+    def check():
+        assert np.array_equal(inc.triangles, _lexsorted(triangle_list(inc.g)))
+        assert np.array_equal(np.bincount(inc.triangles.ravel(),
+                                          minlength=inc.m),
+                              compute_support(inc.g))
+
+    check()
+    rng = np.random.default_rng(7)
+    n = int(inc.edges.max()) + 1
+    add = np.stack([rng.integers(0, n, 6), rng.integers(0, n, 6)], axis=1)
+    add = np.concatenate([add[add[:, 0] != add[:, 1]], [[0, 2]]])
+    rm = inc.edges[rng.choice(inc.m, 3, replace=False)]
+    assert inc.update(add_edges=add, remove_edges=rm).mode == "full"
+    check()
+    assert inc.triangles.shape[0] > 0
 
 
 # ------------------------------------------------ batched insertions (§13) --
